@@ -44,12 +44,12 @@ plan-report:
 tune-overlap:
 	python -m tpu_dist.analysis tune-overlap --inject-payload --out $(or $(OUT),tune_report.json)
 
-# The async-checkpoint cost proof: measure step-loop blocking per
-# sharded save for the synchronous barrier path vs the
-# snapshot-then-write background path on the same model, print the
-# ratio (acceptance floor: >=5x less blocking), and keep the TD120
-# injected-EIO probe honest — a probe that comes back clean is a dead
-# detector: exit 2 (docs/checkpointing.md "The cost, measured"):
+# The async-checkpoint cost, on a TPU host (bench.py refuses without
+# one): measure step-loop blocking per sharded save for the synchronous
+# barrier path vs the snapshot-then-write background path on the same
+# model, print the ratio, and keep the TD120 injected-EIO probe honest —
+# a probe that comes back clean is a dead detector: exit 2
+# (docs/checkpointing.md "The cost"):
 #   make ckpt-bench
 ckpt-bench:
 	python bench.py --ckpt sweep --config resnet18_cifar100_fp32 --batch_size 64 --warmup 1
@@ -154,21 +154,15 @@ serve-report:
 memory-report:
 	python -m tpu_dist.obs memory $(LOG)
 
-# The longitudinal-archive proof (docs/observability.md "Longitudinal
-# archive & trend gating"): rebuild the trend archive from the repo's
-# committed bench/multichip artifacts (must match the seeded
-# tools/bench_archive.jsonl record-for-record), gate the last-good
-# capture against its own rolling MAD band (exit 0 — a sane history
-# admits itself), render the trend + changepoint blame report, and run
-# the TD124 inject-regression self-test: a just-outside-band injection
-# must be CAUGHT per band, an improvement must pass, and the synthetic
-# changepoint must be localized — a dead detector exits 2:
-#   make trend-report [OUT=/tmp/trend_archive.jsonl]
+# Trend + changepoint-blame report over a longitudinal archive, then the
+# TD124 inject-regression self-test against it: a just-outside-band
+# injection must be CAUGHT per band, an improvement must pass, and the
+# synthetic changepoint must be localized — a dead detector exits 2
+# (docs/observability.md "Longitudinal archive & trend gating"):
+#   make trend-report ARCHIVE=bench_archive.jsonl
 trend-report:
-	python -m tpu_dist.obs archive ingest BENCH_r01.json BENCH_r02.json BENCH_r03.json BENCH_r04.json BENCH_r05.json MULTICHIP_r01.json MULTICHIP_r02.json MULTICHIP_r03.json MULTICHIP_r04.json MULTICHIP_r05.json LAST_GOOD_BENCH.json --archive $(or $(OUT),/tmp/trend_archive.jsonl)
-	python -m tpu_dist.obs compare --against-archive $(or $(OUT),/tmp/trend_archive.jsonl) --bench LAST_GOOD_BENCH.json
-	python -m tpu_dist.obs trend $(or $(OUT),/tmp/trend_archive.jsonl) --blame
-	python -m tpu_dist.obs trend $(or $(OUT),/tmp/trend_archive.jsonl) --inject-regression
+	python -m tpu_dist.obs trend $(ARCHIVE) --blame
+	python -m tpu_dist.obs trend $(ARCHIVE) --inject-regression
 
 # Follow a LIVE run from another terminal:
 #   make monitor LOG=run.jsonl [HB=hb.json]

@@ -5,9 +5,8 @@ healing, forward-compat newer-schema skip-with-count, MAD-band
 arithmetic against hand math, the ``compare --against-archive`` exit
 contract (0 in-band / 1 regressed / 2 when the gate compared nothing),
 CUSUM changepoint localization + ``--blame``, hub snapshot records,
-``bench.py --archive`` never-dies self-ingest, the seeded
-``tools/bench_archive.jsonl`` golden, and the TD124 noop gate with its
-vacuity guard. Everything here is host-side file arithmetic except the
+``bench.py --archive`` never-dies self-ingest, and the TD124 noop gate
+with its vacuity guard. Everything here is host-side file arithmetic except the
 TD124 jaxpr gate, which gates in the analysis.yml archive step too.
 """
 
@@ -16,8 +15,6 @@ import json
 import os
 
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 THROUGHPUT = "resnet18_cifar100_train_throughput"
 
@@ -449,63 +446,7 @@ def test_td124_probe_is_vacuity_guarded(monkeypatch):
     assert "VACUOUS" in vs[0].message or "dead" in vs[0].message
 
 
-# -- satellites: seeded archive, hub records, bench self-ingest, stamp --------
-
-
-def test_seeded_archive_golden_matches_committed_artifacts(monkeypatch):
-    """tools/bench_archive.jsonl is exactly what `obs archive ingest`
-    produces from the committed r01-r05 + last-good artifacts: 4 empty
-    STALE bench_probe rounds, 1 stale re-emission, 5 multichip points,
-    1 fresh last-good capture — rebuildable byte-for-record."""
-    from tpu_dist.obs import archive as archive_lib
-
-    monkeypatch.chdir(REPO)
-    committed, counts = archive_lib.load_archive(
-        os.path.join(REPO, "tools", "bench_archive.jsonl")
-    )
-    assert counts["bad_lines"] == 0 and counts["newer_schema"] == 0
-    assert len(committed) == 11
-    assert sum(1 for r in committed if r["stale"]) == 5
-    probes = [r for r in committed if r["label"] == "bench_probe"]
-    assert len(probes) == 4 and all(r["stale"] for r in probes)
-    fresh_bench = [
-        r for r in committed
-        if r["label"] == THROUGHPUT and not r["stale"]
-    ]
-    assert len(fresh_bench) == 1
-    assert fresh_bench[0]["metrics"]["value"] == pytest.approx(36438.2)
-    multi = [r for r in committed if r["label"] == "multichip_dryrun"]
-    assert len(multi) == 5
-    assert sum(r["metrics"]["multichip_ok"] for r in multi) == 4.0
-    # rebuild from the same inputs -> identical records (ignoring none)
-    import tempfile
-
-    with tempfile.TemporaryDirectory() as td:
-        arch = os.path.join(td, "rebuilt.jsonl")
-        inputs = (
-            [f"BENCH_r0{i}.json" for i in range(1, 6)]
-            + [f"MULTICHIP_r0{i}.json" for i in range(1, 6)]
-            + ["LAST_GOOD_BENCH.json"]
-        )
-        archive_lib.ingest_paths(inputs, arch)
-        rebuilt, _ = archive_lib.load_archive(arch)
-    assert rebuilt == committed
-
-
-def test_seeded_archive_self_gate_and_probe_pass(monkeypatch, capsys):
-    """The `make trend-report` contract: the last-good capture gates
-    in-band against the seeded archive (exit 0) and the TD124
-    inject-regression probe is alive (exit 0, not 2)."""
-    from tpu_dist.obs.__main__ import main as obs_main
-
-    monkeypatch.chdir(REPO)
-    arch = os.path.join("tools", "bench_archive.jsonl")
-    assert obs_main(
-        ["compare", "LAST_GOOD_BENCH.json", "--against-archive", arch,
-         "--bench"]
-    ) == 0
-    assert obs_main(["trend", arch, "--inject-regression"]) == 0
-    capsys.readouterr()
+# -- satellites: hub records, bench self-ingest, stamp -----------------------
 
 
 def test_hub_snapshot_record_and_append(tmp_path):

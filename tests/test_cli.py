@@ -14,10 +14,25 @@ from tpu_dist.cli import (
 
 
 def test_train_cli_constructs_trainer_and_runs_zero_epochs(capsys):
-    # epochs=0: full CLI -> config -> Trainer init path without jit compiles
-    train.main(["--epochs", "0", "--dataset", "synthetic", "--batch_size", "64"])
+    import jax
+
+    from tpu_dist import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # epochs=0: full CLI -> config -> Trainer init path without jit compiles
+        trainer = train.main(
+            ["--epochs", "0", "--dataset", "synthetic", "--batch_size", "64"]
+        )
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     out = capsys.readouterr().out
     assert "model=resnet18" in out and "devices=8" in out
+    # a run that landed on the CPU says so
+    assert "platform=cpu" in out and "device_kind='cpu'" in out
+    # the entry point asks for the in-checkout compile cache by default
+    assert trainer.cfg.compile_cache_dir == compile_cache.DEFAULT_DIR
 
 
 def test_presets_set_their_flags(monkeypatch):

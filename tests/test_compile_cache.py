@@ -1,5 +1,6 @@
 """Persistent compile cache: --compile_cache_dir populates an XLA cache a
-second invocation of the same config loads from (VERDICT r1 #8).
+second invocation of the same config loads from; the one helper that places
+the cache (tpu_dist/compile_cache.py) yields to the environment.
 
 The cache setting is process-global jax.config state (that is how XLA's
 persistent cache works); this test restores it afterwards so later tests in
@@ -32,6 +33,7 @@ def test_compile_cache_populated_and_reused(tmp_path):
     from jax._src import compilation_cache as _cc
 
     _cc.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", True)  # conftest: off
     try:
         t = Trainer(cfg)
         # the tiny model can compile in <1s; persist everything so the
@@ -57,6 +59,28 @@ def test_compile_cache_populated_and_reused(tmp_path):
                 continue
             assert os.path.getmtime(os.path.join(cache, e)) == t_
     finally:
+        jax.config.update("jax_enable_compilation_cache", False)
         jax.config.update("jax_compilation_cache_dir", None)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         _cc.reset_cache()  # later tests must not keep writing into tmp
+
+
+def test_enable_yields_to_env_and_is_stable_otherwise(monkeypatch):
+    from tpu_dist import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    assert compile_cache.enable() == "/placed/from/outside"
+    # --compile_cache_dir yields to it too
+    assert compile_cache.enable("/from/the/flag") == "/placed/from/outside"
+    assert jax.config.jax_compilation_cache_dir == before  # untouched
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        first, second = compile_cache.enable(), compile_cache.enable()
+        assert first == second == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)

@@ -172,18 +172,33 @@ class _FakeAnalyzable:
         return self._ma
 
 
-def test_chip_peak_flops_prefix_match_and_unknown():
-    assert costmodel.chip_peak_flops("TPU v4 lite") == pytest.approx(275e12)
-    assert costmodel.chip_peak_flops("TPU v5p slice") == pytest.approx(459e12)
-    # longest prefix wins: v5 lite must not fall through to bare v5
+def test_chip_peak_flops_exact_kind_and_unknown():
+    assert costmodel.chip_peak_flops("TPU v4") == pytest.approx(275e12)
     assert costmodel.chip_peak_flops("TPU v5 lite") == pytest.approx(197e12)
+    # an unknown v5-something is NOT priced as its nearest prefix (v5p)
+    assert costmodel.chip_peak_flops("TPU v5x") is None
+    assert costmodel.chip_peak_flops("TPU v4 lite") is None
     assert costmodel.chip_peak_flops("cpu") is None
     assert costmodel.chip_peak_flops("Tesla V100") is None
 
 
-def test_step_cost_normalizes_list_and_scales_trips():
-    # older jax: one dict per device in a list
-    obj = _FakeAnalyzable(ca=[{"flops": 100.0, "bytes accessed": 10.0}])
+def test_require_chip_row_refuses_cpu_and_unknown_tpu():
+    class Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    row = costmodel.require_chip_row(Dev("tpu", "TPU v5 lite"))
+    assert row == {
+        "kind": "TPU v5 lite", "peak_flops": 197e12, "hbm_bytes": 16 * 1024 ** 3,
+    }
+    with pytest.raises(RuntimeError, match="platform='cpu'"):
+        costmodel.require_chip_row(Dev("cpu", "cpu"))
+    with pytest.raises(RuntimeError, match="has no row"):
+        costmodel.require_chip_row(Dev("tpu", "TPU v5x"))
+
+
+def test_step_cost_scales_trips():
+    obj = _FakeAnalyzable(ca={"flops": 100.0, "bytes accessed": 10.0})
     assert costmodel.step_cost(obj, loop_trips=4) == {
         "flops_per_step": 400.0, "bytes_per_step": 40.0,
     }
@@ -261,9 +276,16 @@ def test_compile_watcher_degrades_without_cache_api():
     assert w.observe() is False and counters.get("compile.events") == 0
 
 
-def test_install_compile_listener_idempotent():
-    assert costmodel.install_compile_listener() is True
-    assert costmodel.install_compile_listener() is True
+def test_install_compile_listener_idempotent(monkeypatch):
+    from jax import monitoring
+
+    calls = []
+    costmodel.install_compile_listener()  # installed (here or earlier)
+    monkeypatch.setattr(
+        monitoring, "register_event_duration_secs_listener", calls.append
+    )
+    costmodel.install_compile_listener()
+    assert calls == []  # the second call registers nothing
 
 
 # -- anomaly detector --------------------------------------------------------

@@ -161,7 +161,8 @@ def test_device_memory_stats_none_on_statless_backend(monkeypatch):
 def test_chip_hbm_budget_table():
     gib = 1024 ** 3
     assert costmodel.chip_hbm_bytes("TPU v5e") == 16 * gib
-    assert costmodel.chip_hbm_bytes("TPU v5p chip") == 95 * gib
+    assert costmodel.chip_hbm_bytes("TPU v5p") == 95 * gib
+    assert costmodel.chip_hbm_bytes("TPU v5p chip") is None  # exact kinds only
     assert costmodel.chip_hbm_bytes("TPU v4") == 32 * gib
     assert costmodel.chip_hbm_bytes("cpu") is None  # never a guess
 

@@ -16,8 +16,6 @@ from __future__ import annotations
 
 import json
 import re
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -654,15 +652,13 @@ def test_serve_drill_e2e(tmp_path):
 
 
 @pytest.mark.slow
-def test_bench_serve_emits_fingerprinted_record(tmp_path):
-    out = subprocess.run(
-        [sys.executable, "bench.py", "--serve", "--serve_tiny",
-         "--serve_requests", "24", "--serve_max_batch", "4"],
-        capture_output=True, text=True, timeout=560,
-        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"},
+def test_bench_serve_emits_fingerprinted_record():
+    # in-process: ``bench.py`` itself refuses to measure without a chip
+    import bench
+
+    rec = bench.run_serve(
+        bench.CONFIGS["resnet18_cifar100"], 24, max_batch=4, tiny=True
     )
-    assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
     for field in ("requests_per_s", "latency_p50_ms", "latency_p99_ms",
                   "batch_occupancy"):
         assert isinstance(rec[field], (int, float)), field

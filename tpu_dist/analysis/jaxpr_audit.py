@@ -99,18 +99,9 @@ def registered_cases() -> list[str]:
 # --------------------------------------------------------------------------
 
 
-def _jaxpr_classes():
-    """(ClosedJaxpr, Jaxpr) wherever this jax keeps them — ``jax.core`` up
-    to 0.5.x, ``jax.extend.core`` afterwards."""
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr  # type: ignore
-    except ImportError:
-        from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
-    return ClosedJaxpr, Jaxpr
-
-
 def _sub_jaxprs(params: dict):
-    ClosedJaxpr, Jaxpr = _jaxpr_classes()
+    from jax.extend.core import ClosedJaxpr, Jaxpr  # noqa: PLC0415
+
     for v in params.values():
         vals = v if isinstance(v, (list, tuple)) else [v]
         for item in vals:

@@ -24,13 +24,21 @@ from tpu_dist.config import add_reference_flags, config_from_args
 from tpu_dist.metrics.logging import rank0_print
 
 
-def main(argv: Optional[Sequence[str]] = None, **preset) -> None:
+def main(argv: Optional[Sequence[str]] = None, **preset):
+    """Parse flags, build the :class:`Trainer`, ``fit()``; returns the
+    trainer so an in-process caller can inspect what the run left behind."""
     parser = argparse.ArgumentParser(
         description="tpu_dist trainer (TPU-native DDP-equivalent)"
     )
     add_reference_flags(parser)
     args = parser.parse_args(argv)
     cfg = config_from_args(args, **preset)
+    if cfg.compile_cache_dir is None:
+        # an entry point, so reruns should load compiled programs: the
+        # Trainer turns the cache on only when the config names a directory
+        from tpu_dist import compile_cache  # noqa: PLC0415
+
+        cfg = cfg.replace(compile_cache_dir=compile_cache.DEFAULT_DIR)
 
     from tpu_dist.resilience.preemption import (  # noqa: PLC0415
         PREEMPTION_EXIT_CODE,
@@ -40,8 +48,10 @@ def main(argv: Optional[Sequence[str]] = None, **preset) -> None:
 
     trainer = Trainer(cfg)
     cfg = trainer.cfg  # --auto_shard apply may have rewritten the config
+    dev0 = trainer.mesh.devices.flat[0]
     rank0_print(
         f"tpu_dist: model={cfg.model} devices={trainer.n_devices} "
+        f"platform={dev0.platform} device_kind={dev0.device_kind!r} "
         f"global_batch={cfg.batch_size} bf16={cfg.bf16} sync_bn={cfg.sync_bn} "
         f"grad_accu_steps={cfg.grad_accu_steps}"
     )
@@ -62,6 +72,7 @@ def main(argv: Optional[Sequence[str]] = None, **preset) -> None:
         # dying on the signal (launch.py propagates it)
         rank0_print(f"=> preempted: {e}; exiting {PREEMPTION_EXIT_CODE}")
         raise SystemExit(PREEMPTION_EXIT_CODE) from None
+    return trainer
 
 
 if __name__ == "__main__":

@@ -1,59 +1,7 @@
-"""Version adapters for JAX APIs that moved between releases.
+"""The one import site of ``shard_map`` (analysis rule TD004): everything
+else in ``tpu_dist`` imports it from here. The supported JAX is stated once,
+in the README's "Running" section."""
 
-This module is the ONE place allowed to touch version-fragile JAX import
-paths (analysis rule TD004 enforces it): everything else in ``tpu_dist``
-imports ``shard_map`` from here. The API has lived in three homes —
-``jax.experimental.shard_map`` (0.4.x), ``jax.shard_map`` (0.5+), with the
-replication-check kwarg renamed ``check_rep`` → ``check_vma`` along the way.
-Call sites use the NEWEST spelling (``check_vma=``); the wrapper translates
-down for older installs, so upgrading JAX never requires touching callers.
-"""
+from jax import shard_map
 
-from __future__ import annotations
-
-import functools
-import inspect
-
-try:  # JAX >= 0.5: promoted to the top-level namespace
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # JAX 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = inspect.signature(_shard_map).parameters
-_HAS_CHECK_VMA = "check_vma" in _PARAMS
-_HAS_CHECK_REP = "check_rep" in _PARAMS
-
-
-@functools.wraps(_shard_map)
-def shard_map(*args, **kwargs):
-    """``jax.shard_map`` with the modern keyword surface on any JAX.
-
-    Accepts ``check_vma=`` (and legacy ``check_rep=``) and forwards
-    whichever spelling the installed JAX understands; drops the kwarg
-    entirely if some future release removes both.
-    """
-    if "check_vma" in kwargs and "check_rep" in kwargs:
-        raise TypeError("pass check_vma or check_rep, not both")
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        if _HAS_CHECK_VMA:
-            kwargs["check_vma"] = check
-        elif _HAS_CHECK_REP:
-            kwargs["check_rep"] = check
-    return _shard_map(*args, **kwargs)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` on any JAX.
-
-    The named-axis size query only gained a public spelling in newer JAX;
-    on older installs ``psum(1, axis)`` computes the same value (folded to
-    a trace-time constant, no collective in the jaxpr)."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-__all__ = ["shard_map", "axis_size"]
+__all__ = ["shard_map"]

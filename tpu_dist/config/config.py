@@ -627,7 +627,9 @@ def add_reference_flags(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
     p.add_argument("--log_every", type=int, default=d.log_every)
     p.add_argument("--compile_cache_dir", type=str, default=None,
                    help="persistent XLA compile-cache dir (repeat runs skip "
-                        "the cold first compile)")
+                        "the cold first compile); default <checkout>/"
+                        ".jax_cache; JAX_COMPILATION_CACHE_DIR, when set, "
+                        "overrides both")
     # accepted for command-line parity with torch.distributed.launch; unused
     p.add_argument("--local_rank", type=int, default=0, help=argparse.SUPPRESS)
     p.add_argument("--gpu", type=str, default=None, help=argparse.SUPPRESS)

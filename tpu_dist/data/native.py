@@ -3,12 +3,12 @@
 The reference leans on native code for its input path (torchvision's C
 extensions + DataLoader worker processes, SURVEY §2.2 N7); this module is
 the TPU build's equivalent: a fused gather+pad+crop+normalize over the
-batch in multi-threaded C++. Falls back to the numpy implementation in
-``tpu_dist.data.transforms`` when the shared library isn't built.
-
-Build once with ``make -C tpu_dist/csrc`` — or let :func:`ensure_built`
-compile it on first use (cached; failures degrade to numpy silently but
-are reported by :func:`available`).
+batch in multi-threaded C++. The shared library is built from
+``csrc/pipeline.cpp`` on the machine that runs (``make`` on first use — a
+no-op when the build is newer than the source), never shipped. Where it
+cannot be built or loaded, the numpy implementation in
+``tpu_dist.data.transforms`` runs instead — same semantics, different crop
+offsets — with a warning; :func:`path_in_use` says which one a run got.
 """
 
 from __future__ import annotations
@@ -17,6 +17,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional
 
 import numpy as np
@@ -29,6 +30,17 @@ _SO = os.path.join(_CSRC, "build", "libtpu_dist_pipeline.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_why_numpy = ""  # why the native path is not in use, once _load has failed
+
+
+def _give_up(reason: str) -> None:
+    global _why_numpy
+    _why_numpy = reason
+    warnings.warn(
+        f"native input pipeline unavailable ({reason}); using the numpy "
+        "path, which draws different crop offsets",
+        RuntimeWarning, stacklevel=3,
+    )
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -37,16 +49,16 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO):
-            try:  # build on first use; tolerate missing toolchain
-                subprocess.run(
-                    ["make", "-C", _CSRC],
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-            except Exception:
-                return None
+        try:  # (re)build from source; tolerate a missing toolchain
+            subprocess.run(
+                ["make", "-C", _CSRC],
+                check=True,
+                capture_output=True,
+                timeout=120,
+            )
+        except (OSError, subprocess.SubprocessError) as e:
+            _give_up(f"build failed: {type(e).__name__}: {e}")
+            return None
         try:
             lib = ctypes.CDLL(_SO)
             lib.tpu_dist_augment_batch.restype = ctypes.c_int
@@ -63,17 +75,24 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_int,                     # n_threads
             ]
             if lib.tpu_dist_pipeline_abi_version() != 1:
+                _give_up("ABI version mismatch")
                 return None
             _lib = lib
-        except (OSError, AttributeError):
-            # AttributeError: a stale/foreign .so missing our symbols — the
-            # promised silent numpy fallback must cover that case too.
+        except (OSError, AttributeError) as e:
+            # AttributeError: a stale/foreign .so missing our symbols
+            _give_up(f"load failed: {type(e).__name__}: {e}")
             return None
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def path_in_use() -> str:
+    """``"native"`` or ``"numpy (<why>)"`` — which augment path this
+    process runs (building/loading the library if that has not happened)."""
+    return "native" if _load() is not None else f"numpy ({_why_numpy})"
 
 
 def gather_augment(

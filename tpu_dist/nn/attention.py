@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tpu_dist.comm import compat
-
 # Process-global default for the single-device attention implementation.
 # "xla": one fused einsum/softmax chain ([S,S] scores in HBM — fine at ViT
 # lengths). "flash": the Pallas tiled kernel (ops/flash_attention.py) —
@@ -91,7 +89,7 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False):
     ``causal`` masks by GLOBAL position: block order on the axis is the
     sequence order (device i holds positions [i·S/n, (i+1)·S/n)).
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale = 1.0 / jnp.sqrt(jnp.float32(d))
@@ -156,7 +154,7 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
     ``impl="flash"`` (or the process default) runs the tiled kernel on the
     gathered sequence — flash × SP with no extra machinery.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % n:
         raise ValueError(

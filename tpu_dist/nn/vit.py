@@ -21,8 +21,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from tpu_dist.comm import compat
-
 from tpu_dist.nn import attention as attn_lib
 
 
@@ -229,7 +227,7 @@ class ViTDef:
                 # x arrived replicated over the seq axis: each device keeps
                 # only its contiguous token chunk (ring attention owns the
                 # cross-chunk interaction)
-                n_sp = compat.axis_size(seq_axis)
+                n_sp = jax.lax.axis_size(seq_axis)
                 if tokens.shape[1] % n_sp:
                     raise ValueError(
                         f"sequence of {tokens.shape[1]} patch tokens does not "

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tpu_dist.comm import compat
-
 from tpu_dist.nn.vit import (
     ViTDef,
     _dense,
@@ -244,7 +242,7 @@ class ViTPipelineDef:
             t = self._stage_scan(blocks, t, attn_impl, tp_axis)
             return self._finish(params, t), state
 
-        n_stages = compat.axis_size(pp_axis)
+        n_stages = lax.axis_size(pp_axis)
         if self.interleave > 1 and self.pp_stages != n_stages:
             raise ValueError(
                 f"model laid out for pp_stages={self.pp_stages}, mesh has "

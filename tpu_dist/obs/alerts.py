@@ -41,10 +41,7 @@ Spec grammar (TOML shown; JSON is the same shape as a list under
     threshold = 0.4                # ...and override fields
 
 ``--alert_rules default`` loads the whole built-in library unmodified.
-Stdlib-only: Python 3.11+ parses TOML with ``tomllib``; older
-interpreters fall back to a built-in parser for exactly the flat
-``[[rule]]`` grammar above (the spec's own subset — anything fancier
-says "use JSON" rather than half-parsing).
+Stdlib-only: TOML is parsed with ``tomllib``.
 """
 
 from __future__ import annotations
@@ -183,51 +180,6 @@ def _rule_from_dict(
     return AlertRule(**d)
 
 
-def _parse_toml_minimal(text: str, path: str) -> List[dict]:
-    """The fallback TOML reader for interpreters without ``tomllib``
-    (< 3.11): exactly the flat ``[[rule]]`` grammar the spec documents —
-    comments, bare ``key = value`` scalars (quoted string / number /
-    bool).  Anything else raises with a pointer to the JSON spec form
-    rather than half-parsing."""
-    rules: List[dict] = []
-    cur: Optional[dict] = None
-    for ln, raw in enumerate(text.splitlines(), start=1):
-        line = raw.split("#", 1)[0].strip()
-        if not line:
-            continue
-        if line == "[[rule]]":
-            cur = {}
-            rules.append(cur)
-            continue
-        if "=" in line and cur is not None:
-            key, _, val = line.partition("=")
-            key, val = key.strip(), val.strip()
-            if val.startswith('"') and val.endswith('"') and len(val) >= 2:
-                cur[key] = val[1:-1]
-            elif val in ("true", "false"):
-                cur[key] = val == "true"
-            else:
-                try:
-                    cur[key] = int(val)
-                except ValueError:
-                    try:
-                        cur[key] = float(val)
-                    except ValueError:
-                        raise ValueError(
-                            f"{path}:{ln}: unsupported TOML value {val!r} "
-                            "(this interpreter has no tomllib; the built-in "
-                            "reader takes strings/numbers/bools only — or "
-                            "use the JSON spec form)"
-                        ) from None
-            continue
-        raise ValueError(
-            f"{path}:{ln}: unsupported TOML construct {line!r} (the spec "
-            "grammar is [[rule]] tables of scalar key = value lines; use "
-            "the JSON form for anything else)"
-        )
-    return rules
-
-
 def load_rules(
     spec: str, builtins: Optional[Dict[str, AlertRule]] = None
 ) -> List[AlertRule]:
@@ -247,12 +199,9 @@ def load_rules(
     elif spec.endswith(".toml"):
         with open(spec) as f:
             text = f.read()
-        try:
-            import tomllib  # noqa: PLC0415 — 3.11+
+        import tomllib  # noqa: PLC0415
 
-            raw = tomllib.loads(text).get("rule")
-        except ImportError:
-            raw = _parse_toml_minimal(text, spec)
+        raw = tomllib.loads(text).get("rule")
     else:
         raise ValueError(
             f"--alert_rules must be 'default' or a .toml/.json spec path, "

@@ -30,7 +30,9 @@ from typing import Optional
 from tpu_dist.obs import counters as counters_lib
 
 # Peak dense matmul FLOP/s per chip (bf16), the MFU denominator. Public
-# spec-sheet numbers; longest-prefix matched against ``device_kind``.
+# spec-sheet numbers, keyed by the exact ``device_kind`` JAX reports (plus
+# the marketing spellings): a kind that is not here has no row, it does not
+# borrow a neighbour's.
 CHIP_PEAK_FLOPS = {
     "TPU v2": 45e12,
     "TPU v3": 123e12,
@@ -47,8 +49,8 @@ _GIB = 1024 ** 3
 # HBM bytes per jax device (public spec-sheet numbers; a "device" is one
 # core on v2/v3 and one megacore chip from v4 on — exactly what
 # ``jax.devices()`` enumerates, so the budget divides the way shardings
-# do). Longest-prefix matched like the FLOP table; the pre-flight memory
-# lint (``obs/memory.py::preflight_check``) prices configs against this.
+# do). Keyed like the FLOP table; the pre-flight memory lint
+# (``obs/memory.py::preflight_check``) prices configs against this.
 CHIP_HBM_BYTES = {
     "TPU v2": 8 * _GIB,
     "TPU v3": 16 * _GIB,
@@ -67,10 +69,31 @@ def _chip_lookup(table: dict, kind: Optional[str]):
         import jax  # noqa: PLC0415
 
         kind = jax.devices()[0].device_kind
-    for name, val in sorted(table.items(), key=lambda kv: -len(kv[0])):
-        if kind.startswith(name):
-            return val
-    return None
+    return table.get(kind)
+
+
+def require_chip_row(device) -> dict:
+    """The table row a MEASUREMENT path prices ``device`` with —
+    ``{"kind", "peak_flops", "hbm_bytes"}``. Raises when the device is not
+    a TPU or its kind has no row: ``bench.py`` and ``chip_smoke.py`` fail
+    there rather than report utilization against a missing or borrowed
+    peak (the trainer's own MFU stays ``None`` on unknown kinds)."""
+    kind = device.device_kind
+    if device.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: jax.devices()[0] is platform={device.platform!r} "
+            f"kind={kind!r}"
+        )
+    if kind not in CHIP_PEAK_FLOPS or kind not in CHIP_HBM_BYTES:
+        raise RuntimeError(
+            f"device_kind {kind!r} has no row in costmodel.CHIP_PEAK_FLOPS/"
+            "CHIP_HBM_BYTES — add its published peaks before measuring on it"
+        )
+    return {
+        "kind": kind,
+        "peak_flops": CHIP_PEAK_FLOPS[kind],
+        "hbm_bytes": CHIP_HBM_BYTES[kind],
+    }
 
 
 def chip_peak_flops(kind: Optional[str] = None) -> Optional[float]:
@@ -86,15 +109,6 @@ def chip_hbm_bytes(kind: Optional[str] = None) -> Optional[int]:
     return _chip_lookup(CHIP_HBM_BYTES, kind)
 
 
-def _cost_dict(obj) -> dict:
-    """``cost_analysis()`` of a Lowered/Compiled, normalized to one dict
-    (older jax returns a one-element list per device)."""
-    ca = obj.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
-
-
 def step_cost(obj, loop_trips: int = 1) -> dict:
     """``{"flops_per_step", "bytes_per_step"}`` of one compiled/lowered
     step (either may be None when XLA reports nothing useful).
@@ -105,7 +119,7 @@ def step_cost(obj, loop_trips: int = 1) -> dict:
     the whole count errs by at most the loop-external ops (a few %,
     overestimating trips-1 copies of them)."""
     try:
-        ca = _cost_dict(obj)
+        ca = obj.cost_analysis() or {}
     except Exception:
         return {"flops_per_step": None, "bytes_per_step": None}
 
@@ -281,17 +295,27 @@ def memory_analysis_jitted(jitted, *args) -> Optional[dict]:
 
 
 def analyze_jitted(jitted, *args, loop_trips: int = 1) -> Optional[dict]:
-    """Cost-analyze a ``jax.jit``-wrapped step WITHOUT compiling it twice:
-    ``jitted.lower(*args)`` re-traces abstractly (host-only, no device
-    dispatch, no XLA compile) and ``Lowered.cost_analysis()`` runs the HLO
-    cost model over the traced module. Returns :func:`step_cost`'s dict,
-    or None when lowering/analysis is unavailable — callers degrade to
-    "no MFU", never to an error."""
+    """Cost-analyze a ``jax.jit``-wrapped step. ``jitted.lower(*args)``
+    re-traces abstractly (host-only, no device dispatch) and, where the
+    backend can, ``Lowered.cost_analysis()`` runs the HLO cost model over
+    the traced module without compiling. The TPU client cannot (measured:
+    PR 21, it raises for PJRT plugins): there only the COMPILED executable
+    reports, so the step is AOT-compiled once more — a cache load where the
+    persistent compile cache is on. Returns :func:`step_cost`'s dict, or
+    None when lowering is unavailable — callers degrade to "no MFU",
+    never to an error."""
     try:
         lowered = jitted.lower(*args)
     except Exception:
         return None
-    return step_cost(lowered, loop_trips)
+    cost = step_cost(lowered, loop_trips)
+    if cost["flops_per_step"] is None:
+        try:
+            compiled = lowered.compile()
+        except Exception:
+            return cost
+        cost = step_cost(compiled, loop_trips)
+    return cost
 
 
 class CompileWatcher:
@@ -381,32 +405,27 @@ class CompileWatcher:
 _LISTENER_INSTALLED = False
 
 
-def install_compile_listener() -> bool:
+def install_compile_listener() -> None:
     """Accumulate XLA's own backend-compile wall time into the
     ``compile.seconds`` counter via ``jax.monitoring`` (fires for every
     compile in the process — train step, eval step, fused paths alike).
     Idempotent; jax offers no unregistration, so ONE process-lifetime
-    listener feeds the process-global counter registry. Returns whether
-    the listener is (now) installed; False on a jax without the API."""
+    listener feeds the process-global counter registry."""
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
-        return True
-    try:
-        from jax import monitoring  # noqa: PLC0415
+        return
+    from jax import monitoring  # noqa: PLC0415
 
-        def _on_event(event: str, duration: float, **kw) -> None:
-            # backend_compile ONLY: one jit compile also fires nested
-            # jaxpr_trace / jaxpr_to_mlir_module duration events whose
-            # wall times overlap it — summing every "compile"-ish event
-            # would over-count real elapsed time severalfold
-            if "backend_compile" in event:
-                counters_lib.inc("compile.seconds", round(float(duration), 3))
+    def _on_event(event: str, duration: float, **kw) -> None:
+        # backend_compile ONLY: one jit compile also fires nested
+        # jaxpr_trace / jaxpr_to_mlir_module duration events whose
+        # wall times overlap it — summing every "compile"-ish event
+        # would over-count real elapsed time severalfold
+        if "backend_compile" in event:
+            counters_lib.inc("compile.seconds", round(float(duration), 3))
 
-        monitoring.register_event_duration_secs_listener(_on_event)
-    except Exception:
-        return False
+    monitoring.register_event_duration_secs_listener(_on_event)
     _LISTENER_INSTALLED = True
-    return True
 
 
 def _sig(v: float, digits: int = 4) -> float:

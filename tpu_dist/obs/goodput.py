@@ -33,7 +33,7 @@ Buckets (field ``<name>_s`` in every record):
 * ``unattributed`` — whatever remains; never hidden, so a growing
   remainder is itself a finding.
 
-Two halves share the bucket taxonomy:
+Two halves share the bucket set:
 
 * **Live** (:class:`GoodputLedger`) — the Trainer attributes seconds as
   they happen and emits one ``goodput`` history record per epoch window

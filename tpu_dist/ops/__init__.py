@@ -1,1 +1,1 @@
-from tpu_dist.ops.fused_sgd import fused_sgd_leaf, pallas_supported  # noqa: F401
+from tpu_dist.ops.fused_sgd import fused_sgd_leaf  # noqa: F401

@@ -24,11 +24,12 @@ pass gridded over k-blocks accumulating across q-blocks in VMEM scratch,
 and a dQ pass gridded the other way — probabilities recomputed blockwise
 from the saved (m, l) statistics, O(block²) working set, never
 materializing [S, S]. The original XLA-level ``lax.scan`` formulation is
-kept behind ``bwd='xla'`` for A/B comparison and as a fallback.
+kept behind ``bwd='xla'`` for A/B comparison; nothing selects it on its own.
 
-Works on any backend via Pallas interpret mode (auto-selected off-TPU),
-which is how the CPU test suite checks it bit-for-bit against the XLA
-path (``tests/test_flash_attention.py``).
+Off-TPU the kernels run in Pallas interpret mode (auto-selected), which is
+how the CPU test suite checks them against the XLA path
+(``tests/test_flash_attention.py``); on the chip they compile
+(``chip_smoke.py`` checks that, and the same comparison, there).
 """
 
 from __future__ import annotations
@@ -39,20 +40,30 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from tpu_dist.comm import compat
-
-try:  # pallas TPU backend is optional at import time (CPU test images)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-# renamed TPUCompilerParams -> CompilerParams across JAX releases
-_CompilerParams = pltpu and (
-    getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-)
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
+_LANES = 128      # lane width: the last dim of every VMEM tile
+
+# Per-row statistics (m, l, delta) cross the kernel boundary LANE-BROADCAST,
+# as [BH, S, _LANES] arrays in (1, block_q, _LANES) blocks. The Pallas TPU
+# lowering refuses a (1, block_q) block over a [BH, S] array (v5e, PR 21: the
+# last two block dims must divide by (8, 128) or equal the array's).
+# Lane-broadcast, any block_q that is a multiple of 8 is legal and a statistic
+# loads as the column it is used as; the price is 128x the statistics' HBM
+# traffic. The wrappers slice / broadcast at the call boundary, so residuals
+# and the ring merge stay [BH, S].
+
+
+def _col(stat):
+    """[block_q, _LANES] lane-broadcast statistic -> its [block_q, 1] column."""
+    return stat[:, :1]
+
+
+def _lane_broadcast(stat, block_q):
+    """[BH, S] statistic -> [BH, S padded to block_q, _LANES] kernel input."""
+    stat = _pad_to(stat, block_q, 1)
+    return jnp.broadcast_to(stat[..., None], (*stat.shape, _LANES))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -82,8 +93,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             mask = jnp.logical_and(mask, q_pos >= k_pos)
         s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_scr[:, :1]                             # [bq, 1]
-        l_prev = l_scr[:, :1]
+        m_prev = _col(m_scr[:])                           # [bq, 1]
+        l_prev = _col(l_scr[:])
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -105,10 +116,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     @pl.when(j == n_k - 1)
     def _finish():
-        l_fin = l_scr[:, :1]
+        l_fin = _col(l_scr[:])
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_fin, 1e-30)).astype(out_dtype)
-        m_ref[0] = m_scr[:, 0]
-        l_ref[0] = l_scr[:, 0]
+        m_ref[0] = m_scr[:]
+        l_ref[0] = l_scr[:]
 
 
 def _pad_to(x, mult, axis):
@@ -126,11 +137,6 @@ def _fwd(q3, k3, v3, causal, block_q, block_k, interpret, out_dtype=None):
     ``out_dtype`` overrides the output dtype (default: ``q3.dtype``) — the
     ring composition asks for f32 so per-rotation partials merge without a
     bf16 quantization per rotation."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "flash_attention requires jax.experimental.pallas.tpu (even in "
-            "interpret mode) — use the XLA path (nn.attention.full_attention)"
-        )
     bh, s_q, d = q3.shape
     s_kv = k3.shape[1]
     bq = min(block_q, -(-s_q // 8) * 8)   # block ≤ padded length, 8-row tiles
@@ -159,28 +165,28 @@ def _fwd(q3, k3, v3, causal, block_q, block_k, interpret, out_dtype=None):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i), **mem),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i), **mem),
+            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0), **mem),
+            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0), **mem),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(qp.shape, odt),
-            jax.ShapeDtypeStruct(qp.shape[:2], jnp.float32),
-            jax.ShapeDtypeStruct(qp.shape[:2], jnp.float32),
+            jax.ShapeDtypeStruct((*qp.shape[:2], _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((*qp.shape[:2], _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
         # only the innermost (k-block) dim carries softmax state between
         # iterations; batch·heads and q-blocks are free for the TPU to
-        # parallelize/pipeline (ADVICE r2)
-        compiler_params=_CompilerParams(
+        # parallelize/pipeline
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :s_q], m[:, :s_q], l[:, :s_q]
+    return out[:, :s_q], m[:, :s_q, 0], l[:, :s_q, 0]
 
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
@@ -205,13 +211,13 @@ def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
     if causal:
         mask = jnp.logical_and(mask, q_pos >= k_pos)
 
-    m_i = m_ref[0][:, None]                                # [bq, 1]
-    l_i = jnp.maximum(l_ref[0][:, None], 1e-30)
+    m_i = _col(m_ref[0])                                   # [bq, 1]
+    l_i = jnp.maximum(_col(l_ref[0]), 1e-30)
     p = jnp.where(mask, jnp.exp(s - m_i), 0.0) / l_i       # [bq, bk]
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                                      # [bq, bk]
-    ds = p * (dp - delta_ref[0][:, None]) * scale
+    ds = p * (dp - _col(delta_ref[0])) * scale
     return q, do, p, ds
 
 
@@ -301,8 +307,7 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
                 delta=None, grad_dtype=None):
     """Pallas FlashAttention-2 backward: two tiled passes (dK/dV then dQ),
     O(block²) VMEM working set, never materializing [S, S] — the TPU-kernel
-    sibling of the XLA-level ``_bwd_blocked`` (kept for A/B and as the
-    ``bwd='xla'`` escape hatch).
+    sibling of the XLA-level ``_bwd_blocked`` (``bwd='xla'``, kept for A/B).
 
     ``delta`` (rowsum(do·o), [BH, S]) may be passed precomputed — the ring
     backward hoists it out of its rotation scan (it is K/V-independent).
@@ -327,9 +332,9 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
         )                                                  # [BH, S]
     qp = _pad_to(q3, bq, 1)
     dop = _pad_to(do3, bq, 1)
-    mp = _pad_to(m, bq, 1)
-    lp = _pad_to(l, bq, 1)
-    deltap = _pad_to(delta, bq, 1)
+    mp = _lane_broadcast(m, bq)
+    lp = _lane_broadcast(l, bq)
+    deltap = _lane_broadcast(delta, bq)
     kp = _pad_to(k3, bk, 1)
     vp = _pad_to(v3, bk, 1)
     n_q = qp.shape[1] // bq
@@ -339,9 +344,9 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
     q_specs = [
         pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0), **mem),  # q
         pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0), **mem),  # do
-        pl.BlockSpec((1, bq), lambda b, j, i: (b, i), **mem),        # m
-        pl.BlockSpec((1, bq), lambda b, j, i: (b, i), **mem),        # l
-        pl.BlockSpec((1, bq), lambda b, j, i: (b, i), **mem),        # delta
+        pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0), **mem),  # m
+        pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0), **mem),  # l
+        pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0), **mem),  # delta
     ]
     kv_specs = [
         pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), **mem),  # k
@@ -367,7 +372,7 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -384,16 +389,16 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **mem),  # v
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),  # q
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),  # do
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i), **mem),        # m
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i), **mem),        # l
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i), **mem),        # delta
+            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0), **mem),  # m
+            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0), **mem),  # l
+            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0), **mem),  # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),
         ],
         out_shape=[jax.ShapeDtypeStruct(qp.shape, dq_dtype)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -470,11 +475,6 @@ def _flash_bwd(causal, block_q, block_k, interpret, bwd, res, do3):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_supported() -> bool:
-    """True when the Pallas TPU backend imported (interpret mode included)."""
-    return pltpu is not None
-
-
 # ---------------------------------------------------------------------------
 # Ring flash attention: the Pallas kernels composed with sequence-parallel
 # K/V rotation (the ring-attention scheme of nn/attention.py), so BOTH
@@ -534,7 +534,7 @@ def _ring_flash(q3, k3, v3, axis_name, causal, block_q, block_k, interpret):
 
 def _ring_flash_fwd_impl(q3, k3, v3, axis_name, causal, block_q, block_k,
                          interpret):
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     bh, s_q, d = q3.shape
     full, diag, masked = _fwd_variants(q3, k3, v3, block_q, block_k, interpret)
@@ -576,7 +576,7 @@ def _ring_flash_fwd(q3, k3, v3, axis_name, causal, block_q, block_k, interpret):
 
 def _ring_flash_bwd(axis_name, causal, block_q, block_k, interpret, res, do3):
     q3, k3, v3, o3, m, l = res
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     # delta is K/V-independent: compute ONCE, not per rotation
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1)

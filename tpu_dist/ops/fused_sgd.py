@@ -23,18 +23,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is optional at import time
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _SUBLANES = 512  # (512, 128) f32 block = 256 KiB/ref; 5 refs ≈ 1.3 MiB VMEM
-
-
-def pallas_supported() -> bool:
-    return pltpu is not None
 
 
 def _kernel(lr_ref, p_ref, g_ref, b_ref, out_p_ref, out_b_ref, *, momentum, weight_decay):
@@ -53,12 +45,6 @@ def fused_sgd_leaf(p, g, b, lr, *, momentum: float = 0.9, weight_decay: float = 
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if not pallas_supported():
-        raise RuntimeError(
-            "fused SGD requires jax.experimental.pallas.tpu, which failed to "
-            "import in this environment — use SGD(fused=False) (the plain jnp "
-            "update; bit-comparable, see tests/test_fused_sgd.py)"
-        )
 
     orig_shape, orig_dtype = p.shape, p.dtype
     n = p.size

@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tpu_dist.comm import compat
-
 
 @dataclass(frozen=True)
 class MoE:
@@ -85,7 +83,7 @@ class MoE:
         devices' slots for ITS experts, the local experts run, and the
         reverse ``all_to_all`` + combine restores token order.
         """
-        n = compat.axis_size(axis)
+        n = lax.axis_size(axis)
         T_loc, d = x.shape
         E = self.n_experts
         e_loc = E // n

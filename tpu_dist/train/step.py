@@ -38,7 +38,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_dist.comm import mesh as mesh_lib
-from tpu_dist.comm import compat
 from tpu_dist.comm.compat import shard_map
 from tpu_dist.nn import functional as F
 from tpu_dist.train.state import TrainState
@@ -247,7 +246,7 @@ def quantized_pmean_flat(grads, axis: str, *, key, ef, chunk: int):
         quantize_int8,
     )
 
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     flat, unravel = ravel_pytree(grads)
     L = flat.shape[0]
     P_len = padded_len(L, n)
@@ -710,7 +709,7 @@ def make_train_step(
         contributions (n_ep× scaled) → pmean over data, divide by n_ep;
         replicated leaves are plain per-shard grads → pmean over both axes.
         """
-        n_ep = compat.axis_size(ep_axis)
+        n_ep = lax.axis_size(ep_axis)
 
         def has_ep(spec):
             return any(

@@ -33,6 +33,7 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 from tpu_dist import ckpt as ckpt_lib
+from tpu_dist import compile_cache
 from tpu_dist.comm import mesh as mesh_lib
 from tpu_dist.config import TrainConfig
 from tpu_dist.data import (
@@ -110,25 +111,6 @@ def build_model(cfg: TrainConfig):
 class Trainer:
     def __init__(self, cfg: TrainConfig, mesh=None):
         self.cfg = cfg
-        # One-TPU-process rule (BENCH_NOTES rounds 1-2): claim the machine
-        # lock BEFORE the first backend touch below; no-op on CPU configs.
-        # Released on a failed construction (e.g. a config-validation raise)
-        # so a caught ValueError doesn't hold the TPU for the process life.
-        # acquire() refcounts reentrant claims (ADVICE r3), so this release
-        # gives back only the Trainer's claim — an outer holder (bench.py,
-        # __graft_entry__) keeps the machine-wide lock.
-        from tpu_dist.comm import tpu_lock  # noqa: PLC0415
-
-        self._tpu_lock = tpu_lock.acquire(owner="trainer")
-        try:
-            self._init_impl(cfg, mesh)
-        except BaseException:
-            if self._tpu_lock is not None:
-                self._tpu_lock.release()
-                self._tpu_lock = None
-            raise
-
-    def _init_impl(self, cfg: TrainConfig, mesh):
         # the telemetry counter registry is process-global and a "run" is
         # one Trainer's lifetime (run_id is stamped per construction, so
         # repeated fit() calls on one instance share it): start the
@@ -147,10 +129,10 @@ class Trainer:
         # the ledger's compile bucket (per-epoch counter deltas)
         costmodel_lib.install_compile_listener()
         if cfg.compile_cache_dir:
-            # persistent XLA compile cache (VERDICT r1 #8): a rerun of the
-            # same config loads compiled programs instead of recompiling
-            jax.config.update("jax_compilation_cache_dir", cfg.compile_cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+            # persistent XLA compile cache: a rerun of the same config
+            # loads compiled programs instead of recompiling. Off unless
+            # asked for — the CLI entry point asks (cli/train.py).
+            compile_cache.enable(cfg.compile_cache_dir)
         mesh_lib.initialize_distributed(
             coordinator_address=cfg.coordinator_address if cfg.num_processes else None,
             num_processes=cfg.num_processes,
