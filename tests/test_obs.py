@@ -504,3 +504,178 @@ def test_e2e_short_run_summarize_reports_everything(tmp_path, capsys):
     names = {e["name"] for e in trace["traceEvents"]}
     assert "train/dispatch" in names or "train/compile+dispatch" in names
     assert "ckpt/write" in names
+
+
+# -- the input path and the epoch boundary (ISSUE 24) -------------------------
+
+
+def _by_name(evts):
+    out = {}
+    for e in evts:
+        out.setdefault(e["name"], []).append(e)
+    return out
+
+
+def _at(evts):
+    return {(e["args"]["epoch"], e["args"]["step"]) for e in evts}
+
+
+def test_add_timed_feeds_the_counter_always_and_the_span_when_on():
+    import time
+
+    t0 = time.perf_counter()
+    t1 = spans.add_timed("x/region", "x.region_s", t0, epoch=3)
+    assert t1 >= t0 and counters.get("x.region_s") == pytest.approx(t1 - t0)
+    assert spans.events() == []  # recorder off: the counter alone
+    spans.enable()
+    t2 = spans.add_timed("x/region", "x.region_s", t1, epoch=3)
+    spans.add_timed("x/uncounted", None, t2)
+    assert counters.get("x.region_s") == pytest.approx(t2 - t0)
+    (ev, _) = spans.events()
+    assert ev["name"] == "x/region" and ev["args"] == {"epoch": 3}
+    assert ev["dur"] == pytest.approx((t2 - t1) * 1e6, abs=0.2)
+
+
+def test_clock_anchor_roundtrips_through_chrome_trace():
+    import time
+
+    spans.enable()
+    lo = time.time_ns()
+    t = time.perf_counter()
+    spans.add_event("tick", t, 0.0)
+    hi = time.time_ns()
+    anchor = spans.clock_anchor()
+    trace = json.loads(json.dumps(spans.to_chrome_trace()))
+    assert trace["metadata"]["tpu_dist_clock_anchor"] == anchor
+    # an event's ts (us since the origin) lands on the wall clock through it
+    unix_ns = anchor["unix_ns_at_origin"] + trace["traceEvents"][0]["ts"] * 1e3
+    assert lo - 1e6 <= unix_ns <= hi + 1e6  # perf_counter vs time_ns: < 1 ms
+    # fresh=False keeps origin and anchor (the TD106 audit re-arms this way)
+    spans.enable(fresh=False)
+    assert spans.clock_anchor() == anchor
+
+
+def test_loader_producer_split_counters_and_spans():
+    """gather / h2d / queue_full are timed on the producer thread, always
+    into counters and, recorder on, into spans keyed by (epoch, step)."""
+    from tpu_dist.comm import mesh as mesh_lib
+    from tpu_dist.data import DataLoader, DistributedSampler
+
+    n, bs = 64, 16
+    images = np.random.default_rng(0).normal(size=(n, 4, 4, 3)).astype(np.float32)
+    labels = np.zeros(n, np.int32)
+    sampler = DistributedSampler(n, 1, 0, shuffle=False)
+    loader = DataLoader(images, labels, bs, sampler, mesh_lib.data_parallel_mesh())
+    sampler.set_epoch(0)
+    assert len(list(loader)) == 4
+    assert spans.events() == []  # recorder off
+    for name in ("loader.gather_s", "loader.h2d_s"):
+        assert counters.get(name) > 0, name
+    assert counters.get("loader.h2d_bytes") == images.nbytes + labels.nbytes
+    spans.enable()
+    sampler.set_epoch(5)
+    for _ in loader.iter_from(1):  # mid-epoch resume keeps the batch's index
+        pass
+    got = _by_name(spans.events())
+    assert set(got) == {"loader/gather", "loader/h2d", "loader/queue_full"}
+    for evts in got.values():
+        assert _at(evts) == {(5, 1), (5, 2), (5, 3)}
+    # one clock read per boundary: the three regions tile the thread's life
+    evts = sorted(spans.events(), key=lambda e: e["ts"])
+    for a, b in zip(evts, evts[1:]):
+        assert b["ts"] == pytest.approx(a["ts"] + a["dur"], abs=0.3)
+
+
+@pytest.fixture(scope="module")
+def boundary_trainer():
+    """One micro-model Trainer for the epoch-boundary tests (its build and
+    first compile are most of their cost)."""
+    from tests.helpers import TinyConvNet
+    from tpu_dist.config import TrainConfig
+    from tpu_dist.train import trainer as trainer_mod
+
+    trainer_mod.register_model(
+        "tiny_obs_boundary", lambda num_classes=10: TinyConvNet(num_classes)
+    )
+    cfg = TrainConfig(
+        dataset="synthetic", model="tiny_obs_boundary", num_classes=10,
+        batch_size=64, epochs=8, eval_every=0, synthetic_n=320, log_every=2,
+        seed=0,
+    )
+    return trainer_mod.Trainer(cfg)
+
+
+BOUNDARY_COUNTERS = (
+    "train.epoch_head_s", "train.epoch_refill_s", "train.epoch_drain_s",
+    "train.epoch_tail_s",
+)
+
+
+def test_train_epoch_boundary_spans_join_and_add_up(boundary_trainer, capsys):
+    spans.enable()
+    for epoch in (0, 1):
+        assert boundary_trainer.train_epoch(epoch)["steps"] == 5
+    for name in BOUNDARY_COUNTERS + ("loader.gather_s", "loader.h2d_s"):
+        assert counters.get(name) > 0, name
+    got = _by_name(spans.events())
+    for name, evts in got.items():  # every span says where it belongs
+        assert all("epoch" in e["args"] for e in evts), name
+    # (epoch, step) joins the producer thread's batch to the loop's step
+    steps = {(e, s) for e in (0, 1) for s in range(5)}
+    dispatches = got["train/dispatch"] + got.get("train/compile+dispatch", [])
+    for evts in (got["loader/gather"], got["loader/h2d"], got["train/data_wait"], dispatches):
+        assert _at(evts) == steps
+    assert _at(got["train/host_fetch"]) == {(e, s) for e in (0, 1) for s in (0, 2, 4)}
+    assert {e["tid"] for e in got["loader/h2d"]}.isdisjoint(
+        e["tid"] for e in got["train/data_wait"]
+    )
+    whole = sorted(got["train/epoch"], key=lambda e: e["ts"])
+    assert [e["args"] for e in whole] == [{"epoch": 0}, {"epoch": 1}]
+    covered = []
+    for w in whole:
+        kids = [
+            e for e in spans.events()
+            if e["tid"] == w["tid"] and e is not w and e["name"] != "train/epoch"
+            and e["args"]["epoch"] == w["args"]["epoch"]
+        ]
+        names = {e["name"] for e in kids}
+        assert names >= {"train/epoch_head", "train/data_wait", "train/host_fetch",
+                         "train/epoch_drain", "train/epoch_tail"}
+        for e in kids:  # nesting by containment
+            assert w["ts"] - 0.2 <= e["ts"]
+            assert e["ts"] + e["dur"] <= w["ts"] + w["dur"] + 0.2, e["name"]
+        covered.append(sum(e["dur"] for e in kids) / w["dur"])
+    # head + waits + dispatches + fetches + drain + tail is the epoch, but
+    # for the Python between them (epoch 0 also holds the one cost capture)
+    assert covered[0] <= 1.001 and 0.9 <= covered[1] <= 1.001, covered
+
+
+def test_train_epoch_recorder_off_counts_and_records_nothing(boundary_trainer, capsys):
+    out = boundary_trainer.train_epoch(2)
+    assert spans.events() == []
+    for name in BOUNDARY_COUNTERS:
+        assert counters.get(name) > 0, name
+    # the first wait of the epoch is its refill, and is part of data_wait_s
+    assert counters.get("train.epoch_refill_s") <= out["data_wait_s"] + 1e-4
+    parts = sum(counters.get(name) for name in BOUNDARY_COUNTERS)
+    assert parts < out["epoch_time"]
+
+
+def test_epoch_mfu_is_flops_over_epoch_wall(boundary_trainer, monkeypatch, capsys):
+    """The epoch's MFU divides by wall time per step (the drain makes it the
+    device's), not by the p50 lap between dispatches, which under run-ahead
+    is host time and read 300-900% on the chip."""
+    from tpu_dist.obs import costmodel
+
+    tr = boundary_trainer
+    warm = tr.train_epoch(3)  # the first dispatch captured the step's cost
+    flops = tr._step_cost["flops_per_step"]
+    # a stub peak that puts this machine's MFU near 1, clear of the rounding
+    peak = flops * warm["steps"] / warm["epoch_time"] / tr.n_devices
+    monkeypatch.setattr(costmodel, "chip_peak_flops", lambda kind=None: peak)
+    compile_s = counters.get("compile.seconds")
+    out = tr.train_epoch(4)
+    wall = out["epoch_time"] - (counters.get("compile.seconds") - compile_s)
+    want = flops * out["steps"] / wall / (peak * tr.n_devices)
+    assert out["mfu"] == pytest.approx(want, rel=2e-3)
+    assert "MFU" in capsys.readouterr().out

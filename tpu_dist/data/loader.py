@@ -155,25 +155,36 @@ class DataLoader:
         killed = []  # --fault_plan loader_stall: producer died, no sentinel
 
         def producer():
+            # One clock read per boundary (docs/observability.md): each read
+            # closes one region into its always-on counter and, recorder
+            # on, its span. (epoch, step) joins a batch's loader/* spans on
+            # this thread to its train/* spans on the consumer's. The
+            # producer THREAD writes the registry — counters are locked
+            # for exactly this.
+            epoch = self.sampler.epoch
             try:
+                clock = time.perf_counter()
                 for b, hb in enumerate(
                     self._host_batches(start_batch), start=start_batch
                 ):
+                    # the generator's next() ran between the last read and here
+                    at = {"epoch": epoch, "step": b}
+                    clock = spans.add_timed(
+                        "loader/gather", "loader.gather_s", clock, **at
+                    )
                     if faults.on_loader_batch(b, self.sampler.epoch) == "die":
                         # simulate a producer killed mid-epoch: exit WITHOUT
                         # the end-of-epoch sentinel (the consumer watchdog
                         # below must notice, not hang)
                         killed.append(b)
                         return
-                    # telemetry: the producer THREAD writes the registry —
-                    # counters are locked for exactly this
-                    with spans.span("loader/produce", batch=b):
-                        batch = mesh_lib.shard_batch(self.mesh, hb, self.shard_axes)
+                    batch = mesh_lib.shard_batch(self.mesh, hb, self.shard_axes)
+                    counters.inc("loader.h2d_bytes", sum(a.nbytes for a in hb))
                     counters.inc("loader.batches_produced")
+                    clock = spans.add_timed("loader/h2d", "loader.h2d_s", clock, **at)
                     # bounded put that notices consumer abandonment (e.g. the
                     # trainer's steps_per_epoch early break) instead of
                     # blocking forever and leaking the thread + device batches
-                    t_put = time.perf_counter()
                     while not stop.is_set():
                         try:
                             q.put(batch, timeout=0.1)
@@ -182,8 +193,8 @@ class DataLoader:
                             continue
                     # time the producer spent blocked on a FULL queue: the
                     # loader outrunning the device (the healthy direction)
-                    counters.add_seconds(
-                        "loader.producer_wait_s", time.perf_counter() - t_put
+                    clock = spans.add_timed(
+                        "loader/queue_full", "loader.producer_wait_s", clock, **at
                     )
                     if stop.is_set():
                         return

@@ -310,7 +310,7 @@ KEY_GAUGES = (
     ("compile.retraces", "retraces", "g"),
     # the memory layer (obs/memory.py): worst-chip peak HBM and the free
     # headroom fraction — a sick worker that was about to OOM says so
-    ("mem.peak_bytes_in_use", "peak_hbm_B", "g"),
+    ("mem.peak_bytes", "peak_hbm_B", "g"),
     ("mem.headroom_frac", "hbm_free", ".1%"),
     # the serving layer (serve/slo.py): a sick SERVING replica's report
     # must say WHY — was the queue exploding, was availability gone, was

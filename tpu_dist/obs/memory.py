@@ -315,7 +315,7 @@ def record_peak_hbm(rec: dict) -> Optional[int]:
     static ``peak_bytes`` estimate, else the reconciled ``bytes_in_use``
     (census authority on CPU). None on an empty record."""
     alloc = rec.get("allocator") or {}
-    v = alloc.get("peak_bytes_in_use")
+    v = alloc.get("peak_bytes", alloc.get("peak_bytes_in_use"))
     if isinstance(v, (int, float)) and v > 0:
         return int(v)
     xla = rec.get("xla") or {}
@@ -703,7 +703,7 @@ def memory_report(records: List[dict]) -> dict:
                 row["epoch"] = rec.get("epoch")
                 series.append(row)
         if isinstance(cnt, dict):
-            v = cnt.get("mem.peak_bytes_in_use")
+            v = cnt.get("mem.peak_bytes", cnt.get("mem.peak_bytes_in_use"))
             if isinstance(v, (int, float)) and v > 0:
                 peak = max(peak or 0, int(v))
     return {
@@ -730,7 +730,7 @@ def format_report_text(report: dict) -> str:
             lines.append(
                 f"  {(ep if ep is not None else '-'):>5} "
                 f"{fmt_bytes(row.get('bytes_in_use')):>10} "
-                f"{fmt_bytes(row.get('peak_bytes_in_use')):>10} "
+                f"{fmt_bytes(row.get('peak_bytes', row.get('peak_bytes_in_use'))):>10} "
                 f"{(format(hr, '.1%') if isinstance(hr, (int, float)) else '-'):>9} "
                 f"{fmt_bytes(row.get('bytes_in_use_skew')):>10}"
             )

@@ -1,4 +1,4 @@
-"""Host-side span tracing — Chrome-trace-event output with an XLA bridge
+"""Host-side span tracing — Chrome-trace-event output with a clock anchor
 (``docs/observability.md``).
 
 The reference repo's timing story is two ``time.time()`` reads around the
@@ -7,10 +7,12 @@ says nothing about the host work that starves it (checkpoint serialization,
 loader waits, eval loops). This module records **host spans** on a
 monotonic clock (``time.perf_counter``) and emits them in the Chrome
 trace-event format, so one file loads in Perfetto / ``chrome://tracing``
-and shows the host timeline; each span additionally enters a
-``jax.profiler.TraceAnnotation`` while open, so when an XLA profile is
-being captured (``--profile_dir``), the SAME spans appear as named ranges
-on the XLA timeline — host and device views line up by construction.
+and shows the host timeline. :func:`clock_anchor` ties that clock to Unix
+time, which is how the spans are laid beside a device capture. (A
+context-manager span also enters a ``jax.profiler.TraceAnnotation``, but
+that reaches a capture only with the profiler's host tracer on, which the
+TPU loop cannot afford; :func:`add_event`/:func:`add_timed` records never
+do.)
 
 Contract (audited by TD106): arming the recorder changes NOTHING inside
 the traced train step — spans wrap host code only, and a disabled
@@ -32,7 +34,9 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+from tpu_dist.obs import counters
 
 #: Cap on buffered events: a week-long run must not grow host memory
 #: without bound. Overflow drops new events and counts them (the count is
@@ -48,6 +52,9 @@ _PID = 0
 # enable(): perf_counter is monotonic and sub-microsecond, and a common
 # origin keeps cross-thread spans comparable in the viewer.
 _T0 = time.perf_counter()
+# (perf_counter, time_ns) read back to back by enable(): the pair that ties
+# every event's ``ts`` to the wall clock (see clock_anchor)
+_ANCHOR: Optional[Tuple[float, int]] = None
 _ANNOTATION = None  # cached jax.profiler.TraceAnnotation (resolved lazily)
 # Span-OPEN listener (the flight recorder's tap, docs/observability.md
 # "Crash forensics"): called with (name, args) the moment a span opens,
@@ -148,6 +155,18 @@ def add_event(name: str, t_start: float, duration: float, **args) -> None:
         _EVENTS.append(evt)
 
 
+def add_timed(name: str, counter: Optional[str], t_start: float, **args) -> float:
+    """One clock read per boundary: close the region that began at
+    ``t_start`` NOW, add its seconds to the always-on ``counter`` (if
+    given) and, recorder on, record the span. Returns the end clock, so
+    the next region starts where this one stopped."""
+    end = time.perf_counter()
+    if counter is not None:
+        counters.add_seconds(counter, end - t_start)
+    add_event(name, t_start, end - t_start, **args)
+    return end
+
+
 def enable(fresh: bool = True) -> None:
     """Arm the recorder (fresh buffer, clock re-zeroed). Rank-agnostic:
     every process MAY record; the trainer only enables (and exports) on
@@ -156,12 +175,14 @@ def enable(fresh: bool = True) -> None:
     ``fresh=False`` re-arms WITHOUT clearing the buffer or moving the
     clock origin — for tooling (the TD106 audit) that must not destroy a
     live recorder's undrained events or shift later timestamps."""
-    global _ENABLED, _DROPPED, _T0, _PID, _ANNOTATION
+    global _ENABLED, _DROPPED, _T0, _ANCHOR, _PID, _ANNOTATION
     if fresh:
         with _LOCK:
             _EVENTS.clear()
             _DROPPED = 0
         _T0 = time.perf_counter()
+    if fresh or _ANCHOR is None:
+        _ANCHOR = (time.perf_counter(), time.time_ns())
     try:  # resolve the bridge + process id once, not per span
         import jax  # noqa: PLC0415
 
@@ -180,6 +201,21 @@ def disable() -> None:
 
 def enabled() -> bool:
     return _ENABLED
+
+
+def clock_anchor() -> Optional[dict]:
+    """What turns an event's ``ts`` (us since the recorder's origin) into
+    Unix time: ``unix_ns = unix_ns_at_origin + ts * 1000``. Taken from one
+    ``perf_counter``/``time_ns`` pair read back to back by the last fresh
+    :func:`enable`; None before the first."""
+    if _ANCHOR is None:
+        return None
+    pc, ns = _ANCHOR
+    return {
+        "perf_counter_s": pc,
+        "unix_ns": ns,
+        "unix_ns_at_origin": ns - int(round((pc - _T0) * 1e9)),
+    }
 
 
 def events() -> List[dict]:
@@ -209,9 +245,15 @@ def to_chrome_trace(extra_events: Optional[List[dict]] = None) -> dict:
     if extra_events:
         evts = extra_events + evts
     out = {"traceEvents": evts, "displayTimeUnit": "ms"}
+    meta: Dict[str, object] = {}
+    anchor = clock_anchor()
+    if anchor is not None:
+        meta["tpu_dist_clock_anchor"] = anchor
     d = dropped()
     if d:
-        out["metadata"] = {"tpu_dist_dropped_events": d}
+        meta["tpu_dist_dropped_events"] = d
+    if meta:
+        out["metadata"] = meta
     return out
 
 

@@ -401,7 +401,7 @@ def summarize(records: List[dict], bad_lines: int = 0) -> dict:
     for rec in records:
         cnt = rec.get("counters")
         if isinstance(cnt, dict):
-            v = cnt.get("mem.peak_bytes_in_use")
+            v = cnt.get("mem.peak_bytes", cnt.get("mem.peak_bytes_in_use"))
             if isinstance(v, (int, float)) and v > 0:
                 peak_hbm = max(peak_hbm or 0, int(v))
     out = {

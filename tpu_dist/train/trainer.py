@@ -1549,6 +1549,12 @@ class Trainer:
                 )
             return self._train_epoch_fused(epoch)
         cfg = self.cfg
+        # Epoch-boundary clocks (docs/observability.md): head = entry -> the
+        # first next() of the loader, refill = that first wait, drain = the
+        # block_until_ready, tail = drain -> return. One perf_counter read
+        # per boundary feeds the always-on train.epoch_*_s counter and,
+        # recorder on, the span (spans.add_timed).
+        t_epoch = time.perf_counter()
         self.train_sampler.set_epoch(epoch)  # shuffle correctness (tutorials/2:§2)
         if start_examples:
             # elastic mid-epoch re-entry: skip the old world's consumed
@@ -1582,16 +1588,24 @@ class Trainer:
 
         def timed_batches(src):
             it = iter(src)
+            # the first wait of an epoch is the boundary's refill: the queue
+            # is empty and the producer thread starts inside this next()
+            t_w = spans_lib.add_timed(
+                "train/epoch_head", "train.epoch_head_s", t_epoch, epoch=epoch
+            )
+            refill, step = "train.epoch_refill_s", start_step
             while True:
-                t_w = time.perf_counter()
                 try:
                     item = next(it)
                 except StopIteration:
                     return
-                d = time.perf_counter() - t_w
-                phase["data"] += d
-                spans_lib.add_event("train/data_wait", t_w, d, epoch=epoch)
+                t = spans_lib.add_timed(
+                    "train/data_wait", refill, t_w, epoch=epoch, step=step
+                )
+                phase["data"] += t - t_w
                 yield item
+                refill, step = None, step + 1
+                t_w = time.perf_counter()
 
         # (state, epoch, completed steps, epoch_complete) published as ONE
         # attribute so an interrupt can never observe a half-updated pair —
@@ -1620,7 +1634,7 @@ class Trainer:
                 # compile — epoch 2's step 0 is a plain dispatch and must
                 # not read as a retrace in the exported timeline
                 "train/dispatch" if self._step_traced else "train/compile+dispatch",
-                t_d, d_d, step=step,
+                t_d, d_d, epoch=epoch, step=step,
             )
             if not self._step_traced:
                 # first dispatch: the executable exists now — capture XLA's
@@ -1675,7 +1689,9 @@ class Trainer:
             t_f = time.perf_counter()
             m = _fetch_metrics(metrics) if (want_save or want_log) else None
             if m is not None:
-                phase["fetch"] += time.perf_counter() - t_f
+                phase["fetch"] += spans_lib.add_timed(
+                    "train/host_fetch", None, t_f, epoch=epoch, step=step
+                ) - t_f
                 # health layer rides the SAME host copy: device_stats
                 # record, anomaly detection (incl. the nonfinite finding,
                 # logged BEFORE the NaN guard below raises), per-step
@@ -1729,7 +1745,11 @@ class Trainer:
                     f"SIGTERM observed at epoch {epoch} after step {step} "
                     f"— shutting down at the step boundary"
                 )
+        t_drain = time.perf_counter()
         jax.block_until_ready(self.state.params)
+        t_tail = spans_lib.add_timed(
+            "train/epoch_drain", "train.epoch_drain_s", t_drain, epoch=epoch
+        )
         # end-of-epoch guard: catches divergence between logged steps BEFORE
         # fit() writes a checkpoint of the poisoned state. One fetch, reused
         # for the returned epoch metrics below.
@@ -1782,13 +1802,16 @@ class Trainer:
                 f"{pct['p95'] * 1e3:.1f}/{pct['p99'] * 1e3:.1f} ms, "
                 f"data stall {stall:.1%}"
             )
-        # MFU from the captured XLA flop count over the steady-state step
-        # time (p50 excludes the compile step; fallback: epoch mean). None
-        # on unknown chips (CPU emulation) — never a made-up figure.
-        if self._step_cost and steps_run:
+        compile_d = max(counters_lib.get("compile.seconds") - compile_s0, 0.0)
+        # MFU from the captured XLA flop count over the epoch's wall time
+        # per step, compile seconds taken out. The drain above makes the
+        # wall the device's; a lap between dispatches is host time under
+        # run-ahead (its p50 printed 300-900% on 13-step epochs, PERF.md).
+        # None on unknown chips (CPU emulation) — never a made-up figure.
+        if self._step_cost and steps_run and dt > compile_d:
             mfu = costmodel_lib.mfu(
                 self._step_cost.get("flops_per_step"),
-                pct["p50"] if pct else dt / steps_run,
+                (dt - compile_d) / steps_run,
                 self.n_devices,
             )
             if mfu is not None:
@@ -1800,7 +1823,6 @@ class Trainer:
         # remainder — the step loop actually stepping — as productive.
         # The in-epoch remainder definition keeps the ledger's sum-equals-
         # wall-clock invariant exact instead of approximately true.
-        compile_d = max(counters_lib.get("compile.seconds") - compile_s0, 0.0)
         ckpt_d = max(self._goodput.window_value("ckpt") - ckpt_s0, 0.0)
         self._goodput.add("data_stall", phase["data"])
         self._goodput.add("compile", compile_d)
@@ -1809,6 +1831,10 @@ class Trainer:
         )
         counters_lib.inc("train.epochs")
         counters_lib.inc("train.steps", steps_run)
+        t_end = spans_lib.add_timed(
+            "train/epoch_tail", "train.epoch_tail_s", t_tail, epoch=epoch
+        )
+        spans_lib.add_event("train/epoch", t_epoch, t_end - t_epoch, epoch=epoch)
         return out
 
     def _train_epoch_fused(self, epoch: int) -> dict:
@@ -1958,15 +1984,16 @@ class Trainer:
         counters (the true device numbers on TPU/GPU, now across ALL
         local devices — the scalar keys are the WORST chip, with min/
         skew gauges beside them; nothing is published on CPU, where the
-        backend keeps no stats). ``mem.headroom_frac`` — the free
-        fraction of the worst chip's limit — feeds the built-in
-        ``memory_headroom_low`` alert rule."""
+        backend keeps no stats). ``mem.headroom_frac`` — the fraction of
+        the worst chip's limit that its peak (``mem.peak_bytes``: buffers
+        plus the executables' reserved temporaries) left free — feeds the
+        built-in ``memory_headroom_low`` alert rule."""
         mem = costmodel_lib.device_memory_stats()
         if mem:
             for key, value in mem.items():
                 counters_lib.set_gauge(f"mem.{key}", value)
             lim = mem.get("bytes_limit")
-            use = mem.get("bytes_in_use")
+            use = mem.get("peak_bytes", mem.get("bytes_in_use"))
             if lim and isinstance(use, (int, float)):
                 counters_lib.set_gauge(
                     "mem.headroom_frac", round(1.0 - use / lim, 4)
