@@ -9,9 +9,11 @@ every step, ``distributed.py:71,88-89``), this path:
   axis (each chip owns N/n examples);
 * shuffles **on device** each epoch (per-shard permutation from a seeded
   key — the ``set_epoch`` semantics, folded per-device);
-* augments **on device**: batch pad + per-image random crop offsets via
-  ``jax.random``, normalize into the compute dtype — fused by XLA into the
-  first conv's input pipeline;
+* augments **on device**: per-image random crop offsets via ``jax.random``,
+  the crop itself as two one-hot shift matmuls (:func:`random_crop`; on
+  the v5e XLA fuses them into one op and a layout copy follows, 0.3 ms of
+  the 120.5-ms ResNet-18 step at batch 4096), then normalize into the
+  compute dtype as one elementwise pass ahead of the first conv;
 * runs the epoch as ``lax.scan`` over steps inside one ``jit`` call: ONE
   host dispatch per epoch, zero host↔device traffic, no Python in the loop.
 
@@ -78,6 +80,37 @@ def fused_steps_per_epoch(dataset_len: int, global_batch: int) -> int:
     return max(1, int(dataset_len) // int(global_batch))
 
 
+def random_crop(imgs_u8, offs, pad: int):
+    """Per-image crop of the zero-padded batch: image ``i`` of the result is
+    ``pad(imgs_u8[i])[offs[i,0]:offs[i,0]+H, offs[i,1]:offs[i,1]+W]``.
+
+    ``imgs_u8`` [B,H,W,C] uint8, ``offs`` [B,2] ints in ``[0, 2*pad]`` (row,
+    column). Each shift is a one-hot [H,H] (rows) or [W,W] (columns) matrix
+    applied on the MXU. 0..255 are exact in bfloat16 and every output is a
+    sum with at most one non-zero term, so the result is exact; a source
+    index outside the image matches nothing, which is the zero padding.
+    A per-image ``dynamic_slice`` under ``vmap`` is a gather, which the
+    v5e's compiler expands into a loop of B trips (75.6 ms of a 196-ms step
+    at B=4096, against 0.3 ms for this).
+    """
+    if imgs_u8.dtype != jnp.uint8:
+        raise TypeError(f"random_crop is exact for uint8 only, got {imgs_u8.dtype}")
+    _, h, w, _ = imgs_u8.shape
+
+    def shift(off, n):
+        # [B,n,n]: output index i reads source index i + off - pad
+        src = jnp.arange(n)[:, None] + (off - pad)[:, None, None]
+        return (src == jnp.arange(n)).astype(jnp.bfloat16)
+
+    # not offs[:, 0]: that traces to a gather (a trivial one, but the test
+    # that keeps per-image gathers out of here could not tell them apart)
+    rows = shift(lax.index_in_dim(offs, 0, axis=1, keepdims=False), h)
+    cols = shift(lax.index_in_dim(offs, 1, axis=1, keepdims=False), w)
+    x = jnp.einsum("bik,bkwc->biwc", rows, imgs_u8.astype(jnp.bfloat16))
+    x = jnp.einsum("bjk,bikc->bijc", cols, x)
+    return x.astype(jnp.uint8)
+
+
 def make_fused_epoch(
     model_apply: Callable,
     optimizer,
@@ -123,14 +156,8 @@ def make_fused_epoch(
 
     def augment(imgs_u8, key):
         """[B,H,W,C] uint8 → normalized compute_dtype, random crop pad=4."""
-        b, h, w, c = imgs_u8.shape
-        xp = jnp.pad(imgs_u8, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-        offs = jax.random.randint(key, (b, 2), 0, 2 * pad + 1)
-
-        def crop(img, off):
-            return lax.dynamic_slice(img, (off[0], off[1], 0), (h, w, c))
-
-        cropped = jax.vmap(crop)(xp, offs)
+        offs = jax.random.randint(key, (imgs_u8.shape[0], 2), 0, 2 * pad + 1)
+        cropped = random_crop(imgs_u8, offs, pad)
         x = (cropped.astype(jnp.float32) / 255.0 - mean_c) * std_inv_c
         return x.astype(compute_dtype)
 
