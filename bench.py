@@ -306,8 +306,8 @@ def run(cfg: BenchConfig, steps: int, warmup: int, n_devices: int | None = None,
     }
     from tpu_dist.nn.attention import set_default_attention_impl
 
-    # process-global: reset per run so --all mixes flash/xla configs safely
-    set_default_attention_impl("flash" if cfg.flash else "xla")
+    # process-global: reset per run so --all mixes flash and by-shape configs safely
+    set_default_attention_impl("flash" if cfg.flash else "auto")
     if n_devices is None:
         mesh = mesh_lib.data_parallel_mesh()
     else:

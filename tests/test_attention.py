@@ -189,12 +189,14 @@ def test_explicit_impl_overrides_process_default(monkeypatch):
         model.apply(params, state, x, attn_impl="flash")
         assert calls
     finally:
-        A.set_default_attention_impl("xla")
+        A.set_default_attention_impl("auto")
 
 
 def test_trainer_snapshots_attn_impl():
     """Two Trainers with different flash settings: each step closure keeps
-    its own impl (the global default no longer leaks across builds)."""
+    its own impl (the global default no longer leaks across builds). With
+    no flag the attention chooses by shape ("auto"); FSDP's GSPMD step, where
+    a Pallas call cannot be partitioned, pins XLA."""
     from tests.helpers import tiny_resnet
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer, register_model
@@ -206,8 +208,9 @@ def test_trainer_snapshots_attn_impl():
     )
     t_xla = Trainer(TrainConfig(**common))
     t_flash = Trainer(TrainConfig(**common, flash_attention=True))
-    assert t_xla._attn_model_kwargs() == {"attn_impl": "xla"}
+    assert t_xla._attn_model_kwargs() == {"attn_impl": "auto"}
     assert t_flash._attn_model_kwargs() == {"attn_impl": "flash"}
+    assert Trainer._attn_impl(TrainConfig(**common, fsdp=True)) == "xla"
     # conv models don't take the kwarg at all
     t_conv = Trainer(TrainConfig(dataset="synthetic", model="tiny_resnet",
                                  num_classes=10, batch_size=64, epochs=1,

@@ -78,12 +78,12 @@ def test_attention_dispatch_impl():
     q, k, v = (
         jnp.asarray(rng.normal(size=(1, 32, 2, 16)), jnp.float32) for _ in range(3)
     )
-    assert get_default_attention_impl() == "xla"
+    assert get_default_attention_impl() == "auto"
     try:
         set_default_attention_impl("flash")
         out = attention(q, k, v)
     finally:
-        set_default_attention_impl("xla")
+        set_default_attention_impl("auto")
     ref = attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
     with pytest.raises(ValueError):
@@ -102,7 +102,7 @@ def test_trainer_flash_attention_e2e():
     try:
         out = Trainer(cfg).train_epoch(0)
     finally:
-        set_default_attention_impl("xla")
+        set_default_attention_impl("auto")
     assert np.isfinite(out["loss"])
 
 

@@ -19,9 +19,18 @@ TPU-style:
   heads (two collectives per call; composes with the Pallas flash
   kernel). Pick by topology: ring = nearest-neighbor ICI traffic,
   ulysses = fewer collectives and flash-compatible, needs heads % n == 0.
+* :func:`projected_attention` — the entry a transformer block calls: the
+  fused qkv projection and the attention over it. It chooses by shape: on a
+  TPU, with no sequence axis, where one image's whole ``[S, S]`` score tile
+  and its operands fit VMEM (ViT lengths), the projection is laid out
+  q|k|v-major and ``ops/short_attention.py`` runs forward and backward as
+  one Pallas kernel each, with no ``[., S, S]`` array and no relayout copy
+  in HBM. Everything else slices q, k, v out of the head-major projection
+  and goes through :func:`attention`. Each call site lowered is counted:
+  ``attn.sites_fused`` / ``attn.sites_xla`` in ``obs/counters``.
 
-Both operate on [B, S, H, D] (batch, sequence, heads, head_dim) and are
-shape-polymorphic under ``shard_map``.
+The first three operate on [B, S, H, D] (batch, sequence, heads, head_dim)
+and are shape-polymorphic under ``shard_map``.
 """
 
 from __future__ import annotations
@@ -32,20 +41,23 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpu_dist.obs import counters
+
 # Process-global default for the single-device attention implementation.
-# "xla": one fused einsum/softmax chain ([S,S] scores in HBM — fine at ViT
-# lengths). "flash": the Pallas tiled kernel (ops/flash_attention.py) —
-# O(block²) memory, the long-context choice. The Trainer sets this from
-# ``--flash_attention``; it is process-global state like the XLA compile
-# cache, not per-model.
-_DEFAULT_IMPL = "xla"
+# "auto": chosen by shape — the whole-sequence Pallas kernel
+# (ops/short_attention.py) where projected_attention finds that it fits,
+# the XLA chain everywhere else. "xla": always the einsum/softmax chain
+# ([S,S] scores in HBM). "flash": always the Pallas tiled kernel
+# (ops/flash_attention.py), O(block²) memory, the long-context choice.
+# The Trainer sets "flash" under ``--flash_attention`` and "auto" otherwise;
+# it is process-global state like the XLA compile cache, not per-model.
+_IMPLS = ("auto", "xla", "flash")
+_DEFAULT_IMPL = "auto"
 
 
 def set_default_attention_impl(impl: str) -> None:
     global _DEFAULT_IMPL
-    if impl not in ("xla", "flash"):
-        raise ValueError(f"attention impl must be 'xla' or 'flash', got {impl!r}")
-    _DEFAULT_IMPL = impl
+    _DEFAULT_IMPL = _resolve_impl(impl)
 
 
 def get_default_attention_impl() -> str:
@@ -54,13 +66,19 @@ def get_default_attention_impl() -> str:
 
 def _resolve_impl(impl: Optional[str]) -> str:
     impl = impl or _DEFAULT_IMPL
-    if impl not in ("xla", "flash"):
-        raise ValueError(f"attention impl must be 'xla' or 'flash', got {impl!r}")
+    if impl not in _IMPLS:
+        raise ValueError(f"attention impl must be one of {_IMPLS}, got {impl!r}")
     return impl
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def full_attention(q, k, v, *, causal: bool = False, impl: Optional[str] = None):
-    """[B,S,H,D] x3 → [B,S,H,D]. Softmax in f32 regardless of input dtype."""
+    """[B,S,H,D] x3 → [B,S,H,D]. Softmax in f32 regardless of input dtype.
+    ``impl`` "auto" is the XLA chain here: q, k and v arrive apart, and the
+    whole-sequence kernel reads them packed (:func:`projected_attention`)."""
     if _resolve_impl(impl) == "flash":
         from tpu_dist.ops.flash_attention import flash_attention  # noqa: PLC0415
 
@@ -198,3 +216,52 @@ def attention(q, k, v, *, causal: bool = False, seq_axis: Optional[str] = None,
             return ring_flash_attention(q, k, v, seq_axis, causal=causal)
         return ring_attention(q, k, v, seq_axis, causal=causal)
     return full_attention(q, k, v, causal=causal, impl=impl)
+
+
+def qkv_major(t, h_dim: int):
+    """Reorder the last axis of a fused projection's weight or bias from
+    head-major ``[heads, 3, h_dim]`` (the parameters' layout: a contiguous
+    column shard is whole heads) to q|k|v-major ``[3, heads, h_dim]``. Done
+    at trace time on the compute-dtype copy the step already makes; its
+    transpose carries the gradient back, so parameters, checkpoints and
+    ``tp_param_specs`` never see it."""
+    heads = t.shape[-1] // (3 * h_dim)
+    t = t.reshape(*t.shape[:-1], heads, 3, h_dim)
+    return jnp.swapaxes(t, -3, -2).reshape(*t.shape[:-3], 3 * heads * h_dim)
+
+
+def takes_short_kernel(impl: Optional[str], seq_axis: Optional[str], causal: bool,
+                       seq: int, heads: int, h_dim: int, dtype) -> bool:
+    """The selection rule of :func:`projected_attention`, from what the call
+    can observe: the implementation left to choice, a TPU, no sequence axis,
+    no mask, and a shape ``ops/short_attention.py`` can hold in VMEM."""
+    if _resolve_impl(impl) != "auto" or seq_axis is not None or causal or not _on_tpu():
+        return False
+    from tpu_dist.ops.short_attention import fits  # noqa: PLC0415
+
+    return fits(seq, heads, h_dim, dtype)
+
+
+def projected_attention(y, proj, h_dim: int, *, causal: bool = False,
+                        seq_axis: Optional[str] = None, impl: Optional[str] = None,
+                        sp_mode: str = "ring"):
+    """Fused qkv projection plus attention: ``y [B, S, Din]`` through
+    ``proj = {"w": [Din, H*3*h_dim], "b"}`` (columns head-major; ``H`` is
+    the local head count under tensor parallelism) to ``[B, S, H*h_dim]``,
+    heads side by side, as the output projection reads it."""
+    impl = _resolve_impl(impl)
+    w, bias = proj["w"].astype(y.dtype), proj["b"].astype(y.dtype)
+    b, s = y.shape[:2]
+    heads = w.shape[-1] // (3 * h_dim)
+    if takes_short_kernel(impl, seq_axis, causal, s, heads, h_dim, y.dtype):
+        from tpu_dist.ops.short_attention import short_attention  # noqa: PLC0415
+
+        counters.inc("attn.sites_fused")
+        qkv = y @ qkv_major(w, h_dim) + qkv_major(bias, h_dim)
+        return short_attention(qkv, heads, interpret=False)  # only taken on a TPU
+    if impl != "flash":
+        counters.inc("attn.sites_xla")
+    qkv = (y @ w + bias).reshape(b, s, heads, 3, h_dim)
+    q, k, v = (qkv[:, :, :, i, :] for i in range(3))
+    o = attention(q, k, v, causal=causal, seq_axis=seq_axis, impl=impl, sp_mode=sp_mode)
+    return o.reshape(b, s, heads * h_dim)

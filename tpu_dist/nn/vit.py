@@ -67,13 +67,9 @@ def block_forward(blk, t, heads: int, attn_impl: Optional[str] = None):
     Shared by ViTDef's sequential path and the pipeline-parallel wrapper.
     ``attn_impl`` pins the attention implementation at build time (None =
     process default at trace time)."""
-    b, s, dim = t.shape
-    h_dim = dim // heads
     y = _ln_apply(blk["ln1"], t)
-    qkv = _dense(blk["qkv"], y).reshape(b, s, heads, 3, h_dim)
-    q, k, v = (qkv[:, :, :, i, :] for i in range(3))
-    o = attn_lib.full_attention(q, k, v, impl=attn_impl)
-    t = t + _dense(blk["proj"], o.reshape(b, s, dim))
+    o = attn_lib.projected_attention(y, blk["qkv"], t.shape[-1] // heads, impl=attn_impl)
+    t = t + _dense(blk["proj"], o)
     y = _ln_apply(blk["ln2"], t)
     y = jax.nn.gelu(_dense(blk["mlp1"], y))
     return t + _dense(blk["mlp2"], y)
@@ -97,16 +93,12 @@ def tp_block_forward(
     sequential TP path and the pipeline-parallel stage scan (PP×TP —
     Megatron's layout: TP inside each pipeline stage)."""
     y = copy_to_tp(_ln_apply(blk["ln1"], t))
-    qkv = _dense(blk["qkv"], y)  # col-sharded under TP: local heads
-    b, s, qkv_dim = qkv.shape
-    h_loc = qkv_dim // (3 * h_dim)
-    # layout [heads, 3, h_dim]: a contiguous column shard is whole heads
-    qkv = qkv.reshape(b, s, h_loc, 3, h_dim)
-    q, k, v = (qkv[:, :, :, i, :] for i in range(3))
-    o = attn_lib.attention(
-        q, k, v, seq_axis=seq_axis, sp_mode=sp_mode, impl=attn_impl
+    # qkv is col-sharded under TP, layout [heads, 3, h_dim]: a contiguous
+    # column shard is whole (local) heads
+    o = attn_lib.projected_attention(
+        y, blk["qkv"], h_dim, seq_axis=seq_axis, sp_mode=sp_mode, impl=attn_impl
     )
-    proj = reduce_from_tp(_dense_local(blk["proj"], o.reshape(b, s, h_loc * h_dim)))
+    proj = reduce_from_tp(_dense_local(blk["proj"], o))
     t = t + proj + blk["proj"]["b"].astype(t.dtype)
     y = copy_to_tp(_ln_apply(blk["ln2"], t))
     y = jax.nn.gelu(_dense(blk["mlp1"], y))  # col-sharded hidden

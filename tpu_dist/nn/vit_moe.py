@@ -132,16 +132,12 @@ class ViTMoEDef:
         t = t + params["pos"][: t.shape[1]].astype(t.dtype)[None]
 
         h_dim = self.dim // self.heads
-        b = t.shape[0]
+        b, s = t.shape[:2]
         aux_total = jnp.zeros((), jnp.float32)
         for blk in params["blocks"]:
             y = _ln_apply(blk["ln1"], t)
-            qkv = _dense(blk["qkv"], y)
-            s = qkv.shape[1]
-            qkv = qkv.reshape(b, s, self.heads, 3, h_dim)
-            q, k, v = (qkv[:, :, :, i, :] for i in range(3))
-            o = attn_lib.full_attention(q, k, v, impl=attn_impl)
-            t = t + _dense(blk["proj"], o.reshape(b, s, self.dim))
+            o = attn_lib.projected_attention(y, blk["qkv"], h_dim, impl=attn_impl)
+            t = t + _dense(blk["proj"], o)
 
             y = _ln_apply(blk["ln2"], t)
             flat = y.reshape(b * s, self.dim)

@@ -304,7 +304,7 @@ class Trainer:
 
         # set BOTH directions: the default is process-global, and a later
         # Trainer in the same process must not inherit a stale 'flash'
-        set_default_attention_impl("flash" if cfg.flash_attention else "xla")
+        set_default_attention_impl(self._attn_impl(cfg))
         self.model = build_model(cfg)
         if cfg.sp_mode not in ("ring", "ulysses"):
             raise ValueError(
@@ -1281,8 +1281,19 @@ class Trainer:
         import inspect  # noqa: PLC0415
 
         if "attn_impl" in inspect.signature(self.model.apply).parameters:
-            return {"attn_impl": "flash" if self.cfg.flash_attention else "xla"}
+            return {"attn_impl": self._attn_impl(self.cfg)}
         return {}
+
+    @staticmethod
+    def _attn_impl(cfg: TrainConfig) -> str:
+        """``--flash_attention`` forces the tiled kernel; otherwise the
+        attention chooses by shape ("auto": the whole-sequence kernel where
+        it fits, XLA elsewhere). FSDP pins XLA: its step is one
+        GSPMD-partitioned jit (no shard_map), where a Pallas call has no
+        partitioning rule."""
+        if cfg.flash_attention:
+            return "flash"
+        return "xla" if cfg.fsdp else "auto"
 
     def _ckpt_meta(self) -> dict:
         """Layout tag written with every checkpoint. Interleaved pipeline
