@@ -1,6 +1,6 @@
 # Convenience targets; CI / the driver call the underlying commands directly.
 
-.PHONY: test quick bench csrc clean lint shard-report plan-report tune-overlap ckpt-bench pod-report monitor profile-report elastic-drill fleet-drill postmortem-drill serve-drill tenancy-drill hub-drill serve-report memory-report trend-report
+.PHONY: test quick bench csrc clean lint shard-report ckpt-bench pod-report monitor profile-report elastic-drill fleet-drill postmortem-drill serve-drill tenancy-drill hub-drill serve-report memory-report trend-report
 
 csrc:
 	$(MAKE) -C tpu_dist/csrc
@@ -16,33 +16,11 @@ lint:
 # Layer 3 — the static HLO sharding & collective audit: lower+compile
 # every config family, parse the OPTIMIZED HLO (what GSPMD actually
 # emitted), gate TD116/TD117 (incl. the injected bad-in_shardings probe
-# that must be caught), and write the schema-pinned shard_report.json the
-# --auto_shard planner reads (docs/shard_report.md):
+# that must be caught), and write the schema-pinned shard_report.json
+# (docs/shard_report.md):
 #   make shard-report [OUT=shard_report.json]
 shard-report:
 	python -m tpu_dist.analysis shard --inject-reshard --out $(or $(OUT),shard_report.json)
-
-# Layer 4 — the sharding planner: enumerate + price the config-family
-# space (calibrated roofline over the HLO-verified wire bytes), refuse
-# over-budget candidates through the typed HBM path, rank, verify the
-# chosen plan against a fresh compile (TD118 — incl. the injected
-# miscost probe that must be caught, exit 2 if the detector went dead),
-# and write the schema-pinned plan_report.json the trainer's
-# --auto_shard consumes (docs/planner.md):
-#   make plan-report [OUT=plan_report.json]
-plan-report:
-	python -m tpu_dist.analysis plan --inject-miscost --out $(or $(OUT),plan_report.json)
-
-# Layer 5 — the comm/compute overlap autotuner: compile every knob
-# candidate per config family, require payload-byte identity while the
-# HLO collective schedule actually moves (TD121 — incl. the injected
-# payload-perturbed probe that must be caught, exit 2 if the detector
-# went dead), and write the schema-pinned tune_report.json that
-# `plan --tune-report` and the trainer's `--tune_report` consume
-# (docs/analysis.md "Layer 5"):
-#   make tune-overlap [OUT=tune_report.json]
-tune-overlap:
-	python -m tpu_dist.analysis tune-overlap --inject-payload --out $(or $(OUT),tune_report.json)
 
 # The async-checkpoint cost, on a TPU host (bench.py refuses without
 # one): measure step-loop blocking per sharded save for the synchronous

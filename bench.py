@@ -125,49 +125,6 @@ def _hbm_fields(compiled) -> dict:
     return {"peak_hbm_bytes": ma["peak_bytes"]} if ma else {}
 
 
-def _plan_fields(cost: dict, *, n_dev: int, step_s: float | None,
-                 grad_compression: str = "none", bf16: bool = False,
-                 grad_accum: int = 1, wire_bytes: int | None = None) -> dict:
-    """The planner's view of THIS measured config, stamped next to the
-    measurement (``analysis/planner.py``): the family label from the
-    shared registry, the cost model's priced step time (calibrated gauges
-    when a capture ran earlier in the process, the planner's uncalibrated
-    defaults otherwise — ``plan.gauge_source`` says which), and the TD119
-    ``planner_error_frac`` of that price against the measured step time —
-    the same drift scalar the trainer logs after a profiled run, so
-    ``obs compare --bench`` gates bench records with the identical
-    metric. Empty on an unpriceable config (cost analysis failed)."""
-    try:
-        from tpu_dist.analysis import planner  # noqa: PLC0415
-        from tpu_dist.obs import costmodel  # noqa: PLC0415
-
-        gauges, source = planner.pricing_gauges()
-        pred = costmodel.predicted_step_time(
-            cost, wire_bytes=wire_bytes, n_devices=n_dev, gauges=gauges,
-        )
-        if not pred:
-            return {}
-        out = {
-            "plan": {
-                "family": planner.family_of(
-                    grad_compression=grad_compression, bf16=bf16,
-                    grad_accu_steps=grad_accum,
-                ),
-                "gauge_source": source,
-            },
-            "predicted_step_s": pred["predicted_step_s"],
-        }
-        err = costmodel.planner_error_frac(pred["predicted_step_s"], step_s)
-        if err is not None:
-            out["planner_error_frac"] = err
-        return out
-    except Exception as e:  # noqa: BLE001 — a bench must not die on a stamp
-        import sys  # noqa: PLC0415
-
-        print(f"bench: plan stamp unavailable: {e}", file=sys.stderr)
-        return {}
-
-
 def _wire_audit(fn, *args, trips: int = 1) -> dict | None:
     """Static wire-byte accounting of a compiled step/epoch's gradient
     collectives (the jaxpr-level TD104 model from ``tpu_dist.analysis``),
@@ -420,11 +377,6 @@ def run(cfg: BenchConfig, steps: int, warmup: int, n_devices: int | None = None,
         out["wire_bytes_per_step"] = wire
     if hlo_wire is not None:
         out["hlo_wire_bytes_per_step"] = hlo_wire
-    out.update(_plan_fields(
-        cost, n_dev=n_dev, step_s=dt / steps,
-        grad_compression=grad_compression, bf16=cfg.bf16,
-        grad_accum=cfg.grad_accum, wire_bytes=hlo_wire,
-    ))
     if profile_dir:
         # read the capture back (obs/xprof): the attribution lands next to
         # the throughput it explains — a bench line with 40% collective
@@ -529,14 +481,6 @@ def _run_fused(cfg: BenchConfig, mesh, model, optimizer, state, n_dev: int,
         out["wire_bytes_per_step"] = wire
     if hlo_wire is not None:
         out["hlo_wire_bytes_per_step"] = hlo_wire
-    out.update(_plan_fields(
-        # the record's per-step normalization of the trips-scaled totals
-        {"flops_per_step": out["flops_per_step"],
-         "bytes_per_step": out["bytes_per_step"]},
-        n_dev=n_dev, step_s=dt / steps_per_epoch,
-        grad_compression=grad_compression, bf16=cfg.bf16,
-        grad_accum=cfg.grad_accum, wire_bytes=hlo_wire,
-    ))
     return _stamped(out)
 
 

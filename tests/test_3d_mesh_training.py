@@ -13,7 +13,6 @@ from tpu_dist.train.step import make_train_step
 from tpu_dist.train.trainer import Trainer
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_dp_tp_sp_training_matches_single_device():
     from jax.sharding import NamedSharding
 
@@ -61,7 +60,6 @@ def test_dp_tp_sp_training_matches_single_device():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_3d_e2e():
     cfg = TrainConfig(
         dataset="synthetic", model="vit_tiny", num_classes=10, batch_size=16,

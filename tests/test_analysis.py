@@ -515,7 +515,6 @@ def test_scan_body_collectives_count_per_trip():
     assert counts["collectives"]["psum"] == 3, counts
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_audit_all_clean_and_budget_mismatch_detected():
     report, violations = audit_all()
     assert violations == []
@@ -563,7 +562,6 @@ def test_cli_nonzero_on_planted_violation(tmp_path):
     assert {v["rule"] for v in out["violations"]} == {"TD002", "TD004", "TD007"}
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_cli_clean_on_repo():
     # the acceptance gate: lint + jaxpr audit over the real package, exit 0
     r = _run_cli(["--format", "json"])

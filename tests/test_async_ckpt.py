@@ -74,8 +74,6 @@ def test_async_error_surfaces_on_wait(tmp_path):
     ac.wait()  # error is consumed once; subsequent waits are clean
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_async_ckpt_e2e(tmp_path):
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer, register_model

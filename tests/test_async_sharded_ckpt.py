@@ -1,6 +1,5 @@
 """Snapshot-then-write sharded checkpointing (--sharded_ckpt +
---async_ckpt, ckpt/checkpoint.py::AsyncShardedCheckpointer) and the
-overlap autotuner's TD121 gate (tpu_dist/analysis/overlap.py).
+--async_ckpt, ckpt/checkpoint.py::AsyncShardedCheckpointer).
 
 TD120 pins the composition's two invariants: the traced train step is
 byte-identical whether or not a background writer is armed, and an
@@ -8,9 +7,6 @@ async-written checkpoint restores bit-exact to a synchronous sharded
 save of the same state. The fault probes (EIO mid-background, SIGKILL
 during the write, SIGTERM mid-run) must all be CAUGHT — a probe that
 comes back clean means the detector is dead.
-
-TD121 pins the tuner contract: every knob moves the collective
-schedule, never the payload-byte inventory shardlint pins.
 """
 
 import json
@@ -313,7 +309,6 @@ w.wait()  # never returns: SIGKILL lands mid-write
 """
 
 
-@pytest.mark.slow
 def test_sigkill_during_background_write_leaves_restorable_ladder(tmp_path):
     """Kill -9 while the background writer is mid-publish: whatever
     latest_sharded_checkpoint then returns must deep-verify and restore
@@ -347,7 +342,6 @@ def test_sigkill_during_background_write_leaves_restorable_ladder(tmp_path):
     _tree_equal(_fsdp_like_state(mesh), restored)
 
 
-@pytest.mark.slow
 def test_cli_sigterm_drains_async_sharded_then_exit_75(tmp_path):
     """SIGTERM mid-run with the async+sharded composition: the trainer
     finishes the in-flight step, emergency-saves, DRAINS the background
@@ -371,7 +365,6 @@ def test_cli_sigterm_drains_async_sharded_then_exit_75(tmp_path):
     ckpt_lib.verify_sharded(found[0], deep=True)
 
 
-@pytest.mark.slow
 def test_trainer_async_sharded_resume_and_ckpt_accounting(tmp_path):
     """e2e: the once-refused --sharded_ckpt + --async_ckpt composition
     trains, commits every epoch, resumes from the manifest, and the
@@ -403,193 +396,3 @@ def test_trainer_async_sharded_resume_and_ckpt_accounting(tmp_path):
     t2 = Trainer(cfg.replace(resume=True))
     assert t2.start_epoch == 2  # both epochs committed and visible
 
-
-# --------------------------------------------------------------------------
-# TD121: tuner knobs are schedule-only (payload pinned, schedule moves)
-# --------------------------------------------------------------------------
-
-from tpu_dist.analysis import overlap as overlap_lib  # noqa: E402
-
-
-def _handcrafted_report():
-    """A minimal structurally-valid tune_report_v1 with recorded
-    inventories — lets the gate/probe/loader tests run without a single
-    compile."""
-    base = {
-        "family": "zero1_sgd", "knobs": {},
-        "wire": {"payload_bytes": 1000, "quantized_payload_bytes": 0,
-                 "sideband_bytes": 0},
-        "collective_ops": 2, "jaxpr_collectives": 2,
-        "fingerprint": [["reduce-scatter", "f32", 100],
-                        ["all-gather", "f32", 100]],
-        "distances": [3, 1],
-        "schedule": {"collectives": 2, "total_distance": 4,
-                     "mean_distance": 2.0, "min_distance": 1},
-    }
-    cand = json.loads(json.dumps(base))
-    cand["knobs"] = {"rs_ag_chunks": 2}
-    cand["fingerprint"] = [["reduce-scatter", "f32", 50]] * 2 + [
-        ["all-gather", "f32", 50]] * 2
-    cand["distances"] = [5, 4, 2, 1]
-    cand["collective_ops"] = 4
-    cand["schedule"] = {"collectives": 4, "total_distance": 12,
-                        "mean_distance": 3.0, "min_distance": 1}
-    cand["td121"] = {"clean": True, "violations": []}
-    return {
-        "schema": overlap_lib.SCHEMA,
-        "backend": "cpu", "device_kind": "cpu", "n_devices": 8,
-        "jax_version": jax.__version__,
-        "objective": "hlo_schedule_proxy",
-        "measured_overlap_frac": None,
-        "families": {"zero1_sgd": {
-            "baseline": base, "candidates": [base, cand],
-            "chosen": {"knobs": cand["knobs"], "schedule": cand["schedule"],
-                       "gain_frac": 0.5},
-        }},
-        "skips": {},
-        "counts": {"families": 1, "skipped": 0, "violations": 0},
-    }
-
-
-def test_td121_gate_payload_and_vacuous_knob():
-    report = _handcrafted_report()
-    assert overlap_lib.recheck_report(report) == []
-
-    # payload moved -> violation
-    bad = overlap_lib.inject_payload(report)
-    vs = overlap_lib.recheck_report(bad)
-    assert vs and all(v.rule == "TD121" for v in vs)
-    assert "payload" in vs[0].message
-
-    # knob that changed NOTHING -> also a violation (vacuous search space)
-    vac = json.loads(json.dumps(report))
-    cand = vac["families"]["zero1_sgd"]["candidates"][1]
-    base = vac["families"]["zero1_sgd"]["baseline"]
-    for k in ("fingerprint", "distances", "jaxpr_collectives",
-              "collective_ops", "schedule"):
-        cand[k] = json.loads(json.dumps(base[k]))
-    vs2 = overlap_lib.recheck_report(vac)
-    assert vs2 and "did not move" in vs2[0].message
-
-
-def test_tune_report_roundtrip_and_forward_compat(tmp_path):
-    report = _handcrafted_report()
-    path = str(tmp_path / "tune_report.json")
-    overlap_lib.save_tune_report(report, path)
-    back = overlap_lib.load_tune_report(path)
-    assert back["families"].keys() == report["families"].keys()
-    assert overlap_lib.chosen_knobs(back, "zero1_sgd") == {"rs_ag_chunks": 2}
-    assert overlap_lib.chosen_knobs(back, "dp_sgd") == {}
-
-    # NEWER schema: tolerated, unreadable families skipped with a count
-    newer = json.loads(json.dumps(report))
-    newer["schema"] = "tune_report_v2"
-    newer["families"]["future_fam"] = {"chosen": {"v2_only": True}}
-    overlap_lib.save_tune_report(newer, path)
-    got = overlap_lib.load_tune_report(path)
-    assert "future_fam" not in got["families"]
-    assert got["load_notes"]["skipped_count"] == 1
-
-    # foreign tag: typed refusal
-    foreign = json.loads(json.dumps(report))
-    foreign["schema"] = "plan_report_v1"
-    overlap_lib.save_tune_report(foreign, path)
-    with pytest.raises(overlap_lib.TuneReportError, match="not a tune_report"):
-        overlap_lib.load_tune_report(path)
-
-    # same-version entry missing required chosen keys: typed refusal
-    broken = json.loads(json.dumps(report))
-    del broken["families"]["zero1_sgd"]["chosen"]["schedule"]
-    overlap_lib.save_tune_report(broken, path)
-    with pytest.raises(overlap_lib.TuneReportError, match="missing"):
-        overlap_lib.load_tune_report(path)
-
-
-def test_knob_refusal_walls():
-    """make_train_step refuses out-of-scope knob combinations before any
-    trace — a tuner knob silently ignored would be a lying report."""
-    from tpu_dist.train.optim import SGD
-    from tpu_dist.train.step import make_train_step
-
-    mesh = mesh_lib.data_parallel_mesh()
-    model = TinyConvNet(num_classes=10, width=16)
-    opt = SGD(momentum=0.9)
-    for bad in (
-        dict(pmean_fusion="nope"),
-        dict(pmean_fusion="per_leaf", shard_weight_update=True),
-        dict(pmean_fusion="per_leaf", grad_compression="int8"),
-        dict(rs_ag_chunks=0),
-        dict(rs_ag_chunks=2),  # needs shard_weight_update
-        dict(rs_ag_chunks=2, shard_weight_update=True,
-             grad_compression="int8"),
-    ):
-        with pytest.raises(ValueError):
-            make_train_step(model.apply, opt, mesh, sync_bn=False, **bad)
-
-
-@pytest.mark.slow
-def test_knob_numerics_bit_exact():
-    """The semantics-preserving contract, executed: per-leaf pmean and
-    chunked RS+AG produce bit-identical params/metrics to the fused /
-    unchunked defaults (and a huge chunk count clamps, not crashes)."""
-    import jax.numpy as jnp
-
-    from tpu_dist.train import step as step_lib
-    from tpu_dist.train.optim import SGD
-
-    mesh = mesh_lib.data_parallel_mesh()
-    model = TinyConvNet(num_classes=10, width=16)
-    params, bn = model.init(jax.random.PRNGKey(0))
-    x = np.random.default_rng(0).normal(size=(64, 8, 8, 3)).astype(np.float32)
-    y = np.random.default_rng(1).integers(0, 10, size=(64,)).astype(np.int32)
-
-    def run(**kw):
-        opt = SGD(momentum=0.9)
-        st = TrainState.create(params, bn, opt)
-        if kw.get("shard_weight_update"):
-            st = st._replace(opt_state=step_lib.init_sharded_opt_state(
-                params, mesh, optimizer=opt
-            ))
-        step = step_lib.make_train_step(
-            model.apply, opt, mesh, sync_bn=False, donate=False, **kw
-        )
-        st2, m = step(st, x, y, jnp.float32(0.1))
-        leaves = [np.asarray(l) for l in jax.tree_util.tree_leaves(st2.params)]
-        return leaves, {k: float(v) for k, v in m.items()}
-
-    p_f, m_f = run()
-    p_l, m_l = run(pmean_fusion="per_leaf")
-    for a, b in zip(p_f, p_l):
-        np.testing.assert_array_equal(a, b)
-    assert m_f == m_l
-
-    p_z1, m_z1 = run(shard_weight_update=True)
-    p_z4, m_z4 = run(shard_weight_update=True, rs_ag_chunks=4)
-    for a, b in zip(p_z1, p_z4):
-        np.testing.assert_array_equal(a, b)
-    assert m_z1 == m_z4
-
-    p_big, _ = run(shard_weight_update=True, rs_ag_chunks=10_000_000)
-    for a, b in zip(p_z1, p_big):
-        np.testing.assert_array_equal(a, b)
-
-
-@pytest.mark.slow
-def test_tune_real_families_clean_and_probe_caught():
-    """The full search on the audit models: zero TD121 violations, no
-    skipped families, every chosen knob recorded — and the injected-
-    payload probe flags, proving the detector lives (CLI exit-2 path)."""
-    report, violations = overlap_lib.tune()
-    assert violations == [], [v.message for v in violations]
-    assert report["skips"] == {}, report["skips"]
-    assert set(report["families"]) == set(overlap_lib.tunable_families())
-    for fam, entry in report["families"].items():
-        assert "knobs" in entry["chosen"], fam
-        # every non-baseline candidate carried a TD121 verdict
-        for cand in entry["candidates"]:
-            if cand["knobs"]:
-                assert cand["td121"]["clean"], (fam, cand["knobs"])
-
-    flagged = overlap_lib.recheck_report(overlap_lib.inject_payload(report))
-    assert flagged, "injected payload perturbation NOT flagged: dead detector"
-    assert overlap_lib.recheck_report(report) == []

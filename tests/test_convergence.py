@@ -47,8 +47,6 @@ def test_accuracy_rises_above_chance():
     assert np.mean(accs[-10:]) > 60.0, np.mean(accs[-10:])  # chance = 25%
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_converges_on_learnable_dataset():
     """Full Trainer (streaming pipeline + eval) reaches well-above-chance
     VALIDATION accuracy on the learnable synthetic task — the closest

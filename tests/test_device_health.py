@@ -436,9 +436,8 @@ def test_compare_missing_metrics_reported_skipped_not_dropped(tmp_path):
     skipped = {r["metric"] for r in result["rows"] if r["verdict"] == "skipped"}
     assert skipped == {"mfu_mean", "final_val_top1", "goodput_frac",
                        "overlap_frac", "collective_frac",
-                       "peak_hbm_bytes", "planner_error_frac", "ckpt_s",
-                       "preempt_for_serve_s"}
-    assert result["skipped"] == 9
+                       "peak_hbm_bytes", "ckpt_s", "preempt_for_serve_s"}
+    assert result["skipped"] == 8
 
 
 def test_compare_bench_mode_matches_by_metric_name(tmp_path):

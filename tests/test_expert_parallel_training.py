@@ -20,8 +20,6 @@ def _model():
                      n_experts=8, capacity_factor=8.0, num_classes=5)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_dp_ep_training_matches_per_shard_dense():
     """2×4 DP×EP step ≡ dense MoE computed shard-by-shard on one device
     (routing/capacity is per token shard in both)."""
@@ -83,8 +81,6 @@ def test_dp_ep_training_matches_per_shard_dense():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_ep_e2e_with_eval_and_resume(tmp_path):
     cfg = TrainConfig(
         dataset="synthetic", model="vit_moe_tiny", num_classes=10, batch_size=16,

@@ -198,8 +198,6 @@ def test_fsdp_eval_step_sums_contract():
     assert np.isfinite(float(sums["loss"]))
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_fsdp_e2e_with_resume(tmp_path):
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer, register_model

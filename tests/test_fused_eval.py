@@ -1,6 +1,5 @@
 """Device-resident fused eval: exact sums, matches the streaming evaluator."""
 
-import pytest
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -45,8 +44,6 @@ def test_fused_eval_counts_and_matches_direct_forward():
     assert int(sums["top1"]) == expect_top1
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_fused_mode_evaluates():
     cfg = TrainConfig(
         dataset="synthetic", model="tiny_resnet_fe", num_classes=10,

@@ -322,28 +322,73 @@ def test_td108_rule_registered():
 # -- forward-compat: unknown kinds / future schema ---------------------------
 
 
-def test_summarize_skips_unknown_kinds_with_count():
+# kinds this reader has never heard of, and the two it once folded: a
+# history written before PR 31 holds ``plan`` / ``tune`` lines (the
+# planner's and the tuner's announcements, at the current schema) and
+# must load and summarise as before, those lines counted as unknown
+_FOREIGN_KINDS = {
+    "future": (
+        [
+            {"kind": "hologram", "epoch": 0, "schema_version": 16, "ts": 3.0},
+            {"kind": "hologram", "epoch": 1, "schema_version": 16, "ts": 4.0},
+            {"kind": "quantum_foam", "schema_version": 16, "ts": 5.0},
+        ],
+        {"hologram": 2, "quantum_foam": 1}, 3,
+    ),
+    "retired_plan_tune": (
+        [
+            {"kind": "plan", "epoch": 0, "schema_version": 15, "ts": 3.0,
+             "run_id": "r", "family": "zero1_sgd", "mode": "apply",
+             "applied": True, "predicted_step_s": 0.012,
+             "gauge_source": "uncalibrated_defaults", "n_candidates": 9,
+             "n_refused": 0},
+            {"kind": "plan", "epoch": 0, "schema_version": 15, "ts": 4.0,
+             "run_id": "r", "family": "zero1_sgd", "mode": "apply",
+             "predicted_step_s": 0.012, "achieved_step_s": 0.02,
+             "prediction_source": "plan"},
+            {"kind": "tune", "epoch": 0, "schema_version": 15, "ts": 5.0,
+             "run_id": "r", "family": "zero1_sgd", "report": "t.json",
+             "objective": "sched_distance", "applied": {},
+             "user_overrides": {}},
+        ],
+        {"plan": 2, "tune": 1}, 0,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FOREIGN_KINDS))
+def test_summarize_skips_unknown_kinds_with_count(case, tmp_path):
     """The mixed v4/v5(/v6) regression: older tooling reading a newer log
     (and vice versa) must skip-with-count, not crash or silently drop."""
-    records = [
+    foreign, skipped, newer = _FOREIGN_KINDS[case]
+    path = tmp_path / "run.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in [
         {"kind": "train_epoch", "epoch": 0, "run_id": "r", "ts": 1.0,
          "rel_s": 1.0, "schema_version": 3, "epoch_time": 1.0,
          "images_per_sec": 100.0, "loss": 2.0},
         _goodput_rec("r", 2.0, 2.0, epoch=0, window_s=2.0,
                      productive_s=1.5, unattributed_s=0.5),
-        # a future schema's record kinds: skipped, counted, noted
-        {"kind": "hologram", "epoch": 0, "schema_version": 16, "ts": 3.0},
-        {"kind": "hologram", "epoch": 1, "schema_version": 16, "ts": 4.0},
-        {"kind": "quantum_foam", "schema_version": 16, "ts": 5.0},
-    ]
+        *foreign,
+    ]))
+    records, bad = load_records(str(path))
+    assert bad == 0 and len(records) == 2 + len(foreign)
     report = summarize(records)
-    assert report["skipped_kinds"] == {"hologram": 2, "quantum_foam": 1}
-    assert report["newer_schema_records"] == 3
+    assert report["skipped_kinds"] == skipped
+    assert report["newer_schema_records"] == newer
     assert report["totals"]["n_epochs"] == 1  # known kinds still parsed
     assert report["goodput"]["productive_s"] == pytest.approx(1.5)
     text = format_text(report)
-    assert "skipped 3 record(s) of unknown kind(s)" in text
-    assert "hologram×2" in text and "newer than this reader" in text
+    n = sum(skipped.values())
+    assert f"skipped {n} record(s) of unknown kind(s)" in text
+    if newer:
+        assert "hologram×2" in text and "newer than this reader" in text
+    else:
+        assert "plan×2" in text and "newer than this reader" not in text
+    # and the compare gate reads such a log against itself as clean
+    from tpu_dist.obs import compare
+
+    result = compare.compare_files(str(path), str(path))
+    assert result["regressions"] == 0
 
 
 def test_summarize_renders_goodput_table():

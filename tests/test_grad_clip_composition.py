@@ -8,7 +8,6 @@ parallelism. The norm under model parallelism is computed shard-aware
 psum over their model axes, replicated leaves locally.
 """
 
-import pytest
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -184,8 +183,6 @@ def test_grad_clip_under_pp_matches_single_device():
     _assert_params_match(s_pp, s_1.params)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_accepts_clip_with_model_parallelism():
     """The trainer-level walls are lifted too: tp/ep/pp + grad_clip_norm
     train a finite step end to end."""

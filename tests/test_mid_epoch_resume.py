@@ -71,8 +71,6 @@ def test_loader_iter_from_matches_full_tail():
         np.testing.assert_array_equal(fl, tl)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_interrupt_at_step_k_resume_matches_uninterrupted(tmp_path, monkeypatch):
     # A: the uninterrupted reference trajectory
     t_full = Trainer(_cfg())
@@ -162,8 +160,6 @@ def test_reinterrupt_before_first_resumed_step_keeps_exact_position(
     assert read_meta(path).get("mid_epoch_step") == 3
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_mid_epoch_resume_sharded_ckpt(tmp_path, monkeypatch):
     """The exact-resume meta rides the sharded-checkpoint format too: the
     emergency snapshot goes through ShardedCheckpointer with the same
@@ -202,8 +198,6 @@ def test_mid_epoch_resume_sharded_ckpt(tmp_path, monkeypatch):
     _params_equal(t2.state.opt_state, want.opt_state)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_periodic_mid_epoch_snapshots_survive_kill(tmp_path):
     """--mid_epoch_save_every: periodic exact snapshots DURING the epoch,
     so a hard kill (no interrupt handler, no emergency save) loses at most

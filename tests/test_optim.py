@@ -109,7 +109,6 @@ def test_adamw_auto_mask_matches_optax_masked():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_adamw_e2e_with_resume(tmp_path):
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer, register_model
@@ -295,7 +294,6 @@ def test_linear_scaling_rule_and_warmup():
     assert multistep_lr(0.8, (10,), 0.1)(0) == pytest.approx(0.8)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_trainer_lars_e2e_and_refusals(tmp_path):
     """LARS end-to-end through the Trainer with the full large-batch
     recipe (linear scaling + warmup), plus the two config refusals: the
@@ -321,7 +319,6 @@ def test_trainer_lars_e2e_and_refusals(tmp_path):
         Trainer(cfg.replace(optimizer="lamb", shard_weight_update=True))
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_adamw_tp_e2e():
     """AdamW under tensor parallelism: {mu,nu,count} placed/spec'd via
     optimizer.state_specs, train + eval run (the pytree-mismatch trap)."""

@@ -207,7 +207,6 @@ def test_moe_top2_first_choices_outrank_second_choices():
     assert pack[1, 0].sum() == 0.0, "token 1's SECOND choice (E0) is dropped"
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_moe_top2_e2e():
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer
@@ -244,7 +243,6 @@ def test_moe_aux_loss_values():
     assert float(aux_c) > 2.5
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_moe_aux_loss_threads_through_train_step():
     """vit_moe returns the aux loss in its state; the train step must pop
     it (stable TrainState structure) and fold coef*aux into the loss."""

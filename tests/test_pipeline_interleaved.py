@@ -49,7 +49,6 @@ def test_interleaved_sequential_forward_matches_plain_vit():
                                rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_interleaved_pp_training_matches_single_device():
     model = _model(interleave=2)
     opt = SGD()
@@ -111,7 +110,6 @@ def test_trainer_pp_interleaved_e2e():
     assert np.isfinite(out["loss"])
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_interleaved_m2s_matches_single_device():
     """M = 2S: the buffered lap-boundary handoff (depth M-S+1 ring buffer)
     must reproduce sequential numerics exactly (VERDICT r2 #7)."""
@@ -191,7 +189,6 @@ def test_interleave_rejects_bad_configs():
         )(jnp.zeros((2, 2, 4)))
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_interleaved_ckpt_refuses_layout_mismatch(tmp_path):
     """Interleaved storage permutes block order on disk — resuming under a
     different pp/pp_interleave must be refused, not run silently wrong."""
@@ -230,7 +227,6 @@ def test_interleave_without_pp_is_refused():
         ))
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_untagged_ckpt_refused_by_interleaved_resume(tmp_path):
     """A pre-layout-tag checkpoint (logical block order) must not be
     resumed by an interleaved config."""

@@ -65,7 +65,6 @@ def test_dp_pp_training_matches_single_device():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_pp_e2e_with_eval_and_resume(tmp_path):
     cfg = TrainConfig(
         dataset="synthetic", model="vit_pp_tiny", num_classes=10, batch_size=16,

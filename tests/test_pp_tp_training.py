@@ -119,8 +119,6 @@ def test_dp_pp_tp_with_grad_clip_matches_single_device():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_trainer_pp_tp_e2e_with_eval(tmp_path):
     cfg = TrainConfig(
         dataset="synthetic", model="vit_pp_tiny", num_classes=10, batch_size=16,

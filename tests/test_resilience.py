@@ -425,7 +425,7 @@ def test_launcher_propagates_preemption_exit_code():
     assert rc == PREEMPTION_EXIT_CODE
 
 
-def test_launcher_crash_outranks_concurrent_preemption():
+def test_launcher_crash_outranks_concurrent_preemption(tmp_path):
     """A child crashing for real while another is preempted must surface
     the CRASH code — '75, requeue me' would loop the orchestrator on a
     genuine bug forever."""
@@ -433,11 +433,22 @@ def test_launcher_crash_outranks_concurrent_preemption():
 
     from tpu_dist.cli.launch import main as launch_main
 
+    # rank 0 leaves only once rank 1 ignores SIGTERM: on a loaded host the
+    # launcher's fail-fast SIGTERM otherwise reaches rank 1 while its
+    # interpreter is still starting, and it dies on the raw signal (-15,
+    # no crash) instead of exiting 1
+    ready = str(tmp_path / "rank1_ready")
     code = (
-        "import signal, sys, time\n"
+        "import os, signal, sys, time\n"
         "signal.signal(signal.SIGTERM, signal.SIG_IGN)\n"
         "rank = int(sys.argv[sys.argv.index('--process_id') + 1])\n"
-        "time.sleep(0.3 * rank)\n"
+        f"ready = {ready!r}\n"
+        "if rank == 1:\n"
+        "    open(ready, 'w').close()\n"
+        "    time.sleep(0.3)\n"
+        "else:\n"
+        "    while not os.path.exists(ready):\n"
+        "        time.sleep(0.01)\n"
         f"sys.exit({PREEMPTION_EXIT_CODE} if rank == 0 else 1)\n"
     )
     rc = launch_main(["--nproc", "2", "--", sys.executable, "-c", code])

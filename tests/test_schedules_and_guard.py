@@ -68,8 +68,6 @@ def test_nan_guard_catches_between_log_steps():
         Trainer(cfg).train_epoch(0)
 
 
-@pytest.mark.slow  # >10s e2e: excluded from the timed tier-1 gate; the
-# quick slice keeps a fast representative of this subsystem in the gate
 def test_nan_guard_covers_fused_epoch():
     cfg = TrainConfig(
         dataset="synthetic", model="tiny_resnet_g", num_classes=10,
@@ -91,7 +89,6 @@ def test_no_nan_guard_cli_flag():
     assert config_from_args(p.parse_args([])).nan_guard is True
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_auto_recover_reloads_and_backs_off(tmp_path):
     """--auto_recover: epoch 0 trains and checkpoints at lr=0.1, the
     milestone then multiplies LR by 1e13 and epoch 1 diverges; recovery
@@ -116,7 +113,6 @@ def test_auto_recover_reloads_and_backs_off(tmp_path):
     assert any(e.get("kind") == "auto_recover" for e in events), events
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_auto_recover_exhausted_reraises(tmp_path):
     cfg = TrainConfig(
         dataset="synthetic", model="tiny_resnet_g", num_classes=10,
@@ -129,7 +125,6 @@ def test_auto_recover_exhausted_reraises(tmp_path):
         Trainer(cfg).fit()
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_auto_recover_without_ckpt_reraises(tmp_path):
     # divergence in epoch 0, nothing saved yet: nothing to recover FROM
     cfg = TrainConfig(
@@ -142,7 +137,6 @@ def test_auto_recover_without_ckpt_reraises(tmp_path):
         Trainer(cfg).fit()
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_auto_recover_scale_survives_resume(tmp_path):
     """The backoff is stamped into checkpoint meta: a --resume after a
     recovered run continues with the SCALED schedule instead of replaying

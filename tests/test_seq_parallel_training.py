@@ -54,7 +54,6 @@ def test_dp_sp_training_matches_single_device():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-4, atol=3e-5)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_sp_e2e():
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer
@@ -154,7 +153,6 @@ def test_dp_sp_ulysses_training_matches_single_device():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-4, atol=3e-5)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_trainer_sp_ulysses_e2e():
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer

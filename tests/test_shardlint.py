@@ -306,27 +306,32 @@ def test_extended_families_clean():
 # -- shard_report.json: schema-pinned round-trip -----------------------------
 
 
-def test_shard_report_roundtrip(tmp_path):
+@pytest.fixture(scope="module")
+def dp_report():
     report, _ = shardlint.build_shard_report(names=["dp_sgd"])
+    return report
+
+
+def test_shard_report_roundtrip(tmp_path, dp_report):
+    report = dp_report
     path = str(tmp_path / "shard_report.json")
     shardlint.save_shard_report(report, path)
     loaded = shardlint.load_shard_report(path)
     assert loaded["schema"] == shardlint.SCHEMA
     fam = loaded["families"]["dp_sgd"]
     assert fam["hlo"]["bytes"] == report["families"]["dp_sgd"]["hlo"]["bytes"]
-    # planner-facing keys present
+    # pricing keys present
     for key in ("collectives", "hbm", "cost", "predicted_step", "verdict"):
         assert key in fam
     # a FOREIGN schema tag is a typed, loud error (a newer
-    # shard_report_vN is tolerated instead — see
-    # test_planner.py::test_shard_report_newer_schema_tolerated_with_count)
-    bad = dict(loaded, schema="plan_report_v1")
+    # shard_report_vN is tolerated instead — the next test)
+    bad = dict(loaded, schema="other_report_v1")
     bad_path = str(tmp_path / "bad.json")
     with open(bad_path, "w") as f:
         json.dump(bad, f)
     with pytest.raises(ShardReportError, match="schema"):
         shardlint.load_shard_report(bad_path)
-    # a family entry missing planner keys is equally loud
+    # a family entry missing pricing keys is equally loud
     broken = json.loads(json.dumps(loaded))
     del broken["families"]["dp_sgd"]["predicted_step"]
     broken_path = str(tmp_path / "broken.json")
@@ -334,6 +339,22 @@ def test_shard_report_roundtrip(tmp_path):
         json.dump(broken, f)
     with pytest.raises(ShardReportError, match="missing"):
         shardlint.load_shard_report(broken_path)
+
+
+def test_shard_report_newer_schema_tolerated_with_count(tmp_path, dp_report):
+    """Forward compat: a newer-versioned report keeps its readable
+    families and skips-with-count the ones missing the v1 keys (the
+    same-version case above is corruption and raises)."""
+    future = json.loads(json.dumps(dp_report))
+    future["schema"] = "shard_report_v2"
+    future["families"]["v2_only"] = {"note": "no v1 keys at all"}
+    path = str(tmp_path / "future_shard.json")
+    with open(path, "w") as f:
+        json.dump(future, f)
+    loaded = shardlint.load_shard_report(path)
+    assert "v2_only" not in loaded["families"]
+    assert loaded["load_notes"]["skipped_count"] == 1
+    assert "dp_sgd" in loaded["families"]
 
 
 def test_predicted_step_time_calibration():

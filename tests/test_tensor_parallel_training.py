@@ -86,7 +86,6 @@ def test_tp_forward_parity():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_tp_e2e_with_eval_and_resume(tmp_path):
     cfg = TrainConfig(
         dataset="synthetic", model="vit_tiny", num_classes=10, batch_size=16,

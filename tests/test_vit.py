@@ -14,7 +14,6 @@ from tpu_dist.train.state import TrainState
 from tpu_dist.train.step import make_train_step
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_vit_b16_param_count():
     # ViT-B/16 published size ≈ 86.6M (ImageNet-1k head, no cls token here)
     p, _ = vit_b16().init(jax.random.PRNGKey(0))
@@ -31,7 +30,6 @@ def test_vit_s16_param_count():
     assert 20e6 < n < 23e6, n
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_vit_b16_accepts_smaller_images():
     # --model vit_b16 on CIFAR-sized input: uses the leading pos embeddings
     m = vit_b16(num_classes=10)
@@ -58,7 +56,6 @@ def test_vit_forward_shape():
     assert bool(jnp.all(jnp.isfinite(logits)))
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 18): gates in analysis.yml
 def test_vit_trains_in_dp_step():
     mesh = mesh_lib.data_parallel_mesh()
     m = vit_tiny()

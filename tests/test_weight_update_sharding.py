@@ -4,7 +4,6 @@ plain allreduce+full-update path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from tpu_dist.comm import mesh as mesh_lib
 from tpu_dist.train.optim import SGD
@@ -49,7 +48,6 @@ def test_sharded_update_matches_plain():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_zero1_e2e_with_resume(tmp_path):
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer, register_model
@@ -126,7 +124,6 @@ def test_sharded_update_matches_plain_adamw():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 17): gates in analysis.yml
 def test_trainer_zero1_adamw_e2e_with_resume(tmp_path):
     from tpu_dist.config import TrainConfig
     from tpu_dist.train.trainer import Trainer, register_model

@@ -19,8 +19,7 @@ Three layers (see ``docs/analysis.md``):
   the program GSPMD actually emitted — into a structured collective
   inventory; the compiled accounting must agree with the jaxpr ring model
   (TD116) and carry no unpredicted reshard (TD117). Emits
-  ``shard_report.json``, the ``--auto_shard`` planner input
-  (docs/shard_report.md).
+  ``shard_report.json`` (docs/shard_report.md).
 
 CLI: ``python -m tpu_dist.analysis [--format text|json] [--baseline F]``
 for Layers 1+2; ``python -m tpu_dist.analysis shard [--out F]`` for

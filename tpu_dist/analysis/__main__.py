@@ -8,18 +8,7 @@ docs/analysis.md's rule table is tested against); text mode prints
 
 ``python -m tpu_dist.analysis shard`` runs Layer 3 — the static HLO
 sharding & collective audit (TD116/TD117) — and writes/prints the
-``shard_report.json`` planner input (docs/shard_report.md).
-
-``python -m tpu_dist.analysis plan`` runs Layer 4 — the static
-``--auto_shard`` planner: enumerate + price + HBM-filter + rank the
-config families, TD118-verify the chosen plan against a fresh compile,
-and write the schema-pinned ``plan_report.json`` (docs/planner.md).
-
-``python -m tpu_dist.analysis tune-overlap`` runs Layer 4b — the
-comm/compute overlap autotuner: search the collective-scheduling knobs
-(pmean_fusion, quant_chunk, rs_ag_chunks), TD121-gate every candidate
-(payload bytes pinned, schedule must move), and write the schema-pinned
-``tune_report.json`` the planner/trainer consume (docs/analysis.md).
+``shard_report.json`` (docs/shard_report.md).
 """
 
 from __future__ import annotations
@@ -51,7 +40,7 @@ def shard_main(argv) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m tpu_dist.analysis shard",
         description="static HLO sharding & collective audit (TD116/TD117) "
-        "— writes the shard_report.json the --auto_shard planner reads",
+        "— writes shard_report.json",
     )
     ap.add_argument("--format", choices=("text", "json"), default="text")
     ap.add_argument(
@@ -130,237 +119,11 @@ def shard_main(argv) -> int:
     return 1 if violations else 0
 
 
-def plan_main(argv) -> int:
-    """The ``plan`` subcommand: the static ``--auto_shard`` planner —
-    enumerate, price, HBM-filter, rank, TD118-verify, emit the plan."""
-    ap = argparse.ArgumentParser(
-        prog="python -m tpu_dist.analysis plan",
-        description="static --auto_shard planner: rank the config "
-        "families by calibrated predicted step time under the per-chip "
-        "HBM budget, TD118-verify the chosen plan, write plan_report.json",
-    )
-    ap.add_argument("--format", choices=("text", "json"), default="text")
-    ap.add_argument(
-        "--out", default=None,
-        help="write the schema-pinned plan_report.json here",
-    )
-    ap.add_argument(
-        "--family", action="append",
-        help="restrict the search to this config family (repeatable)",
-    )
-    ap.add_argument("--list-families", action="store_true")
-    ap.add_argument(
-        "--from-report", default=None, metavar="SHARD_REPORT",
-        help="price candidates from an existing shard_report.json "
-        "instead of recompiling each family (the TD118 verification "
-        "still compiles the chosen family fresh)",
-    )
-    ap.add_argument(
-        "--tune-report", default=None, metavar="TUNE_REPORT",
-        help="tune_report.json from `tune-overlap`: attach the tuner's "
-        "chosen schedule knobs to every candidate (tune_knobs) — knobs "
-        "never change the ranking (TD121: schedule-only transforms)",
-    )
-    ap.add_argument(
-        "--hbm_budget_bytes", type=int, default=None,
-        help="per-device HBM budget override (default: the chip table; "
-        "unknown chips — CPU emulation — skip the feasibility filter)",
-    )
-    ap.add_argument(
-        "--memory_headroom", type=float, default=0.9, metavar="FRAC",
-        help="fraction of the budget the static ledger may claim",
-    )
-    ap.add_argument(
-        "--inject-miscost", action="store_true",
-        help="ALSO run TD118 over a deliberately mis-priced copy of the "
-        "plan (perturbed wire bytes) — its violations are expected and "
-        "prove the detector is alive; exit 2 if it comes back clean",
-    )
-    args = ap.parse_args(argv)
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from tpu_dist.analysis import planner, shardlint
-
-    if args.list_families:
-        for name in planner.plan_candidates(jax.device_count()):
-            print(name)
-        return 0
-    unknown = sorted(
-        set(args.family or ()) - set(shardlint.registered_families())
-    )
-    if unknown:
-        print(
-            f"tpu_dist.analysis plan: unknown famil(ies) {unknown}; "
-            f"registered: {shardlint.registered_families()}",
-            file=sys.stderr,
-        )
-        return 2
-    shard_report = None
-    if args.from_report:
-        try:
-            shard_report = shardlint.load_shard_report(args.from_report)
-        except (OSError, ValueError) as e:
-            print(f"tpu_dist.analysis plan: {e}", file=sys.stderr)
-            return 2
-    tune_report = None
-    if args.tune_report:
-        from tpu_dist.analysis import overlap as overlap_lib
-
-        try:
-            tune_report = overlap_lib.load_tune_report(args.tune_report)
-        except (OSError, ValueError) as e:
-            print(f"tpu_dist.analysis plan: {e}", file=sys.stderr)
-            return 2
-    plan = planner.build_plan(
-        names=args.family,
-        hbm_budget_bytes=args.hbm_budget_bytes,
-        memory_headroom=args.memory_headroom,
-        shard_report=shard_report,
-        tune_report=tune_report,
-    )
-    probe, violations = planner.verify_plan(plan)
-    plan["verification"] = probe
-    if args.inject_miscost:
-        inj_probe, inj_vs = planner.verify_plan(
-            planner.inject_miscost(plan)
-        )
-        plan["injected_miscost_probe"] = {
-            "violations": inj_probe.get("violations", []),
-            "caught": bool(inj_vs),
-        }
-        if not inj_vs:
-            print(
-                "tpu_dist.analysis plan: the injected mis-priced plan "
-                "came back CLEAN — the TD118 detector is dead",
-                file=sys.stderr,
-            )
-            return 2
-    if args.out:
-        planner.save_plan_report(plan, args.out)
-    if args.format == "json":
-        print(json.dumps(plan, indent=2, sort_keys=True))
-    else:
-        print(planner.format_text(plan))
-        for v in violations:
-            print(v.format_text())
-        if args.out:
-            print(f"autoplan: wrote {args.out}")
-    if plan["counts"]["skipped"] and not args.family:
-        # a full search that silently lost families must be loud (the
-        # same degrade-per-family/fail-the-gate contract shard has)
-        print(
-            f"tpu_dist.analysis plan: {plan['counts']['skipped']} "
-            f"famil(ies) skipped: {plan['skips']}",
-            file=sys.stderr,
-        )
-        return 2
-    return 1 if violations else 0
-
-
-def tune_main(argv) -> int:
-    """The ``tune-overlap`` subcommand: Layer 4b — search the
-    collective-scheduling knobs, TD121-gate, emit tune_report.json."""
-    ap = argparse.ArgumentParser(
-        prog="python -m tpu_dist.analysis tune-overlap",
-        description="comm/compute overlap autotuner: search the "
-        "schedule-only collective knobs per config family (TD121-gated: "
-        "payload bytes pinned, schedule must move), write tune_report.json",
-    )
-    ap.add_argument("--format", choices=("text", "json"), default="text")
-    ap.add_argument(
-        "--out", default=None,
-        help="write the schema-pinned tune_report.json here",
-    )
-    ap.add_argument(
-        "--family", action="append",
-        help="tune only this config family (repeatable)",
-    )
-    ap.add_argument("--list-families", action="store_true")
-    ap.add_argument(
-        "--capture", default=None, metavar="DIR",
-        help="jax.profiler capture dir: use the measured overlap_frac "
-        "as the objective instead of the HLO schedule proxy",
-    )
-    ap.add_argument(
-        "--inject-payload", action="store_true",
-        help="ALSO re-gate a deliberately payload-perturbed copy of the "
-        "report — its TD121 findings are expected and prove the detector "
-        "is alive; exit 2 if it comes back clean",
-    )
-    args = ap.parse_args(argv)
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from tpu_dist.analysis import overlap as overlap_lib
-
-    if args.list_families:
-        for name in overlap_lib.tunable_families():
-            print(name)
-        return 0
-    unknown = sorted(
-        set(args.family or ()) - set(overlap_lib.tunable_families())
-    )
-    if unknown:
-        print(
-            f"tpu_dist.analysis tune-overlap: unknown/untunable "
-            f"famil(ies) {unknown}; tunable: "
-            f"{overlap_lib.tunable_families()}",
-            file=sys.stderr,
-        )
-        return 2
-    report, violations = overlap_lib.tune(
-        names=args.family, capture_dir=args.capture
-    )
-    if args.inject_payload:
-        inj_vs = overlap_lib.recheck_report(
-            overlap_lib.inject_payload(report)
-        )
-        report["injected_payload_probe"] = {
-            "violations": [v.to_json() for v in inj_vs],
-            "caught": bool(inj_vs),
-        }
-        if not inj_vs:
-            print(
-                "tpu_dist.analysis tune-overlap: the injected payload-"
-                "perturbed report came back CLEAN — the TD121 detector "
-                "is dead",
-                file=sys.stderr,
-            )
-            return 2
-    if args.out:
-        overlap_lib.save_tune_report(report, args.out)
-    if args.format == "json":
-        print(json.dumps(report, indent=2, sort_keys=True))
-    else:
-        print(overlap_lib.format_text(report))
-        for v in violations:
-            print(v.format_text())
-        if args.out:
-            print(f"tune-overlap: wrote {args.out}")
-    if report["counts"]["skipped"] and not args.family:
-        # same degrade-per-family/fail-the-gate contract as shard/plan
-        print(
-            f"tpu_dist.analysis tune-overlap: "
-            f"{report['counts']['skipped']} famil(ies) skipped: "
-            f"{report['skips']}",
-            file=sys.stderr,
-        )
-        return 2
-    return 1 if violations else 0
-
-
 def main(argv=None) -> int:
     if argv is None:
         argv = sys.argv[1:]
     if argv and argv[0] == "shard":
         return shard_main(argv[1:])
-    if argv and argv[0] == "plan":
-        return plan_main(argv[1:])
-    if argv and argv[0] == "tune-overlap":
-        return tune_main(argv[1:])
     ap = argparse.ArgumentParser(
         prog="python -m tpu_dist.analysis",
         description="distributed-training lint (TD0xx) + jaxpr audit (TD1xx)",

@@ -126,6 +126,47 @@ def _walk_eqns(jaxpr, mult: int = 1):
             yield from _walk_eqns(sub, sub_mult)
 
 
+def _call_site(eqn):
+    """The Python stack an eqn was bound from, as a hashable key."""
+    tb = getattr(eqn.source_info, "traceback", None)
+    if tb is None:
+        return None
+    return tuple((f.file_name, f.line_num) for f in tb.frames)
+
+
+def _collective_calls(jaxpr):
+    """Yield ``(prim, invars, outvars, multiplicity)`` per collective CALL.
+
+    ``lax.psum(tree)`` / ``lax.pmean(tree)`` bind one eqn PER LEAF since
+    JAX 0.5 (one multi-operand eqn before), so a 4-leaf gradient pmean
+    reads as four psums in the jaxpr although it is one call in the
+    program and XLA's combiner makes it one all-reduce again. The budgets
+    and the payload/sideband cut are statements about calls, so adjacent
+    eqns of one primitive with equal params bound from the same Python
+    stack are folded back into the one call they were (nothing else can
+    sit between the leaves of one call; two calls on different lines, or
+    with arithmetic between them, stay two)."""
+    run = None  # (key, prim, invars, outvars, mult)
+    for eqn, mult in _walk_eqns(jaxpr):
+        name = eqn.primitive.name
+        key = None
+        if name in COLLECTIVE_PRIMS:
+            key = (name, mult, str(sorted(eqn.params.items(), key=str)),
+                   _call_site(eqn))
+            if run is not None and run[0] == key and key[3] is not None:
+                run[2].extend(eqn.invars)
+                run[3].extend(eqn.outvars)
+                continue
+        if run is not None:
+            yield run[1:]
+        run = (
+            (key, name, list(eqn.invars), list(eqn.outvars), mult)
+            if key is not None else None
+        )
+    if run is not None:
+        yield run[1:]
+
+
 # Per-replica wire legs of each collective under the standard ring model:
 # psum (allreduce) = reduce-scatter + all-gather of its operand; the
 # scatter/gather/transpose prims each move their payload once. The common
@@ -148,8 +189,8 @@ _WIRE_LEGS = {
 _PAYLOAD_MIN_ELEMS = 32
 
 
-def _eqn_wire(eqn) -> tuple[int, int, bool]:
-    """``(elements, bytes_on_wire, is_int)`` for one collective eqn."""
+def _call_wire(name, invars, outvars) -> tuple[int, int, bool]:
+    """``(elements, bytes_on_wire, is_int)`` for one collective call."""
     import numpy as np
 
     def total(vars_):
@@ -163,13 +204,13 @@ def _eqn_wire(eqn) -> tuple[int, int, bool]:
             byts += n * (np.dtype(dt).itemsize if dt is not None else 4)
         return elems, byts
 
-    in_e, in_b = total(eqn.invars)
-    out_e, out_b = total(eqn.outvars)
-    legs = _WIRE_LEGS.get(eqn.primitive.name, 1)
+    in_e, in_b = total(invars)
+    out_e, out_b = total(outvars)
+    legs = _WIRE_LEGS.get(name, 1)
     # all_gather/pgather: the wire carries the gathered OUTPUT; everything
     # else is costed on what the replica feeds in
-    e, b = (out_e, out_b) if eqn.primitive.name in ("all_gather", "pgather") else (in_e, in_b)
-    dt = getattr(getattr(eqn.invars[0], "aval", None), "dtype", None)
+    e, b = (out_e, out_b) if name in ("all_gather", "pgather") else (in_e, in_b)
+    dt = getattr(getattr(invars[0], "aval", None), "dtype", None)
     # quantized payload is specifically the 8-bit wire (int32 scalar
     # METRIC reduces — correct-count psums — are sideband, not payload)
     is_quant = dt is not None and np.dtype(dt).itemsize == 1
@@ -216,13 +257,13 @@ def trace_counts(fn, *args) -> dict:
     transfers = 0
     bf16_to_f32 = 0
     wire_records = []
+    for name, invars, outvars, mult in _collective_calls(closed.jaxpr):
+        collectives[name] += mult
+        elems, byts, is_int = _call_wire(name, invars, outvars)
+        wire_records.append((name, elems, byts, is_int, mult))
     for eqn, mult in _walk_eqns(closed.jaxpr):
         name = eqn.primitive.name
-        if name in COLLECTIVE_PRIMS:
-            collectives[name] += mult
-            elems, byts, is_int = _eqn_wire(eqn)
-            wire_records.append((name, elems, byts, is_int, mult))
-        elif name in TRANSFER_PRIMS:
+        if name in TRANSFER_PRIMS:
             transfers += mult
         elif name == "convert_element_type":
             (invar,) = eqn.invars
@@ -366,9 +407,9 @@ _BF16_CONVERTS = 6
 
 # The dp/zero1 flag combos come from the ONE config-family registry
 # (``train/step.py::SHARD_CONFIG_FAMILIES``) shared with the shardlint
-# HLO audit and the future --auto_shard planner — a family added there is
-# automatically the same flags here, so the two static accountings (jaxpr
-# ring model, compiled HLO) always describe the same program.
+# HLO audit — a family added there is automatically the same flags here,
+# so the two static accountings (jaxpr ring model, compiled HLO) always
+# describe the same program.
 
 
 def _family_setup(mesh, family: str):
@@ -1464,7 +1505,7 @@ def memory_ledger_noop_violations(mesh=None) -> list[Violation]:
 
 def tenancy_arbitration_noop_violations(mesh=None) -> list[Violation]:
     """TD122: the multi-tenancy cost contract, checked at the program
-    level (the TD105-TD121 armed-vs-off discipline applied to the
+    level (the TD105-TD120 armed-vs-off discipline applied to the
     train+serve co-scheduling plane) — trace the data-parallel train
     step AND the serving forward step with nothing armed, then arm the
     FULL tenancy kit exactly as a co-scheduled pod runs it: a breached

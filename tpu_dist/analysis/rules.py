@@ -244,28 +244,6 @@ RULES: dict[str, Rule] = {
             "(tpu_dist/analysis/shardlint.py)",
         ),
         Rule(
-            "TD118",
-            "plan-must-verify",
-            "the --auto_shard planner's chosen plan was priced on a "
-            "collective inventory that does not match what the fresh "
-            "shardlint compile of the same family emits (per-kind "
-            "op/element/byte counts, total wire bytes) — the ranking "
-            "rests on a stale or perturbed cost basis; the "
-            "--inject-miscost probe must be caught or the detector is "
-            "dead (tpu_dist/analysis/planner.py, docs/planner.md)",
-        ),
-        Rule(
-            "TD119",
-            "planner-error-tracked",
-            "after a profiled run, the predicted-vs-achieved step time "
-            "drift (|predicted - achieved| / achieved) must land in "
-            "history as planner_error_frac ('plan' records, schema v12) "
-            "and gate through `obs compare` METRIC_DIRECTIONS (lower is "
-            "better) — planner drift is a regression like any other "
-            "(tpu_dist/analysis/planner.py, obs/compare.py, "
-            "docs/planner.md)",
-        ),
-        Rule(
             "TD120",
             "async-ckpt-semantics-preserved",
             "the async sharded checkpoint path (--sharded_ckpt + "
@@ -275,18 +253,6 @@ RULES: dict[str, Rule] = {
             "must surface through the drain path — an uncaught probe "
             "means the detector is dead (CLI exit 2) "
             "(tpu_dist/ckpt/checkpoint.py, docs/checkpointing.md)",
-        ),
-        Rule(
-            "TD121",
-            "tuner-knob-schedule-only",
-            "an overlap-autotuner knob (pmean_fusion, rs_ag_chunks, "
-            "quant_chunk) changed the HLO payload-byte inventory shardlint "
-            "pins, or failed to move the collective schedule at all — "
-            "knobs must be semantics-preserving schedule transforms by "
-            "construction, and a payload drift or a vacuous knob is a "
-            "lying search space; the --inject-payload probe must be "
-            "caught or the detector is dead (CLI exit 2) "
-            "(tpu_dist/analysis/overlap.py, docs/analysis.md)",
         ),
         Rule(
             "TD122",

@@ -25,13 +25,13 @@ Two rules ride on the inventory:
   GSPMD gather state the step expected resident
   (:func:`injected_bad_zero1` demonstrates it on the ZeRO-1 step).
 
-Config families come from the ONE registry the planner will search
+Config families come from ONE registry
 (``train/step.py::SHARD_CONFIG_FAMILIES``): the dp/zero1/compression
 families reuse the jaxpr-audit model zoo; fsdp (GSPMD engine), tp
 (Megatron ViT), sp (ring attention), and the serve forward step get
 builders here. Each analyzed family lands in ``shard_report.json``
 (:func:`build_shard_report` / :func:`load_shard_report`,
-docs/shard_report.md) — the machine-readable planner input: verified
+docs/shard_report.md): verified
 collective inventory + HLO wire bytes + static HBM ledger + calibrated
 step-time prediction per family.
 
@@ -175,6 +175,29 @@ def _shapes_in(text: str):
     return out
 
 
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _result_shapes(lines: list) -> dict:
+    """``{instruction name: [(dtype, elems), ...]}`` for one computation:
+    the result type each line defines. Newer XLA prints operands by name
+    only (``reduce-scatter(%fusion.3)``), so an operand's shape has to be
+    read off the line that defines it."""
+    out: dict = {}
+    for line in lines:
+        m = _DEF_RE.match(line)
+        if not m:
+            continue
+        rhs = m.group(2)
+        if rhs.startswith("("):  # tuple type: up to its closing paren
+            type_text = "(" + _balanced_operands(rhs, 0) + ")"
+        else:
+            type_text = rhs.split(" ", 1)[0]
+        out[m.group(1)] = _shapes_in(type_text)
+    return out
+
+
 def _balanced_operands(line: str, open_idx: int) -> str:
     """The operand text between the paren at ``open_idx`` and its match
     (TPU tiled layouts like ``{1,0:T(8,128)}`` nest parens)."""
@@ -280,6 +303,7 @@ def parse_hlo_collectives(
     for comp, lines in comps.items():
         in_loop = comp in loop_comps
         trips = loop_trips if in_loop else 1
+        defs = None
         for line in lines:
             m = _KIND_RE.search(line)
             if not m or m.group(3) == "-done":
@@ -289,6 +313,17 @@ def parse_hlo_collectives(
             operand_part = _balanced_operands(line, open_idx)
             attrs = line[open_idx + 1 + len(operand_part):]
             op_shapes = _shapes_in(operand_part)
+            if not op_shapes:
+                # operands printed by name only: take each one's shape
+                # from its defining line (a reduce-scatter's RESULT is
+                # 1/n of what the replica feeds in, so the result is no
+                # stand-in for the operand)
+                if defs is None:
+                    defs = _result_shapes(lines)
+                op_shapes = [
+                    sh for name in _OPERAND_NAME_RE.findall(operand_part)
+                    for sh in defs.get(name, ())
+                ]
             res_shapes = _shapes_in(result_part)
             if kind == "all-gather":
                 # costed on the gathered OUTPUT; async -start results
@@ -373,23 +408,14 @@ def predicted_inventory(fn, *args) -> dict:
     import jax
     import numpy as np
 
-    from tpu_dist.analysis.jaxpr_audit import (
-        COLLECTIVE_PRIMS,
-        _WIRE_LEGS,
-        _walk_eqns,
-    )
+    from tpu_dist.analysis.jaxpr_audit import _WIRE_LEGS, _collective_calls
 
     closed = jax.make_jaxpr(fn)(*args)
     by_kind: dict = {}
-    for eqn, mult in _walk_eqns(closed.jaxpr):
-        name = eqn.primitive.name
-        if name not in COLLECTIVE_PRIMS:
-            continue
+    for name, invars, outvars, mult in _collective_calls(closed.jaxpr):
         kind = PRIM_TO_HLO_KIND.get(name, name)
         legs = _WIRE_LEGS.get(name, 1)
-        vars_ = (
-            eqn.outvars if name in ("all_gather", "pgather") else eqn.invars
-        )
+        vars_ = outvars if name in ("all_gather", "pgather") else invars
         entry = by_kind.setdefault(
             kind,
             {"eqns": 0, "elems": 0, "bytes": 0, "bytes_f32norm": 0,
@@ -991,7 +1017,7 @@ def shard_all(
 
 
 # --------------------------------------------------------------------------
-# shard_report.json — the --auto_shard planner input
+# shard_report.json
 # --------------------------------------------------------------------------
 
 
@@ -1033,8 +1059,7 @@ _SCHEMA_TAG_RE = re.compile(r"^shard_report_v(\d+)$")
 
 
 def load_shard_report(path: str) -> dict:
-    """Schema-pinned loader — the contract the ``--auto_shard`` planner
-    reads through — with the summarize ``KNOWN_KINDS`` forward-compat
+    """Schema-pinned loader with the summarize ``KNOWN_KINDS`` forward-compat
     discipline: a NEWER ``shard_report_v<N>`` tag is tolerated (every
     schema bump is additive) — its extra fields are ignored and any
     family entry missing the v1 pricing keys is skipped with a count

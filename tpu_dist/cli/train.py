@@ -47,7 +47,6 @@ def main(argv: Optional[Sequence[str]] = None, **preset):
     from tpu_dist.train.trainer import Trainer  # lazy: jax init after parse
 
     trainer = Trainer(cfg)
-    cfg = trainer.cfg  # --auto_shard apply may have rewritten the config
     dev0 = trainer.mesh.devices.flat[0]
     rank0_print(
         f"tpu_dist: model={cfg.model} devices={trainer.n_devices} "
@@ -55,15 +54,6 @@ def main(argv: Optional[Sequence[str]] = None, **preset):
         f"global_batch={cfg.batch_size} bf16={cfg.bf16} sync_bn={cfg.sync_bn} "
         f"grad_accu_steps={cfg.grad_accu_steps}"
     )
-    plan = getattr(trainer, "_plan", None)
-    if plan is not None:
-        pred = plan.get("predicted_step_s")
-        rank0_print(
-            f"tpu_dist: auto_shard={plan['mode']} plan={plan['family']}"
-            + (" (applied)" if plan.get("applied") else " (advisory)")
-            + (f" predicted_step={pred:g}s" if pred is not None else "")
-            + f" [rates: {plan.get('gauge_source')}]"
-        )
     try:
         trainer.fit()
     except PreemptedError as e:

@@ -223,33 +223,8 @@ class TrainConfig:
                                    # two-stage quantized RS+AG); int8_ef adds
                                    # error-feedback residuals in TrainState
                                    # (docs/compression.md)
-    quant_chunk: int = 0           # elements per int8 quantization scale
-                                   # (0 = comm/quantize.DEFAULT_CHUNK); a
-                                   # tune-overlap schedule knob — payload
-                                   # bytes are chunk-invariant (TD121)
-    pmean_fusion: str = "fused"    # fused | per_leaf: one multi-operand grad
-                                   # pmean vs one per leaf — schedule-only
-                                   # overlap knob (analysis/overlap.py)
-    rs_ag_chunks: int = 1          # split the ZeRO-1 reduce-scatter/all-
-                                   # gather pair into k pipelined column-
-                                   # group collectives (payload-identical;
-                                   # tune-overlap's zero1 knob)
-    tune_report: str = ""          # path to a tune_report.json (make
-                                   # tune-overlap): apply the tuner's chosen
-                                   # schedule knobs for this config's family
-                                   # (explicit knob flags win over the report)
     sharded_ckpt: bool = False     # per-process shard files + rank-0 manifest;
                                    # no gather at save time (FSDP/ZeRO scale)
-    auto_shard: str = "off"        # off | plan | apply — run the static
-                                   # sharding planner (analysis/planner.py)
-                                   # at startup: enumerate the shardlint
-                                   # family matrix, price each with the
-                                   # calibrated cost model, refuse HBM-
-                                   # infeasible configs through the
-                                   # --memory_check path, print the ranked
-                                   # table. 'apply' additionally rewrites
-                                   # this config to the chosen plan's
-                                   # family (docs/planner.md)
 
     # -- resilience (docs/resilience.md) ------------------------------------
     ckpt_verify: bool = True       # CRC32-verify checkpoints at restore and
@@ -367,29 +342,6 @@ def add_reference_flags(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "the plain DP, fused-epoch, and ZeRO-1 paths; not "
                         "under --fsdp (GSPMD-inserted collectives) or "
                         "sp/tp/ep/pp (docs/compression.md)")
-    p.add_argument("--quant_chunk", type=int, default=d.quant_chunk,
-                   metavar="N",
-                   help="elements per int8 quantization scale (0 = the "
-                        "comm/quantize default) — a tune-overlap schedule "
-                        "knob: payload bytes are chunk-invariant, only the "
-                        "f32 scale sideband granularity moves (TD121)")
-    p.add_argument("--pmean_fusion", choices=("fused", "per_leaf"),
-                   default=d.pmean_fusion,
-                   help="data-parallel grad reduce granularity: one fused "
-                        "multi-operand pmean, or one pmean per gradient "
-                        "leaf (schedule-only overlap knob; identical "
-                        "payload bytes — analysis/overlap.py)")
-    p.add_argument("--rs_ag_chunks", type=int, default=d.rs_ag_chunks,
-                   metavar="K",
-                   help="split the ZeRO-1 reduce-scatter/all-gather pair "
-                        "into K pipelined column-group collectives "
-                        "(payload-identical schedule knob; needs "
-                        "--shard_weight_update)")
-    p.add_argument("--tune_report", type=str, default=d.tune_report,
-                   metavar="PATH",
-                   help="tune_report.json from `make tune-overlap`: apply "
-                        "the tuner's chosen schedule knobs for this "
-                        "config's family (explicitly-set knob flags win)")
     p.add_argument("--no_sync_bn", dest="sync_bn", action="store_false",
                    help="per-replica BatchNorm statistics (SyncBN off)")
     p.add_argument("--no_nan_guard", dest="nan_guard", action="store_false")
@@ -577,17 +529,6 @@ def add_reference_flags(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    help="per-device HBM budget override in bytes "
                         "(default: the chip table — "
                         "obs/costmodel.CHIP_HBM_BYTES)")
-    p.add_argument("--auto_shard", choices=("off", "plan", "apply"),
-                   default=d.auto_shard,
-                   help="static sharding planner at startup "
-                        "(analysis/planner.py): enumerate the shardlint "
-                        "family matrix, price each candidate with the "
-                        "calibrated cost model + HLO wire bytes, refuse "
-                        "HBM-infeasible ones through the --memory_check "
-                        "path, and print the ranked plan (also lands in "
-                        "the history as a 'plan' record, TD119-gated). "
-                        "'apply' rewrites this config to the winning "
-                        "family's flags before training (docs/planner.md)")
     p.add_argument("--per_host_log", action="store_true",
                    help="every process writes its own JSONL history "
                         "(<log_file>.h<rank>; rank 0 keeps the bare path) "

@@ -45,17 +45,11 @@ waterfall, a live-buffer census reconciled against the allocator so
 attributed + unattributed == bytes_in_use exactly; ``event: "oom"``
 records carry a parsed RESOURCE_EXHAUSTED report plus the ledger
 snapshot live at the crash — ``obs/memory.py``, docs/observability.md
-"HBM ledger & OOM forensics"); v12 added the planner layer — the
-``plan`` kind (the ``--auto_shard`` plan chosen at fit() start: family,
-mode, predicted step time, gauge source; after a profiled run a second
-``plan`` record lands with the achieved step time and the TD119
-``planner_error_frac`` drift scalar — ``tpu_dist/analysis/planner.py``,
-docs/planner.md); v13 added the tuner layer — the ``tune`` kind (the
-``--tune_report`` knob application at fit() start: the config's planner
-family, the schedule knobs actually applied, explicit user overrides
-kept, and the tuner objective; the same knobs ride the counter snapshot
-as ``tune.*`` gauges — ``tpu_dist/analysis/overlap.py``,
-docs/analysis.md)
+"HBM ledger & OOM forensics"); v12 and v13 added the ``plan`` and
+``tune`` kinds, the announcements of a static sharding planner and a
+collective-schedule tuner that are gone — both kinds are RETIRED:
+nothing writes them, and an old log that holds them reads like any log
+with kinds the reader does not know (skipped, with a count)
 (docs/observability.md). Consumers (``obs summarize``/``compare``) read
 all versions: every addition is a new kind or optional field, never a
 changed one, and readers skip-with-count kinds they don't know — so a
@@ -89,12 +83,8 @@ SCHEMA_VERSION = 15  # v15 (additive): causal arbitration tracing —
 #                      scheduler's per-tick chip-accounting snapshots
 #                      (alloc/free/pending; tpu_dist/fleet/scheduler.py)
 #                      whose sums make chip-second conservation exact;
-#                      v13 added 'tune' records — the --tune_report
-#                      overlap-autotuner knob application + tune.* gauges
-#                      (tpu_dist/analysis/overlap.py); v12 added 'plan'
-#                      records — the --auto_shard chosen plan + TD119
-#                      predicted-vs-achieved planner_error_frac
-#                      (tpu_dist/analysis/planner.py); v11 'memory'
+#                      v12 'plan' and v13 'tune' are retired
+#                      kinds (no writer; readers skip them); v11 'memory'
 #                      HBM-ledger records (tpu_dist/obs/memory.py);
 #                      v10 'serve' serving-SLO windows; v9 'postmortem'
 #                      crash bundles; v8 'fleet' scheduler decisions;

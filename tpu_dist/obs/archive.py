@@ -12,8 +12,8 @@ and ``obs compare --against-archive``:
   driver's ``BENCH_*.json`` / ``MULTICHIP_*.json`` wrappers (a failed
   probe archives as an empty STALE record — the empty trajectory is
   itself evidence), ``--log_file`` histories (via the summarize
-  report), and the schema-pinned analysis reports
-  (``shard_report`` / ``plan_report`` / ``tune_report``). Each record
+  report), and the schema-pinned analysis report
+  (``shard_report``). Each record
   carries a deterministic **fingerprint** (the bench capture identity
   when present, a content hash otherwise) and ingest is idempotent by
   it: re-ingesting an artifact appends nothing. A record that
@@ -47,7 +47,7 @@ and ``obs compare --against-archive``:
   a synthetic worse-than-band candidate MUST come back REGRESSED, a
   better one MUST come back clean, and an injected step in a synthetic
   series MUST be localized to the exact record. A dead detector is
-  exit 2 — the same injected-fault discipline as TD105/TD118/TD120.
+  exit 2 — the same injected-fault discipline as TD105/TD120.
 
 Pure host-side file crunching — no jax, runs anywhere the package
 imports. Formatters return strings; printing and exit codes belong to
@@ -289,9 +289,9 @@ def record_from_history(path: str) -> dict:
 
 
 def record_from_report(data: dict, *, source_path: str) -> dict:
-    """A schema-pinned analysis report (``shard_report`` /
-    ``plan_report`` / ``tune_report``): every registered scalar found
-    anywhere in the tree archives under the report's schema tag."""
+    """A schema-pinned analysis report (``shard_report``): every
+    registered scalar found anywhere in the tree archives under the
+    report's schema tag."""
     tag = str(data.get("schema"))
     metrics: Dict[str, float] = {}
     unregistered = 0
@@ -432,9 +432,7 @@ def _classify_json(data) -> str:
         if "n_devices" in data and "rc" in data and "ok" in data:
             return "multichip"
         tag = data.get("schema")
-        if isinstance(tag, str) and tag.startswith(
-            ("shard_report", "plan_report", "tune_report")
-        ):
+        if isinstance(tag, str) and tag.startswith("shard_report"):
             return "report"
     raise ValueError("unrecognized JSON artifact shape")
 

@@ -68,13 +68,6 @@ METRIC_DIRECTIONS: dict = {
     # 1 MiB: allocator peaks wobble by small workspace allocations on
     # otherwise identical runs, and a pure ratio would flag them.
     "peak_hbm_bytes": ("lower", 1024 * 1024),
-    # the planner's gating scalar (TD119, schema v12 'plan' records +
-    # bench records; analysis/planner.py): |predicted - achieved| /
-    # achieved step time. HIGHER is a regression — the cost model the
-    # --auto_shard ranking rests on drifted from the hardware. Absolute
-    # slack of 0.02: achieved step time wobbles a couple of points on
-    # quiet reruns, and a pure ratio of a small fraction would flag them.
-    "planner_error_frac": ("lower", 0.02),
     # the async-checkpoint layer's gating scalar (goodput ledger bucket,
     # obs/goodput.py; ckpt/checkpoint.py two-phase sharded saves): total
     # wall-clock seconds the step loop spent blocked on checkpoint
@@ -173,8 +166,7 @@ REPORT_METRICS: Tuple[Tuple[str, str, float], ...] = _table((
     "images_per_sec_mean", "step_time_p50_s", "step_time_p95_s",
     "step_time_p99_s", "data_stall_frac", "mfu_mean", "final_loss",
     "final_val_top1", "goodput_frac", "overlap_frac", "collective_frac",
-    "peak_hbm_bytes", "planner_error_frac", "ckpt_s",
-    "preempt_for_serve_s",
+    "peak_hbm_bytes", "ckpt_s", "preempt_for_serve_s",
 ))
 
 #: the ``--goodput`` gate's metric set: time-to-useful-work only. The
@@ -210,10 +202,6 @@ BENCH_FIELDS: Tuple[Tuple[str, str, float], ...] = _table((
     # ...and the compiled-collective wire bytes (shardlint over the
     # optimized HLO), the communication twin of that memory gate
     "hlo_wire_bytes_per_step",
-    # ...and the planner's predicted-vs-achieved drift (TD119,
-    # analysis/planner.py) — bench measures real step time next to the
-    # plan's prediction, so cost-model drift gates per bench record too
-    "planner_error_frac",
     # ...and the checkpoint drill's blocking window (bench.py --ckpt) —
     # a save that stopped hiding behind the step loop gates here
     "ckpt_blocked_ms",
@@ -270,12 +258,6 @@ def report_scalars(report: dict) -> dict:
         # the memory layer's worst observed per-chip peak (schema v11);
         # None — skipped, never faked — on a memory-less / pre-v11 log
         "peak_hbm_bytes": (report.get("memory") or {}).get("peak_hbm_bytes"),
-        # the planner layer's drift scalar (TD119, schema v12 'plan'
-        # records); None — skipped, never faked — on an unprofiled or
-        # plan-less run
-        "planner_error_frac": (report.get("plan") or {}).get(
-            "planner_error_frac"
-        ),
         # the async-checkpoint layer's blocking total (goodput ledger
         # 'ckpt' bucket); None — skipped, never faked — on a ledger-less
         # log. Gates the two-phase save's whole point: hiding the write.

@@ -462,9 +462,8 @@ def calibration(
 
     * ``cost.calibration_flops_per_s`` — AGGREGATE achieved FLOP/s over
       the capture's COMPUTE seconds only (matmul/conv + fusion), i.e.
-      what the hardware sustains when it is actually computing — the
-      number an ``--auto_shard`` planner should price compute with,
-      where MFU (whole-step wall over chip peak) prices nothing.
+      what the hardware sustains when it is actually computing
+      (MFU is whole-step wall over chip peak).
     * ``cost.calibration_compute_frac`` — that rate over the AGGREGATE
       chip peak (``peak × n_devices``, :func:`mfu`'s denominator —
       ``flops_per_step`` is treated as the step's total across devices,
@@ -543,9 +542,8 @@ def predicted_step_time(
     peak: Optional[float] = None,
 ) -> dict:
     """Static step-time prediction, corrected by the latest measured
-    ``cost.calibration_*`` gauges — the scalar an ``--auto_shard`` planner
-    ranks mesh layouts with (ROADMAP item 3; the shard report stamps it
-    per config family).
+    ``cost.calibration_*`` gauges (the shard report stamps it per config
+    family).
 
     Model (documented, deliberately simple): compute time is the step's
     FLOPs over the ACHIEVED FLOP/s from the last calibrated capture
@@ -601,25 +599,6 @@ def predicted_step_time(
         "rate_source": source,
     }
     return out
-
-
-def planner_error_frac(
-    predicted_s: Optional[float], achieved_s: Optional[float],
-) -> Optional[float]:
-    """The TD119 drift scalar: ``|predicted - achieved| / achieved`` of
-    one step's wall time — how far the ``--auto_shard`` planner's priced
-    step time sits from what the hardware measured. Lands in history as
-    ``planner_error_frac`` (``plan`` records, schema v12) and gates
-    through ``obs compare`` METRIC_DIRECTIONS (lower is better), so a
-    cost-model regression fails CI like a throughput one. None — a
-    skipped gate row, never a fake zero — when either side is missing
-    or non-positive."""
-    if (
-        not isinstance(predicted_s, (int, float)) or predicted_s <= 0
-        or not isinstance(achieved_s, (int, float)) or achieved_s <= 0
-    ):
-        return None
-    return round(abs(float(predicted_s) - float(achieved_s)) / float(achieved_s), 4)
 
 
 def publish(cost: Optional[dict]) -> None:

@@ -18,8 +18,7 @@ step byte-identical):
   and accounts bytes from avals + shardings alone: shape x itemsize per
   leaf, at the leaf's SHARDED extent per device (a ZeRO-1 flat momentum
   vector laid ``P('data')`` over 8 devices counts ceil(L/8) elements per
-  chip, not L). CPU-valid: no device transfer, no compile — the exact
-  input the ``--auto_shard`` planner's HBM budget needs (ROADMAP item 3).
+  chip, not L). CPU-valid: no device transfer, no compile.
 * **Live census + reconciliation** — :func:`live_census` sums
   ``jax.live_arrays()`` per device (again from sharding metadata);
   :func:`reconcile` sets it against the allocator's own

@@ -38,7 +38,7 @@ KNOWN_KINDS = frozenset((
     "train_epoch", "eval", "straggler", "anomaly", "device_stats",
     "auto_recover", "spans", "goodput", "profile", "alert",
     "profile_analysis", "resume", "fleet", "postmortem", "serve",
-    "memory", "plan", "tune", "tenancy",
+    "memory", "tenancy",
 ))
 
 
@@ -128,8 +128,6 @@ def summarize(records: List[dict], bad_lines: int = 0) -> dict:
     serve_events: List[dict] = []   # serving events (mid-serve retraces)
     memory_records: List[dict] = []  # HBM-ledger snapshots (schema v11)
     oom_events: List[dict] = []      # parsed RESOURCE_EXHAUSTED crashes
-    plan_records: List[dict] = []    # --auto_shard plan / TD119 drift (v12)
-    tune_records: List[dict] = []    # --tune_report knob application (v13)
     tenancy_snapshots: List[dict] = []  # per-tick chip accounting (v14)
     dstats: dict = {}  # epoch -> per-epoch device_stats aggregate
     recoveries = 0
@@ -290,29 +288,6 @@ def summarize(records: List[dict], bad_lines: int = 0) -> dict:
                               "reconciliation", "allocator", "feasibility")
                     if rec.get(k) is not None
                 })
-        elif kind == "plan":
-            # an --auto_shard plan (schema v12, analysis/planner.py):
-            # the chosen family + its priced step time at fit() start,
-            # and — after a profiled run — the TD119 predicted-vs-
-            # achieved drift record
-            plan_records.append({
-                k: rec.get(k)
-                for k in ("epoch", "family", "mode", "applied",
-                          "predicted_step_s", "achieved_step_s",
-                          "planner_error_frac", "gauge_source",
-                          "n_candidates", "n_refused")
-                if rec.get(k) is not None
-            })
-        elif kind == "tune":
-            # a --tune_report application (schema v13, analysis/overlap.py):
-            # which schedule knobs the run trains with, which the user
-            # kept, and the tuner objective they were chosen under
-            tune_records.append({
-                k: rec.get(k)
-                for k in ("epoch", "family", "report", "objective",
-                          "applied", "user_overrides")
-                if rec.get(k) is not None
-            })
         elif kind == "profile":
             profiles.append({
                 k: rec.get(k)
@@ -426,38 +401,12 @@ def summarize(records: List[dict], bad_lines: int = 0) -> dict:
             if (peak_hbm is not None or oom_events or memory_records)
             else None
         ),
-        "plan_records": plan_records,
-        "plan": (
-            # the gating view of the planner layer: the last plan record
-            # wins (the post-profile TD119 drift record supersedes the
-            # fit()-start announcement, which carries no achieved time)
-            {
-                k: plan_records[-1].get(k)
-                for k in ("family", "mode", "applied", "predicted_step_s",
-                          "achieved_step_s", "planner_error_frac",
-                          "gauge_source")
-                if plan_records[-1].get(k) is not None
-            }
-            if plan_records else None
-        ),
         "tenancy_snapshots": tenancy_snapshots,
         "tenancy": (
             # the gating view of the multi-tenant pod: the exact
             # chip-second conservation audit over every snapshot seen
             _tenancy_audit(tenancy_snapshots)
             if tenancy_snapshots else None
-        ),
-        "tune_records": tune_records,
-        "tune": (
-            # the gating view of the tuner layer: the last application
-            # wins (a resume re-applies and re-announces)
-            {
-                k: tune_records[-1].get(k)
-                for k in ("family", "objective", "applied",
-                          "user_overrides")
-                if tune_records[-1].get(k) is not None
-            }
-            if tune_records else None
         ),
         "stragglers": stragglers,
         "anomalies": anomalies,
@@ -746,21 +695,6 @@ def format_text(report: dict) -> str:
             f"peak HBM: {memory_lib.fmt_bytes(mem['peak_hbm_bytes'])} "
             "(worst chip — the compare gate's memory scalar)"
         )
-    plan = report.get("plan")
-    if plan:
-        bits = [f"plan: {plan.get('family', '?')}"]
-        if plan.get("mode"):
-            bits.append(f"mode={plan['mode']}")
-        if plan.get("predicted_step_s") is not None:
-            bits.append(f"predicted {plan['predicted_step_s'] * 1e3:.3g} ms/step")
-        if plan.get("achieved_step_s") is not None:
-            bits.append(f"achieved {plan['achieved_step_s'] * 1e3:.3g} ms/step")
-        if plan.get("planner_error_frac") is not None:
-            bits.append(
-                f"planner_error_frac={plan['planner_error_frac']:.4f}"
-                " (TD119 — the compare gate's planner scalar)"
-            )
-        lines.append("  ".join(bits))
     gp_epochs = report.get("goodput_epochs") or []
     if gp_epochs:
         lines.append("goodput (seconds per window):")

@@ -46,6 +46,8 @@ import os
 import re
 from typing import Dict, List, Optional, Tuple
 
+from tpu_dist.analysis.shardlint import PRIM_TO_HLO_KIND
+
 #: Attribution categories; their seconds sum to ``device_busy_s``.
 CATEGORIES = (
     "matmul_conv", "collective", "infeed_outfeed", "fusion_other", "host",
@@ -125,7 +127,10 @@ def collective_kind(name: str) -> Optional[str]:
     for kind in COLLECTIVE_KINDS:
         if stem == kind or stem.startswith(kind + "-"):
             return kind
-    return None
+    # since jax 0.5 XLA names an instruction after the jax primitive that
+    # made it (``%psum.7 = f32[] all-reduce(...)``), and a trace event
+    # bears the instruction's NAME, not its opcode
+    return PRIM_TO_HLO_KIND.get(stem)
 
 
 def classify(name: str) -> str:

@@ -125,7 +125,6 @@ def make_fused_epoch(
     std: np.ndarray = CIFAR100_STD,
     moe_aux_coef: float = 0.01,
     grad_compression: str = "none",
-    quant_chunk: int | None = None,
     model_kwargs: dict | None = None,
 ):
     """Build ``epoch(state, images_u8, labels, lr, epoch_idx) ->
@@ -140,7 +139,6 @@ def make_fused_epoch(
     so every step of the fused epoch compensates the previous step's
     quantization error exactly like the streaming path.
     """
-    from tpu_dist.comm.quantize import DEFAULT_CHUNK  # noqa: PLC0415
     from tpu_dist.train.step import (  # noqa: PLC0415
         _QUANT_KEY_SEED,
         compressed_pmean,
@@ -149,7 +147,6 @@ def make_fused_epoch(
     )
 
     validate_grad_compression(grad_compression)
-    q_chunk = int(quant_chunk) if quant_chunk else DEFAULT_CHUNK
     bn_axis = axis if sync_bn else None
     mean_c = jnp.asarray(mean, jnp.float32)
     std_inv_c = jnp.asarray(1.0 / std, jnp.float32)
@@ -201,7 +198,7 @@ def make_fused_epoch(
             )
             grads, new_ef = compressed_pmean(
                 grads, axis, grad_compression,
-                key=qkey, ef=state.ef, chunk=q_chunk,
+                key=qkey, ef=state.ef,
             )
             if not sync_bn:
                 new_bn = lax.pmean(new_bn, axis)

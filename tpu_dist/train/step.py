@@ -39,6 +39,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_dist.comm import mesh as mesh_lib
 from tpu_dist.comm.compat import shard_map
+from tpu_dist.comm.quantize import DEFAULT_CHUNK
 from tpu_dist.nn import functional as F
 from tpu_dist.train.state import TrainState
 
@@ -62,17 +63,12 @@ GRAD_COMPRESSION_MODES = ("none", "bf16", "int8", "int8_ef")
 # ONE registry of the shard-auditable parallelism config families: name →
 # the :func:`make_train_step` kwargs that select the family. This is the
 # enumeration the static analyzers walk (the jaxpr audit's budget cases,
-# the shardlint HLO audit — tpu_dist/analysis) and the search space the
-# measurement-calibrated ``--auto_shard`` planner ranks over
-# (``analysis/planner.py``): every entry lowers to a distinct collective
-# inventory, and each gets its own verified entry in ``shard_report.json``
-# (docs/shard_report.md). Families that need a model/mesh beyond the flag
-# combo (fsdp's per-leaf specs, tp's param_specs, sp's ring-attention
-# model) carry the axis flags here and get their builders in
-# ``analysis/shardlint.py``. The planner's TRAINER-flag projection of
-# these step kwargs lives in ``planner.FAMILY_TRAIN_OVERRIDES`` — a new
-# family here that --auto_shard apply should reach needs an entry there
-# too (test_planner pins the two registries against each other).
+# the shardlint HLO audit — tpu_dist/analysis): every entry lowers to a
+# distinct collective inventory, and each gets its own verified entry in
+# ``shard_report.json`` (docs/shard_report.md). Families that need a
+# model/mesh beyond the flag combo (fsdp's per-leaf specs, tp's
+# param_specs, sp's ring-attention model) carry the axis flags here and
+# get their builders in ``analysis/shardlint.py``.
 SHARD_CONFIG_FAMILIES: dict = {
     "dp_sgd": {},
     "dp_sgd_accum4": {"grad_accum_steps": 4},
@@ -92,7 +88,7 @@ SHARD_CONFIG_FAMILIES: dict = {
 def family_step_kwargs(name: str) -> dict:
     """Resolve a :data:`SHARD_CONFIG_FAMILIES` entry to real
     :func:`make_train_step` kwargs (the registry stores dtypes by NAME so
-    it stays a plain-data enumeration planners can serialize)."""
+    it stays a plain-data enumeration)."""
     kw = dict(SHARD_CONFIG_FAMILIES[name])
     if isinstance(kw.get("compute_dtype"), str):
         kw["compute_dtype"] = jnp.dtype(kw["compute_dtype"]).type
@@ -181,22 +177,6 @@ def init_ef_state(
     )
 
 
-def _chunk_bounds(total: int, k: int) -> list:
-    """Split ``[0, total)`` into at most ``k`` contiguous column groups of
-    near-equal width (remainder spread over the first groups, NO padding —
-    the pieces repartition the original extent exactly, so chunking the
-    ZeRO-1 RS+AG pair moves the collective *schedule* without adding a
-    single wire byte; that invariance is what TD121 pins)."""
-    k = max(1, min(k, total))
-    base, rem = divmod(total, k)
-    bounds, lo = [], 0
-    for i in range(k):
-        hi = lo + base + (1 if i < rem else 0)
-        bounds.append((lo, hi))
-        lo = hi
-    return bounds
-
-
 def _quantized_reduce_scatter_rows(rows, axis: str, key, chunk: int):
     """EQuARX-style quantized reduce-scatter of ``rows`` ``(n, m)`` over
     ``axis``: quantize → int8 ``all_to_all`` (+ tiny f32 scale sideband) →
@@ -274,7 +254,7 @@ def quantized_pmean_flat(grads, axis: str, *, key, ef, chunk: int):
     return unravel(full), new_ef
 
 
-def compressed_pmean(grads, axes, mode: str, *, key=None, ef=(), chunk=None):
+def compressed_pmean(grads, axes, mode: str, *, key=None, ef=()):
     """Cross-replica grad mean on the compressed wire format — the shared
     entry point of the per-step and fused-epoch paths. Returns
     ``(mean_grads, new_ef)``; ``new_ef`` is ``()`` except under
@@ -286,11 +266,9 @@ def compressed_pmean(grads, axes, mode: str, *, key=None, ef=(), chunk=None):
                 "int8 grad compression reduces over a single mesh axis "
                 f"(got {axes!r}) — see make_train_step's composition wall"
             )
-        from tpu_dist.comm.quantize import DEFAULT_CHUNK  # noqa: PLC0415
-
         return quantized_pmean_flat(
             grads, axes, key=key, ef=ef if mode == "int8_ef" else (),
-            chunk=chunk or DEFAULT_CHUNK,
+            chunk=DEFAULT_CHUNK,
         )
     if mode == "none":
         return lax.pmean(grads, axes), ef
@@ -325,9 +303,6 @@ def make_train_step(
     param_specs=None,
     remat: bool = False,
     grad_compression: str = "none",
-    quant_chunk: int | None = None,
-    pmean_fusion: str = "fused",
-    rs_ag_chunks: int = 1,
     device_metrics: bool = False,
     model_kwargs: dict | None = None,
 ):
@@ -379,16 +354,6 @@ def make_train_step(
     gradients); the model-parallel reduces (tp/ep/pp/sp) are refused, and
     the FSDP engine's GSPMD collectives remain unhookable.
 
-    ``pmean_fusion`` / ``rs_ag_chunks``: collective-*scheduling* knobs for
-    the overlap autotuner (``python -m tpu_dist.analysis tune-overlap``).
-    ``pmean_fusion='per_leaf'`` reduces each gradient leaf with its own
-    ``pmean`` instead of the single fused multi-operand reduce;
-    ``rs_ag_chunks=k`` splits the ZeRO-1 reduce-scatter / all-gather pair
-    into ``k`` pipelined column-group collectives. Both move the HLO
-    collective *schedule* only — the payload-byte inventory is identical
-    by construction (no repacking, no extra padding) and TD121 pins
-    exactly that invariant.
-
     ``device_metrics=True``: fuse the training-health scalars
     (``obs/device_stats.py`` — global grad norm, param norm, update
     ratio, nonfinite-leaf count) into the step's metrics dict. Computed
@@ -405,9 +370,6 @@ def make_train_step(
     n_axis = int(mesh.shape[axis])
     validate_grad_compression(grad_compression)
     quantized = grad_compression in QUANTIZED_MODES
-    from tpu_dist.comm.quantize import DEFAULT_CHUNK  # noqa: PLC0415
-
-    q_chunk = int(quant_chunk) if quant_chunk else DEFAULT_CHUNK
     if quantized and any(
         a is not None for a in (tp_axis, ep_axis, pp_axis, seq_axis)
     ):
@@ -432,34 +394,6 @@ def make_train_step(
             "device_metrics is scoped to the replicated-param paths "
             "(plain DP/SP, any grad_compression) — it cannot combine "
             "with shard_weight_update/tp/ep/pp"
-        )
-    if pmean_fusion not in ("fused", "per_leaf"):
-        raise ValueError(
-            f"pmean_fusion={pmean_fusion!r}: expected 'fused' or 'per_leaf'"
-        )
-    if pmean_fusion == "per_leaf" and (
-        quantized or shard_weight_update or ep_axis is not None
-    ):
-        # the knob only exists where the fused multi-operand pmean exists:
-        # the plain data-parallel reduce. The quantized path reduces one
-        # flat vector (nothing to split), ZeRO-1 reduce-scatters, and the
-        # MoE engine owns its own per-group reduces — accepting the knob
-        # there would be a silent no-op, which TD121 tooling forbids
-        raise ValueError(
-            "pmean_fusion='per_leaf' is scoped to the non-quantized "
-            "data-parallel reduce; it cannot combine with "
-            "grad_compression int8/ep/shard_weight_update"
-        )
-    rs_ag_chunks = int(rs_ag_chunks)
-    if rs_ag_chunks < 1:
-        raise ValueError(f"rs_ag_chunks={rs_ag_chunks}: must be >= 1")
-    if rs_ag_chunks > 1 and not (shard_weight_update and not quantized):
-        # pipelining the RS+AG pair only means something where that pair
-        # exists: the non-quantized ZeRO-1 update (the quantized variant
-        # already chunks on the int8 wire via quant_chunk)
-        raise ValueError(
-            "rs_ag_chunks > 1 is scoped to the non-quantized ZeRO-1 path "
-            "(shard_weight_update=True, grad_compression none/bf16)"
         )
     if device_metrics:
         from tpu_dist.obs.device_stats import compute_device_stats  # noqa: PLC0415
@@ -653,24 +587,14 @@ def make_train_step(
                 grads, new_ef = quantized_pmean_flat(
                     grads, axis, key=quant_key(state.step),
                     ef=state.ef if grad_compression == "int8_ef" else (),
-                    chunk=q_chunk,
+                    chunk=DEFAULT_CHUNK,
                 )
             else:
                 # THE data-parallel step: average grads over the mesh (DDP),
                 # on the (optionally bf16-compressed) wire format; one cast
                 # round-trip covers both axes.
                 local = grads
-                if pmean_fusion == "per_leaf":
-                    # one pmean PER GRADIENT LEAF instead of one fused
-                    # multi-operand reduce: identical payload bytes on the
-                    # wire, but many small collectives the scheduler can
-                    # launch as each leaf's backward finishes — the overlap
-                    # autotuner's schedule knob (analysis/overlap.py, TD121)
-                    grads = jax.tree_util.tree_map(
-                        lambda g: lax.pmean(wire(g), axis), grads
-                    )
-                else:
-                    grads = lax.pmean(jax.tree_util.tree_map(wire, grads), axis)
+                grads = lax.pmean(jax.tree_util.tree_map(wire, grads), axis)
                 if seq_axis is not None:
                     # every seq shard differentiates a full replica of the
                     # loss, so local grads sum to n× the true gradient —
@@ -759,26 +683,10 @@ def make_train_step(
                 x = x + state.ef["r1"]
             g_shard, sent = _quantized_reduce_scatter_rows(
                 x.reshape(n_axis, chunk), axis,
-                quant_key(state.step), q_chunk,
+                quant_key(state.step), DEFAULT_CHUNK,
             )
             if grad_compression == "int8_ef":
                 new_ef = {"r1": x - sent.reshape(chunk * n_axis)}
-        elif rs_ag_chunks > 1:
-            # pipelined reduce-scatter: split the padded flat vector into
-            # column groups of the per-replica extent and reduce-scatter
-            # each independently — same total payload (the groups tile the
-            # extent exactly, no extra padding), but k smaller collectives
-            # the scheduler can interleave with the shard update below.
-            # Shard p of group [c0:c1) is exactly rows[p, c0:c1], so the
-            # concatenation rebuilds this replica's contiguous g_shard.
-            rows = wire(jnp.pad(flat_g / n_axis, (0, pad))).reshape(n_axis, chunk)
-            g_shard = jnp.concatenate([
-                lax.psum_scatter(
-                    rows[:, c0:c1].reshape(-1), axis,
-                    scatter_dimension=0, tiled=True,
-                )
-                for c0, c1 in _chunk_bounds(chunk, rs_ag_chunks)
-            ]).astype(flat_g.dtype)
         else:
             g_shard = lax.psum_scatter(
                 wire(jnp.pad(flat_g / n_axis, (0, pad))), axis,
@@ -805,20 +713,7 @@ def make_train_step(
         new_p_shard, new_b_shard = optimizer.update(
             g_shard, state.opt_state, p_shard, lr, **kw
         )
-        if rs_ag_chunks > 1:
-            # mirrored pipelined all-gather: gather each column group and
-            # reassemble columnwise — tiled gather of piece [c0:c1) yields
-            # (n*(c1-c0),) = rows (n, c1-c0), so concat on axis=1 restores
-            # the (n, chunk) row layout the flat vector linearizes
-            parts = [
-                lax.all_gather(
-                    new_p_shard[c0:c1], axis, tiled=True
-                ).reshape(n_axis, c1 - c0)
-                for c0, c1 in _chunk_bounds(chunk, rs_ag_chunks)
-            ]
-            flat_new = jnp.concatenate(parts, axis=1).reshape(-1)[:L]
-        else:
-            flat_new = lax.all_gather(new_p_shard, axis, tiled=True)[:L]
+        flat_new = lax.all_gather(new_p_shard, axis, tiled=True)[:L]
         return unravel(flat_new), new_b_shard, new_ef
 
     p_spec = param_specs if param_specs is not None else P()

@@ -138,21 +138,6 @@ class Trainer:
             num_processes=cfg.num_processes,
             process_id=cfg.process_id,
         )
-        # --auto_shard: run the static sharding planner BEFORE this config
-        # is consumed — 'apply' rewrites cfg to the chosen plan's family,
-        # and the rewritten config then flows through every validation and
-        # the real-model HBM preflight below like a hand-written one
-        self._plan = None
-        if cfg.auto_shard != "off":
-            cfg = self._run_auto_shard(cfg, mesh)
-            self.cfg = cfg
-        # --tune_report: apply the overlap autotuner's chosen schedule
-        # knobs for this config's family (AFTER --auto_shard apply, so the
-        # knobs land on the family actually being trained)
-        self._tune = None
-        if cfg.tune_report:
-            cfg = self._apply_tune_report(cfg)
-            self.cfg = cfg
         if cfg.ckpt_io_retries < 0:
             raise ValueError(
                 f"ckpt_io_retries must be >= 0, got {cfg.ckpt_io_retries}"
@@ -1048,144 +1033,6 @@ class Trainer:
             n = min(n, self.cfg.steps_per_epoch)
         self._global_step = self.start_epoch * n + self._resume_step
 
-    def _run_auto_shard(self, cfg: TrainConfig, mesh) -> TrainConfig:
-        """``--auto_shard``: enumerate/price/filter the shardlint family
-        matrix (analysis/planner.py) and print the ranked plan. ``apply``
-        rewrites the returned config to the chosen family's flags — the
-        rewritten config then passes through every downstream validation
-        and the real-model HBM preflight exactly like a hand-written one.
-
-        The chosen plan is TD118-verified here (fresh compile of the
-        chosen family, inventory must match the priced one) — an
-        unverifiable plan is refused in ``apply`` mode, warned in ``plan``
-        mode. The plan lands in the history as a ``plan`` record (schema
-        v12) at fit() start, and TD119 closes the loop after a profiled
-        run (``_note_capture_analysis``)."""
-        import dataclasses  # noqa: PLC0415
-
-        from tpu_dist.analysis import planner  # noqa: PLC0415
-        from tpu_dist.obs import memory as memory_lib  # noqa: PLC0415
-
-        apply = cfg.auto_shard == "apply"
-        if apply and (cfg.sp > 1 or cfg.tp > 1 or cfg.ep > 1 or cfg.pp > 1):
-            raise ValueError(
-                "--auto_shard apply plans over the flat data-parallel "
-                "family matrix and would clobber an explicit sp/tp/ep/pp "
-                "layout — use --auto_shard plan for an advisory table"
-            )
-        plan = planner.build_plan(
-            mesh=mesh,
-            hbm_budget_bytes=cfg.hbm_budget_bytes,
-            memory_headroom=cfg.memory_headroom,
-            applyable_only=apply,
-        )
-        chosen = plan.get("chosen")
-        if chosen is None:
-            rank0_print(planner.format_text(plan))
-            if plan["counts"]["refused"]:
-                raise memory_lib.InfeasibleMemoryError(
-                    f"--auto_shard: all {plan['counts']['refused']} "
-                    "candidate(s) exceed the per-chip HBM budget — shrink "
-                    "the batch, raise --memory_headroom, or widen the mesh"
-                )
-            raise ValueError(
-                "--auto_shard: no candidate could be planned "
-                f"(skipped: {plan.get('skips')})"
-            )
-        probe, violations = planner.verify_plan(plan, mesh=mesh)
-        plan["verification"] = probe
-        rank0_print(planner.format_text(plan))
-        if violations:
-            for v in violations:
-                rank0_print(f"=> {v}")
-            if apply:
-                raise ValueError(
-                    "--auto_shard apply: the chosen plan failed TD118 "
-                    "plan-must-verify (compiled collective inventory != "
-                    "priced inventory) — refusing to train on a mispriced "
-                    "ranking"
-                )
-        self._plan = {
-            "family": chosen["family"],
-            "mode": cfg.auto_shard,
-            "applied": apply,
-            "predicted_step_s": chosen.get("predicted_step_s"),
-            "gauge_source": plan.get("gauge_source"),
-            "n_candidates": plan["counts"]["candidates"],
-            "n_refused": plan["counts"]["refused"],
-        }
-        if not apply:
-            return cfg
-        overrides = planner.family_train_overrides(chosen["family"])
-        rank0_print(
-            f"=> auto_shard apply: {chosen['family']} -> "
-            + (", ".join(f"{k}={v}" for k, v in sorted(overrides.items()))
-               or "reference flags")
-        )
-        return dataclasses.replace(cfg, **overrides)
-
-    def _apply_tune_report(self, cfg: TrainConfig) -> TrainConfig:
-        """``--tune_report``: load the overlap autotuner's report
-        (analysis/overlap.py) and apply its chosen schedule knobs for this
-        config's planner family. A knob flag the user set explicitly
-        (non-default) wins over the report; every applied/overridden knob
-        is printed and exported as a ``tune.*`` gauge at fit() start.
-        A malformed report raises (typed ``TuneReportError``) — silently
-        training untuned against an explicit --tune_report would be a
-        lying flag."""
-        import dataclasses  # noqa: PLC0415
-
-        from tpu_dist.analysis import overlap as overlap_lib  # noqa: PLC0415
-        from tpu_dist.analysis import planner  # noqa: PLC0415
-
-        report = overlap_lib.load_tune_report(cfg.tune_report)
-        family = planner.family_of(
-            grad_compression=cfg.grad_compression,
-            bf16=cfg.bf16,
-            grad_accu_steps=cfg.grad_accu_steps,
-            shard_weight_update=cfg.shard_weight_update,
-            fsdp=cfg.fsdp,
-        )
-        self._tune = {
-            "report": cfg.tune_report,
-            "objective": report.get("objective"),
-            "family": family,
-            "applied": {},
-            "user_overrides": {},
-        }
-        if family is None:
-            rank0_print(
-                "=> tune_report: this flag combination maps to no planner "
-                "family — no tuned knobs to apply"
-            )
-            return cfg
-        knobs = overlap_lib.chosen_knobs(report, family)
-        if not knobs:
-            rank0_print(
-                f"=> tune_report: family {family} — baseline wins, "
-                "no knob overrides"
-            )
-            return cfg
-        defaults = TrainConfig()
-        applied: dict = {}
-        for knob, value in sorted(knobs.items()):
-            if getattr(cfg, knob) != getattr(defaults, knob):
-                # the user set this knob explicitly; the report yields
-                self._tune["user_overrides"][knob] = getattr(cfg, knob)
-                continue
-            applied[knob] = value
-        self._tune["applied"] = applied
-        msg = ", ".join(f"{k}={v}" for k, v in sorted(applied.items()))
-        skipped = ", ".join(
-            f"{k}={v} (user)" for k, v in
-            sorted(self._tune["user_overrides"].items())
-        )
-        rank0_print(
-            f"=> tune_report apply [{family}]: {msg or 'nothing'}"
-            + (f"; kept {skipped}" if skipped else "")
-        )
-        return dataclasses.replace(cfg, **applied) if applied else cfg
-
     def _ckpt_io(self):
         """Sync module functions, the sharded writer (``--sharded_ckpt``),
         or an async writer (``--async_ckpt``: plain, or snapshot-then-write
@@ -1265,9 +1112,6 @@ class Trainer:
             param_specs=self._param_specs,
             remat=cfg.remat,
             grad_compression=cfg.grad_compression,
-            quant_chunk=cfg.quant_chunk or None,
-            pmean_fusion=cfg.pmean_fusion,
-            rs_ag_chunks=cfg.rs_ag_chunks,
             device_metrics=cfg.device_metrics,
             model_kwargs=mk or None,
         )
@@ -2262,8 +2106,7 @@ class Trainer:
         attribution line, ``profile_analysis`` history record (schema
         v6), and cost-model calibration gauges (``cost.calibration_*`` —
         measured category seconds divided into the predicted per-step
-        FLOPs/bytes, the drift signal a later ``--auto_shard`` planner
-        prices layouts with). Analysis failures were counted by the hook
+        FLOPs/bytes). Analysis failures were counted by the hook
         already; here they surface as a warning + an error-stamped
         record, never an exception — forensics must not kill training."""
         if analysis is None:
@@ -2299,42 +2142,6 @@ class Trainer:
                 "profile_analysis", epoch=epoch, reason=reason,
                 dir=capture_dir, **rec,
             )
-        # TD119 planner-error-tracked: every profiled run closes the
-        # planner's loop — the capture's achieved per-step wall time
-        # against the priced one. An --auto_shard plan is held to the
-        # step time it promised; without one, this run's own compiled
-        # cost is priced with the calibration just published, so the
-        # drift gauge exists for every profiled run, planned or not.
-        busy = analysis.get("device_busy_s")
-        if steps and isinstance(busy, (int, float)) and busy > 0:
-            n_dev = max(jax.local_device_count(), 1)
-            achieved = busy / steps / n_dev
-            predicted = (self._plan or {}).get("predicted_step_s")
-            src = "plan"
-            if predicted is None and self._step_cost:
-                pred = costmodel_lib.predicted_step_time(
-                    self._step_cost, n_devices=n_dev,
-                )
-                predicted = pred.get("predicted_step_s") if pred else None
-                src = "step_cost"
-            err = costmodel_lib.planner_error_frac(predicted, achieved)
-            if err is not None:
-                counters_lib.set_gauge("plan.planner_error_frac", err)
-                rank0_print(
-                    f"=> planner drift (TD119): predicted {predicted:g}s "
-                    f"vs achieved {achieved:g}s per step — "
-                    f"planner_error_frac={err:.4f} [{src}]"
-                )
-                if self._history is not None:
-                    self._history.log(
-                        "plan", epoch=epoch,
-                        family=(self._plan or {}).get("family"),
-                        mode=(self._plan or {}).get("mode"),
-                        predicted_step_s=predicted,
-                        achieved_step_s=float(f"{achieved:.4g}"),
-                        planner_error_frac=err,
-                        prediction_source=src,
-                    )
 
     def _apply_step_faults(self, epoch: int, step: int, lr: float) -> None:
         """Host-side --fault_plan actions at the step grain. A matching
@@ -2793,32 +2600,6 @@ class Trainer:
                 counters_lib.set_gauge(
                     "comm.grad_wire_bytes_per_step", 2 * bpe * n_params
                 )
-        if self._plan is not None:
-            # the --auto_shard announcement record (schema v12): what the
-            # planner chose and what step time it promised. TD119's drift
-            # record lands later, from _note_capture_analysis, once a
-            # profiled run produces an achieved step time to compare
-            if telemetry:
-                counters_lib.set_gauge("plan.family", self._plan["family"])
-                if self._plan.get("predicted_step_s") is not None:
-                    counters_lib.set_gauge(
-                        "plan.predicted_step_s", self._plan["predicted_step_s"]
-                    )
-            history.log("plan", epoch=self.start_epoch, **self._plan)
-        if self._tune is not None:
-            # the --tune_report announcement (satellite of the overlap
-            # autotuner): which schedule knobs the run actually trains
-            # with, as gauges (history + compare can pin a regression to
-            # a knob flip) plus one 'tune' history record
-            if telemetry:
-                counters_lib.set_gauge(
-                    "tune.family", self._tune.get("family") or "none"
-                )
-                for knob, value in sorted(
-                    (self._tune.get("applied") or {}).items()
-                ):
-                    counters_lib.set_gauge(f"tune.{knob}", value)
-            history.log("tune", epoch=self.start_epoch, **self._tune)
         if cfg.heartbeat_file:
             from tpu_dist.obs.heartbeat import (  # noqa: PLC0415
                 Heartbeat, per_rank_path,
