@@ -60,3 +60,20 @@ def test_drop_last():
 def test_bad_shard_id():
     with pytest.raises(ValueError):
         DistributedSampler(10, 2, 2)
+
+
+@pytest.mark.parametrize("drop_last", [False, True])
+@pytest.mark.parametrize("shuffle", [True, False])
+def test_indices_of_a_given_epoch_are_pure(shuffle, drop_last):
+    """``indices(epoch)`` is what ``set_epoch(epoch); indices()`` gives, and
+    leaves the sampler as it was: the loader's look-ahead asks for the next
+    epoch's order while the current one is in flight."""
+    s = DistributedSampler(103, 4, 2, shuffle=shuffle, seed=7, drop_last=drop_last)
+    s.set_epoch(3)
+    here = s.indices().copy()
+    ahead = s.indices(4)
+    assert s.epoch == 3 and np.array_equal(s.indices(), here)
+    assert np.array_equal(s.indices(3), here)
+    s.set_epoch(4)
+    assert np.array_equal(s.indices(), ahead)
+    assert np.array_equal(ahead, here) == (not shuffle)

@@ -18,6 +18,8 @@ the batch array, not by the sampler.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 
 
@@ -82,10 +84,15 @@ class DistributedSampler:
         self.offset = int(n_examples)
         self._recompute_sizes()
 
-    def indices(self) -> np.ndarray:
-        """This shard's indices for the current epoch (deterministic)."""
+    def indices(self, epoch: Optional[int] = None) -> np.ndarray:
+        """This shard's indices for ``epoch`` (default: the current one);
+        deterministic, and pure: a given ``epoch`` is never stored, so the
+        loader can ask for the next epoch's order while this one is in
+        flight (``DataLoader``'s look-ahead)."""
         if self.shuffle:
-            g = np.random.default_rng(self.seed + self.epoch)
+            g = np.random.default_rng(
+                self.seed + (self.epoch if epoch is None else epoch)
+            )
             order = g.permutation(self.num_examples)
         else:
             order = np.arange(self.num_examples)
