@@ -459,6 +459,27 @@ def phase_vit_b16_flash_step(ctx: dict) -> str:
             + spy.check_compiled(ctx["on_tpu"]))
 
 
+def phase_token_step(ctx: dict) -> str:
+    """The token path end to end at toy widths: one epoch of the tiny hybrid
+    decoder (mixer, expert layer, causal grouped attention) through
+    ``cli.train.main``, bf16. Informational: the widths are toys."""
+    from tpu_dist.cli import train as cli
+    from tpu_dist.obs import counters
+
+    tr = cli.main([
+        "--dataset", "synthetic_tokens", "--synthetic_n", "64", "--model", "nemotron_h_tiny",
+        "--batch_size", "16", "--optimizer", "adamw", "--lr", "0.01", "--bf16",
+        "--epochs", "1", "--steps_per_epoch", "2", "--log_every", "1", "--eval_every", "0",
+    ])
+    check(int(tr.state.step) == 2, f"state.step {int(tr.state.step)}, expected 2")
+    check(counters.get("moe.rows_live") > 0 and counters.get("moe.rows_over_cap") == 0,
+          f"moe.rows_live {counters.get('moe.rows_live')}, "
+          f"moe.rows_over_cap {counters.get('moe.rows_over_cap')}")
+    return (f"nemotron_h_tiny: 2 steps, {counters.get('lm.tokens'):.0f} tokens, "
+            f"{counters.get('moe.rows_live'):.0f} live expert rows of "
+            f"{counters.get('moe.rows_balanced'):.0f} balanced")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument(
@@ -511,7 +532,8 @@ def main(argv=None) -> int:
     phases = [phase_train, phase_resume, phase_placement]
     if device["count"] > 1:
         phases += [phase_dp_equivalence, phase_ring_flash]
-    phases += [phase_fused_sgd, phase_flash_kernels, phase_vit_b16_flash_step]
+    phases += [phase_fused_sgd, phase_flash_kernels, phase_vit_b16_flash_step,
+               phase_token_step]
 
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     ctx = {"size": size, "flash_shapes": shapes, "workdir": workdir,

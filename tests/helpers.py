@@ -55,3 +55,24 @@ class TinyMLP:
         x = x.reshape(x.shape[0], -1)
         y = L.relu(L.linear_apply(params["l1"], x))
         return L.linear_apply(params["l2"], y), state
+
+
+def hybrid_arch(m, bias=None) -> dict:
+    """The ``arch`` block the plain reference (``benchmarks/models/nemotron_h.py``)
+    reads, for a ``HybridDecoderDef``; ``bias``: the expert layers' selection bias."""
+    arch = dict(
+        hidden_size=m.hidden, mamba_num_heads=m.mamba_heads, mamba_head_dim=m.mamba_head_dim,
+        n_groups=m.ssm_groups, ssm_state_size=m.ssm_state, conv_kernel=m.conv_kernel,
+        chunk_size=m.chunk_size, num_attention_heads=m.attn_heads,
+        num_key_value_heads=m.kv_heads, head_dim=m.attn_head_dim,
+        published={"n_routed_experts": m.n_experts}, experts_held=list(m.experts_held),
+        num_experts_per_tok=m.top_k, moe_intermediate_size=m.expert_width,
+        moe_shared_expert_intermediate_size=m.shared_width,
+        routed_scaling_factor=m.routed_scaling, layer_norm_epsilon=m.eps,
+        vocab_size=m.vocab_size, seq_len=m.seq_len, hybrid_override_pattern=m.pattern,
+    )
+    if bias is not None:
+        import numpy as np  # noqa: PLC0415
+
+        arch["router_bias"] = np.asarray(bias)
+    return arch

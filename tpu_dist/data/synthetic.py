@@ -115,3 +115,24 @@ def synthetic_quadrant(
         r, c = divmod(quad, 2)
         images[idx, r * half : (r + 1) * half, c * half : (c + 1) * half, :] += 100
     return np.clip(images, 0, 255).astype(np.uint8), labels
+
+
+def synthetic_tokens(
+    n: int, seq_len: int, vocab_size: int, seed: int = 0, noise: float = 0.1,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Token sequences for a decoder: ``(inputs, targets)``, both int32
+    ``[n, seq_len]``, the target of a position being the next token. Each
+    sequence walks one fixed random permutation of the vocabulary from a
+    random start (the same permutation for every ``seed``, so a held-out set
+    tests what the training set teaches), with a share ``noise`` of the
+    steps replaced by a uniform draw: learnable in a few steps, never
+    perfectly. One document a sequence; deterministic per seed."""
+    perm = np.random.default_rng(0x70CE).permutation(vocab_size)
+    rng = np.random.default_rng(seed)
+    walk = np.empty((n, seq_len + 1), np.int64)
+    walk[:, 0] = rng.integers(0, vocab_size, size=n)
+    jump = rng.random((n, seq_len)) < noise
+    draws = rng.integers(0, vocab_size, size=(n, seq_len))
+    for t in range(seq_len):
+        walk[:, t + 1] = np.where(jump[:, t], draws[:, t], perm[walk[:, t]])
+    return walk[:, :-1].astype(np.int32), walk[:, 1:].astype(np.int32)

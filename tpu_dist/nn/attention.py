@@ -75,23 +75,45 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+FLASH_MIN_SEQ = 1024  # below it the XLA chain's [S, S] scores are small
+
+
+def takes_flash_kernel(impl: Optional[str], causal: bool, seq: int, h_dim: int) -> bool:
+    """Where :func:`full_attention` takes the tiled kernel
+    (``ops/flash_attention.py``) without being told to: a causal site on a
+    TPU from ``FLASH_MIN_SEQ`` tokens on, whose ``[S, S]`` scores the XLA
+    chain would put in HBM for every head, with whole 128-lane heads and
+    whole tiles. ``impl`` "flash" takes it at any shape."""
+    impl = _resolve_impl(impl)
+    if impl != "auto":
+        return impl == "flash"
+    return causal and _on_tpu() and seq >= FLASH_MIN_SEQ and seq % 128 == 0 and h_dim % 128 == 0
+
+
 def full_attention(q, k, v, *, causal: bool = False, impl: Optional[str] = None):
-    """[B,S,H,D] x3 → [B,S,H,D]. Softmax in f32 regardless of input dtype.
-    ``impl`` "auto" is the XLA chain here: q, k and v arrive apart, and the
+    """``q [B,S,H,D]``, ``k``/``v [B,S,Hkv,D]`` → ``[B,S,H,D]``; ``H / Hkv``
+    consecutive query heads share a key/value head (``Hkv == H``: plain
+    heads). Softmax in f32 regardless of input dtype. Under ``impl`` "auto"
+    a long causal site on a TPU takes the tiled kernel
+    (:func:`takes_flash_kernel`, counted in ``attn.sites_flash``); anything
+    else is the XLA chain here: q, k and v arrive apart, and the
     whole-sequence kernel reads them packed (:func:`projected_attention`)."""
-    if _resolve_impl(impl) == "flash":
+    if takes_flash_kernel(impl, causal, q.shape[1], q.shape[-1]):
         from tpu_dist.ops.flash_attention import flash_attention  # noqa: PLC0415
 
+        counters.inc("attn.sites_flash")
         return flash_attention(q, k, v, causal=causal)
-    d = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    b, s_q, h, d = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, s_q, kv, h // kv, d)
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(d))
     if causal:
-        s_q, s_k = scores.shape[-2], scores.shape[-1]
+        s_k = scores.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
         scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return jnp.einsum("bkgqs,bskd->bqkgd", probs, v).reshape(b, s_q, h, d)
 
 
 def ring_attention(q, k, v, axis_name: str, *, causal: bool = False):
@@ -196,6 +218,12 @@ def attention(q, k, v, *, causal: bool = False, seq_axis: Optional[str] = None,
     (``sp_mode``: "ring" rotation or "ulysses" all-to-all), else full
     (``impl``/module default selecting XLA vs Pallas flash).
 
+    What a site takes with no sequence axis, by what it can observe: a
+    causal site of ``FLASH_MIN_SEQ`` tokens or more on a TPU takes the tiled
+    kernel, grouped heads (``k``/``v`` with fewer heads than ``q``) read by
+    index; any other site takes the XLA chain, which handles grouped heads
+    too. The sequence-parallel variants take equal head counts only.
+
     Under the RING the flash impl selects
     :func:`tpu_dist.ops.flash_attention.ring_flash_attention`: the ring
     already tiles ACROSS devices (each rotation sees one [S/n, S/n] local
@@ -234,7 +262,11 @@ def takes_short_kernel(impl: Optional[str], seq_axis: Optional[str], causal: boo
                        seq: int, heads: int, h_dim: int, dtype) -> bool:
     """The selection rule of :func:`projected_attention`, from what the call
     can observe: the implementation left to choice, a TPU, no sequence axis,
-    no mask, and a shape ``ops/short_attention.py`` can hold in VMEM."""
+    no mask, and a shape ``ops/short_attention.py`` can hold in VMEM. A
+    causal site never takes it (the kernel has no mask): it goes on to
+    :func:`attention`, where :func:`takes_flash_kernel` decides between the
+    tiled kernel and XLA; so does a grouped-head site, whose q, k and v are
+    no one fused projection."""
     if _resolve_impl(impl) != "auto" or seq_axis is not None or causal or not _on_tpu():
         return False
     from tpu_dist.ops.short_attention import fits  # noqa: PLC0415
@@ -259,8 +291,9 @@ def projected_attention(y, proj, h_dim: int, *, causal: bool = False,
         counters.inc("attn.sites_fused")
         qkv = y @ qkv_major(w, h_dim) + qkv_major(bias, h_dim)
         return short_attention(qkv, heads, interpret=False)  # only taken on a TPU
-    if impl != "flash":
-        counters.inc("attn.sites_xla")
+    if seq_axis is not None or not takes_flash_kernel(impl, causal, s, h_dim):
+        if impl != "flash":
+            counters.inc("attn.sites_xla")
     qkv = (y @ w + bias).reshape(b, s, heads, 3, h_dim)
     q, k, v = (qkv[:, :, :, i, :] for i in range(3))
     o = attention(q, k, v, causal=causal, seq_axis=seq_axis, impl=impl, sp_mode=sp_mode)

@@ -4,6 +4,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Sequence, Tuple
 
 import jax
@@ -47,3 +48,30 @@ def accuracy(logits, labels, topk: Sequence[int] = (1,)) -> Tuple:
     counts = topk_correct(logits, labels, topk)
     b = logits.shape[0]
     return tuple(c.astype(jnp.float32) * (100.0 / b) for c in counts)
+
+
+def blocked_cross_entropy(hidden, w_head, targets, weights, *, block: int):
+    """Head and cross-entropy over the tokens a block at a time: the
+    ``[block, vocabulary]`` float32 logits exist for one block only, forward
+    and (recomputed) backward. ``hidden [T, d]``, ``w_head [d, V]`` in the
+    compute dtype, ``targets [T]``, ``weights [T]`` float32. Returns float32
+    sums over the tokens: ``(weighted nll, weighted top-1 hits, top-5 hits)``;
+    a hit is counted from the target's rank among the logits, no sort."""
+    t = targets.shape[0]
+    block = math.gcd(t, block)
+
+    @jax.checkpoint
+    def one(h, y, wt):
+        logits = jnp.dot(h, w_head, preferred_element_type=jnp.float32)
+        at = jnp.take_along_axis(logits, y[:, None], axis=-1)
+        nll = jax.nn.logsumexp(logits, axis=-1) - at[:, 0]
+        rank = jnp.sum(logits > at, axis=-1)
+        return jnp.stack([jnp.sum(nll * wt), jnp.sum((rank < 1) * wt), jnp.sum((rank < 5) * wt)])
+
+    def body(acc, xs):
+        return acc + one(*xs), None
+
+    cut = lambda a: a.reshape((t // block, block) + a.shape[1:])  # noqa: E731
+    sums, _ = lax.scan(body, jnp.zeros((3,), jnp.float32),
+                       (cut(hidden), cut(targets), cut(weights)))
+    return sums[0], sums[1], sums[2]

@@ -1,0 +1,406 @@
+"""Hybrid decoder of the Nemotron-H family: Mamba-2 mixers, sparse expert
+layers and grouped-head causal attention in one stack, driven by a pattern
+string (``M`` mixer, ``*`` attention, ``E`` expert layer), one pre-norm
+residual block a character: ``x <- x + f_i(RMSNorm(x))``, a final RMSNorm and
+an untied head. ``docs/hybrid_decoder.md`` has the equations.
+
+The first token model of the framework, so its contract is wider than
+``ViTDef``'s. ``init(key) -> (params, state)`` and ``apply(params, state,
+tokens, train=) -> (logits, state)`` as everywhere; beside them
+``loss(params, state, tokens, targets, train=, compute_dtype=)``, which the
+train and eval steps call where a model has it: the head and its
+cross-entropy run over the tokens in blocks, so no ``[tokens, vocabulary]``
+array outlives a block, and layers are recomputed in the backward pass one at
+a time (``jax.checkpoint`` a layer, not one around the whole loss; which
+layers, ``recompute`` says).
+
+``state`` holds what is not a parameter: ``router_bias [expert layers,
+experts]``, the selection bias of the auxiliary-loss-free balancing rule,
+which moves by ``bias_rate * sign(mean load - load)`` after every training
+step and receives no gradient.
+
+Mixed precision is the model's own: parameters arrive in float32 and each
+matrix is cast to ``compute_dtype`` where it is used; the router, the
+mixer's decay and state, every norm and the loss stay in float32; the
+residual stream is in ``compute_dtype``.
+
+A deployment's share: ``experts_held = (first, count)`` says which routed
+experts this chip holds (``w_up``/``w_down`` have ``count`` leading rows);
+the router scores all ``n_experts``, and the layer adds only what its own
+experts give to the shared expert's output. ``vocab_size`` is the slice of
+the vocabulary held here.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from tpu_dist.nn import attention as attn_lib
+from tpu_dist.nn import functional as F
+from tpu_dist.obs import counters as counters_lib
+from tpu_dist.parallel import expert as expert_lib
+
+
+def rms_norm(scale, x, eps: float):
+    """RMSNorm over the last axis in float32, back in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def ssm_scan(x, dt, a, b, c, chunk: int, state_dtype=jnp.float32):
+    """Mamba-2's recurrence ``H_t = exp(dt_t a) H_{t-1} + dt_t x_t (x) B_t``,
+    ``y_t = H_t C_t`` in chunks of ``chunk`` tokens: the quadratic form inside
+    a chunk, one state a chunk carried between them.
+
+    ``x [B,S,H,P]``, ``dt [B,S,H]`` (float32, after softplus), ``a [H]``
+    (float32, negative), ``b``/``c [B,S,G,N]`` (``H/G`` heads share a group's
+    B and C). Decays and the carried state are ``state_dtype``: float32 in
+    the model (the benchmark's lower-precision control passes bfloat16 to show
+    what that costs); the four products take their operands in ``x``'s dtype
+    and accumulate in float32. Returns ``y [B,S,H,P]`` in ``x``'s dtype.
+    Differentiable by plain autodiff."""
+    bsz, s, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    e = h // g
+    if s % chunk:
+        raise ValueError(f"sequence {s} is not whole chunks of {chunk} tokens")
+    nc, dtype, f32, sd = s // chunk, x.dtype, jnp.float32, state_dtype
+    xr = x.reshape(bsz, nc, chunk, g, e, p)
+    br = b.reshape(bsz, nc, chunk, g, n)
+    cr = c.reshape(bsz, nc, chunk, g, n)
+    dtr = dt.reshape(bsz, nc, chunk, g, e)
+    cum = jnp.cumsum((dtr * a.reshape(g, e)).astype(sd), axis=2)  # [B,nc,Q,G,E], <= 0
+    xdt = xr.astype(f32) * dtr[..., None]                       # dt_s x_s
+
+    # inside a chunk: y_q += sum_{s<=q} exp(cum_q - cum_s) (C_q . B_s) dt_s x_s
+    cb = jnp.einsum("bcqgn,bcsgn->bcgqs", cr, br, preferred_element_type=f32)
+    seg = cum.transpose(0, 1, 3, 4, 2)                          # [B,nc,G,E,Q]
+    seg = seg[..., :, None] - seg[..., None, :]                 # cum_q - cum_s
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    m = cb[:, :, :, None] * jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    y = jnp.einsum("bcgeqs,bcsgep->bcqgep", m.astype(dtype), xdt.astype(dtype),
+                   preferred_element_type=f32)
+
+    # a chunk's own state at its end, then the state each chunk starts from
+    last = cum[:, :, -1]                                        # [B,nc,G,E]
+    to_end = jnp.exp(last[:, :, None] - cum)
+    states = jnp.einsum("bcsgep,bcsgn->bcgepn", (xdt * to_end[..., None]).astype(dtype),
+                        br, preferred_element_type=sd)
+    before = jnp.cumsum(last, axis=1) - last                    # sum of last_k, k < c
+    after = before + last                                       # k <= c
+    between = before[:, :, None] - after[:, None, :]            # [B,c,z,G,E]: z < k < c
+    earlier = jnp.tril(jnp.ones((nc, nc), bool), k=-1)[None, :, :, None, None]
+    carry = jnp.exp(jnp.where(earlier, between, -jnp.inf))
+    start = jnp.einsum("bczge,bzgepn->bcgepn", carry, states,
+                       precision=lax.Precision.HIGHEST)
+    y = y + jnp.einsum("bcqgn,bcgepn->bcqgep", cr, start.astype(dtype),
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    return y.reshape(bsz, s, h, p).astype(dtype)
+
+
+@dataclass(frozen=True)
+class HybridDecoderDef:
+    pattern: str
+    vocab_size: int
+    seq_len: int
+    hidden: int
+    mamba_heads: int
+    mamba_head_dim: int
+    ssm_groups: int
+    ssm_state: int
+    conv_kernel: int
+    chunk_size: int
+    attn_heads: int
+    kv_heads: int
+    attn_head_dim: int
+    n_experts: int                       # the router's width
+    experts_held: Tuple[int, int]        # (first, count) of the routed experts held here
+    top_k: int
+    expert_width: int
+    shared_width: int
+    routed_scaling: float = 2.5
+    eps: float = 1e-5
+    bias_rate: float = 1e-3
+    # rows of the dropless buffer over the balanced share: a router at its
+    # random initialisation sent up to 1.7x (docs/hybrid_decoder.md)
+    capacity_factor: float = 2.0
+    head_block: int = 2048               # tokens whose logits exist at once
+    # the layers (by depth) that training recomputes in the backward pass; a
+    # layer left out keeps its activations instead (memory for time). None: all
+    recompute: Optional[Tuple[int, ...]] = None
+    rescale_layers: Optional[int] = None  # depth the residual outputs' init is scaled for
+
+    # -- sizes -----------------------------------------------------------------
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.pattern.count("E")
+
+    def buffer_rows(self, tokens: int) -> int:
+        """Static rows of an expert layer's buffer for ``tokens`` tokens."""
+        balanced = tokens * self.top_k * self.experts_held[1] / self.n_experts
+        return min(tokens * self.top_k, int(math.ceil(self.capacity_factor * balanced / 8.0)) * 8)
+
+    # -- parameters ------------------------------------------------------------
+
+    def init(self, key, dtype=jnp.float32):
+        """``(params, state)`` from ``key``, as one compiled program: run
+        eagerly, each of the ~50 leaves' draws compiles a program of its own,
+        50 s of a cold start on the v5e (my chip run, PR 33)."""
+        return _jitted_init(self, key, dtype)
+
+    def _init(self, key, dtype):
+        d, std = self.hidden, 0.02
+        out_std = std / math.sqrt(self.rescale_layers or len(self.pattern))
+        normal = lambda k, shape, s=std: (jax.random.normal(k, shape) * s).astype(dtype)  # noqa: E731
+        keys = jax.random.split(key, len(self.pattern) + 2)
+        layers = []
+        for kind, k in zip(self.pattern, keys):
+            ks = jax.random.split(k, 8)
+            p = {"norm": jnp.ones((d,), dtype)}
+            if kind == "M":
+                h, inner = self.mamba_heads, self.mamba_inner
+                bound = self.conv_kernel ** -0.5
+                step = jnp.exp(jax.random.uniform(
+                    ks[4], (h,), minval=math.log(1e-3), maxval=math.log(1e-1)))
+                step = jnp.maximum(step, 1e-4)
+                p.update(
+                    in_proj=normal(ks[0], (d, 2 * inner + 2 * self.ssm_groups * self.ssm_state + h)),
+                    conv_w=jax.random.uniform(ks[1], (self.conv_kernel, self.conv_dim), dtype, -bound, bound),
+                    conv_b=jax.random.uniform(ks[2], (self.conv_dim,), dtype, -bound, bound),
+                    A_log=jnp.log(jax.random.uniform(ks[3], (h,), dtype, 1.0, 16.0)),
+                    dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(dtype),  # softplus^-1
+                    D=jnp.ones((h,), dtype),
+                    gnorm=jnp.ones((inner,), dtype),
+                    out_proj=normal(ks[5], (inner, d), out_std),
+                )
+            elif kind == "*":
+                q, kv = self.attn_heads * self.attn_head_dim, self.kv_heads * self.attn_head_dim
+                p.update(wq=normal(ks[0], (d, q)), wk=normal(ks[1], (d, kv)),
+                         wv=normal(ks[2], (d, kv)), wo=normal(ks[3], (q, d), out_std))
+            elif kind == "E":
+                held, f, fs = self.experts_held[1], self.expert_width, self.shared_width
+                p.update(
+                    router=normal(ks[0], (d, self.n_experts)),
+                    w_up=normal(ks[1], (held, d, f)), w_down=normal(ks[2], (held, f, d), out_std),
+                    shared_up=normal(ks[3], (d, fs)), shared_down=normal(ks[4], (fs, d), out_std),
+                )
+            else:
+                raise ValueError(f"pattern {self.pattern!r}: unknown layer kind {kind!r}")
+            layers.append(p)
+        params = {
+            "embed": normal(keys[-2], (self.vocab_size, d)),
+            "layers": layers,
+            "norm_f": jnp.ones((d,), dtype),
+            "head": normal(keys[-1], (d, self.vocab_size)),
+        }
+        state = {"router_bias": jnp.zeros((self.n_expert_layers, self.n_experts), jnp.float32)}
+        return params, state
+
+    # -- layers ----------------------------------------------------------------
+
+    def _mixer(self, p, h, dtype):
+        bsz, s, _ = h.shape
+        heads, hp, g, n = self.mamba_heads, self.mamba_head_dim, self.ssm_groups, self.ssm_state
+        inner, k = self.mamba_inner, self.conv_kernel
+        proj = h @ p["in_proj"].astype(dtype)
+        gate, xbc, dt = jnp.split(proj, [inner, inner + self.conv_dim], axis=-1)
+        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+        conv = sum(padded[:, i:i + s] * p["conv_w"][i] for i in range(k)) + p["conv_b"]
+        xbc = jax.nn.silu(conv).astype(dtype)
+        x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+        x = x.reshape(bsz, s, heads, hp)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        with jax.named_scope("ssm/scan"):
+            y = ssm_scan(x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)),
+                         b.reshape(bsz, s, g, n), c.reshape(bsz, s, g, n), self.chunk_size)
+            y = y + (p["D"].astype(jnp.float32)[:, None] * x).astype(dtype)
+        # gate before the norm; the norm is over groups of inner/G channels
+        y = y.reshape(bsz, s, inner).astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+        y = rms_norm(1.0, y.reshape(bsz, s, g, inner // g), self.eps).reshape(bsz, s, inner)
+        return (y * p["gnorm"]).astype(dtype) @ p["out_proj"].astype(dtype)
+
+    def _attention(self, p, h, dtype, attn_impl):
+        bsz, s, _ = h.shape
+        q = (h @ p["wq"].astype(dtype)).reshape(bsz, s, self.attn_heads, self.attn_head_dim)
+        k = (h @ p["wk"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
+        v = (h @ p["wv"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
+        with jax.named_scope("attn/causal"):
+            o = attn_lib.attention(q, k, v, causal=True, impl=attn_impl)
+        return o.reshape(bsz, s, -1) @ p["wo"].astype(dtype)
+
+    def router_scores(self, p, h):
+        """``sigmoid(h W_r)`` over all experts in float32, ``h [T, d]``."""
+        logits = jnp.dot(h.astype(jnp.float32), p["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        return jax.nn.sigmoid(logits)
+
+    def _experts(self, p, bias, h, dtype):
+        bsz, s, d = h.shape
+        x = h.reshape(bsz * s, d)
+        with jax.named_scope("moe/route"):
+            scores = self.router_scores(p, x)
+            chosen, weights = expert_lib.choose_experts(
+                scores, bias, self.top_k, self.routed_scaling)
+            load = jnp.zeros((self.n_experts,), jnp.float32).at[chosen.reshape(-1)].add(1.0)
+        with jax.named_scope("moe/experts"):
+            routed, rows = expert_lib.dropless_experts(
+                x, chosen, weights.astype(dtype), p["w_up"].astype(dtype),
+                p["w_down"].astype(dtype), held=self.experts_held,
+                capacity=self.buffer_rows(bsz * s), activation=_relu2,
+            )
+        with jax.named_scope("moe/shared"):
+            shared = _relu2(x @ p["shared_up"].astype(dtype)) @ p["shared_down"].astype(dtype)
+        return (routed + shared).reshape(bsz, s, d), load, rows
+
+    def hidden_states(self, params, state, tokens, *, train: bool, compute_dtype,
+                      attn_impl: Optional[str] = None, router_inputs: bool = False,
+                      axis_name=None):
+        """Final-norm hidden states ``[B, S, d]``, the new state and the
+        expert layers' counts. Training recomputes each layer in the backward
+        pass. ``router_inputs=True`` also returns every expert layer's
+        normed input ``[T, d]`` (what its router scores; for a balancing
+        loop outside the model). ``axis_name``: the mesh axes the batch is
+        split over; the balancing rule then moves by the whole batch's load."""
+        dtype = compute_dtype
+        x = params["embed"].astype(dtype)[tokens]
+        bias = state["router_bias"]
+        loads, rows, seen = [], [], []
+        for depth, (kind, p) in enumerate(zip(self.pattern, params["layers"])):
+            remat = train and (self.recompute is None or depth in self.recompute)
+            if kind == "E":
+                def f(p, x, b):
+                    return self._experts(p, b, rms_norm(p["norm"], x, self.eps), dtype)
+
+                if router_inputs:
+                    seen.append(rms_norm(p["norm"], x, self.eps).reshape(-1, x.shape[-1]))
+                f = jax.checkpoint(f) if remat else f
+                out, load, n_rows = f(p, x, bias[len(loads)])
+                loads.append(load)
+                rows.append(n_rows)
+            else:
+                def f(p, x, kind=kind):
+                    y = rms_norm(p["norm"], x, self.eps)
+                    return self._mixer(p, y, dtype) if kind == "M" else self._attention(
+                        p, y, dtype, attn_impl)
+
+                out = (jax.checkpoint(f) if remat else f)(p, x)
+            x = x + out
+        loads = jnp.stack(loads) if loads else jnp.zeros((0, self.n_experts), jnp.float32)
+        stats = expert_lib.load_stats(loads, rows, self.experts_held)
+        new_state = state
+        if train and self.n_expert_layers:
+            if axis_name is not None:
+                loads = lax.psum(loads, axis_name)  # the whole batch's load
+            # the auxiliary-loss-free rule: raise the bias of an expert below
+            # the mean load, lower it above; selection only, no gradient
+            mean = loads.mean(axis=-1, keepdims=True)
+            new_state = {"router_bias": bias + self.bias_rate * jnp.sign(mean - loads)}
+        out = (rms_norm(params["norm_f"], x, self.eps), new_state, stats)
+        return out + (seen,) if router_inputs else out
+
+    # -- the model's two entries ------------------------------------------------
+
+    def apply(self, params, state, tokens, train: bool = False, axis_name=None,
+              compute_dtype=jnp.float32, attn_impl: Optional[str] = None):
+        """``[B, S]`` token ids to ``[B, S, vocab]`` float32 logits, all kept:
+        for small shapes and tests; training goes through :meth:`loss`."""
+        h, new_state, _ = self.hidden_states(
+            params, state, tokens, train=train, compute_dtype=compute_dtype, attn_impl=attn_impl)
+        logits = jnp.einsum("bsd,dv->bsv", h, params["head"].astype(compute_dtype),
+                            preferred_element_type=jnp.float32)
+        return logits, new_state
+
+    def loss(self, params, state, tokens, targets, *, train: bool, compute_dtype=jnp.float32,
+             sample_weight=None, attn_impl: Optional[str] = None, axis_name=None):
+        """Mean over all positions of the float32 next-token cross-entropy.
+        Returns ``(loss, new_state, stats)``; ``stats`` has the sums the steps'
+        metrics are made of (``nll_sum``, ``weight_sum``, ``top1``, ``top5``)
+        and the expert layers' counts. ``sample_weight [B]`` weighs whole
+        sequences (eval's padding mask)."""
+        h, new_state, stats = self.hidden_states(
+            params, state, tokens, train=train, compute_dtype=compute_dtype,
+            attn_impl=attn_impl, axis_name=axis_name)
+        b, s, d = h.shape
+        w = jnp.ones((b,), jnp.float32) if sample_weight is None else sample_weight
+        with jax.named_scope("lm/head_loss"):
+            nll, top1, top5 = F.blocked_cross_entropy(
+                h.reshape(b * s, d), params["head"].astype(compute_dtype),
+                targets.reshape(b * s), jnp.repeat(w.astype(jnp.float32), s),
+                block=self.head_block,
+            )
+        weight_sum = w.sum() * s
+        stats = dict(stats, nll_sum=nll, weight_sum=weight_sum, top1=top1, top5=top5,
+                     tokens=jnp.float32(b * s))
+        return nll / jnp.maximum(weight_sum, 1.0), new_state, stats
+
+
+    def count_stats(self, m: dict) -> str:
+        """One fetched step's metrics (what :meth:`loss` returned beside the
+        loss, reduced over replicas by the step) into the process's counters,
+        and the words a step line shows of them. The trainer calls it where
+        it fetches a step's metrics anyway, so no step gains a fetch: sums
+        become counters over the fetched steps, the load ratio a gauge, its
+        peak and its running sum (``docs/observability.md``)."""
+        counters_lib.inc("lm.tokens", m["tokens"])
+        if "moe_rows_live" not in m:
+            return ""
+        for key in ("rows_live", "rows_balanced", "rows_over_cap"):
+            counters_lib.inc("moe." + key, m["moe_" + key])
+        ratio = m["moe_load_max_over_mean"]
+        counters_lib.inc("moe.steps_observed")
+        counters_lib.inc("moe.load_max_over_mean_sum", ratio)
+        counters_lib.set_gauge("moe.load_max_over_mean", ratio)
+        counters_lib.set_gauge("moe.load_max_over_mean_peak", max(
+            ratio, counters_lib.snapshot().get("moe.load_max_over_mean_peak", 0.0)))
+        return (f" moe_load={ratio:.3f} "
+                f"rows={m['moe_rows_live'] / max(m['moe_rows_balanced'], 1.0):.3f}")
+
+
+_jitted_init = jax.jit(HybridDecoderDef._init, static_argnums=(0, 2))
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def nemotron3_nano_share(num_classes: int = 0) -> HybridDecoderDef:
+    """One chip's share of NVIDIA-Nemotron-3-Nano-30B-A3B at its published
+    widths: the first seven layers (one whole ``MEMEM*E`` unit of the
+    52-layer pattern), experts 0-7 of 128 and 16,384 of 131,072 vocabulary
+    rows, as one of 16 expert-parallel chips would hold them; 528.1M
+    parameters. ``num_classes`` is the zoo's calling convention and unused:
+    the vocabulary is the model's."""
+    return HybridDecoderDef(
+        pattern="MEMEM*E", vocab_size=16384, seq_len=8192, hidden=2688,
+        mamba_heads=64, mamba_head_dim=64, ssm_groups=8, ssm_state=128, conv_kernel=4,
+        chunk_size=128, attn_heads=32, kv_heads=2, attn_head_dim=128,
+        n_experts=128, experts_held=(0, 8), top_k=6, expert_width=1856, shared_width=3712,
+        rescale_layers=52, recompute=(0, 2),
+    )
+
+
+def nemotron_h_tiny(num_classes: int = 0) -> HybridDecoderDef:
+    """Every kind of layer at toy widths, for the CPU: 4 experts of 16 held."""
+    return HybridDecoderDef(
+        pattern="ME*E", vocab_size=64, seq_len=32, hidden=32,
+        mamba_heads=4, mamba_head_dim=8, ssm_groups=2, ssm_state=8, conv_kernel=4,
+        chunk_size=8, attn_heads=4, kv_heads=2, attn_head_dim=8,
+        n_experts=16, experts_held=(0, 4), top_k=2, expert_width=16, shared_width=32,
+        head_block=16,
+    )

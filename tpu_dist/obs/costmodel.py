@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import Optional
 
 from tpu_dist.obs import counters as counters_lib
+from tpu_dist.obs import hlo_scopes
 
 # Peak dense matmul FLOP/s per chip (bf16), the MFU denominator. Public
 # spec-sheet numbers, keyed by the exact ``device_kind`` JAX reports (plus
@@ -329,6 +330,12 @@ def analyze_jitted(jitted, *args, loop_trips: int = 1) -> Optional[dict]:
         except Exception:
             return cost
         cost = step_cost(compiled, loop_trips)
+        # the executable is in hand: keep which of its ops lie in which of
+        # the model's named scopes, for whoever reads a device trace
+        try:
+            hlo_scopes.record(compiled.as_text())
+        except Exception:
+            hlo_scopes.record("")  # no table rather than a stale or half one
     return cost
 
 

@@ -79,40 +79,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)                  # [bk, d]
+    def _accumulate(masked):
+        # operands reach the MXU in their own dtype (bf16 under the bf16
+        # policy), products accumulate in f32; the statistics are f32
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         ) * scale                                         # [bq, bk]
-
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < kv_len                             # kv padding
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        s = jnp.where(mask, s, _NEG_INF)
+        if masked:
+            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = k_pos < kv_len                         # kv padding
+            if causal:
+                q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                mask = jnp.logical_and(mask, q_pos >= k_pos)
+            s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = _col(m_scr[:])                           # [bq, 1]
         l_prev = _col(l_scr[:])
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)                       # exact zeros
+        if masked:
+            p = jnp.where(mask, p, 0.0)                   # exact zeros
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32),
+            p.astype(v_ref.dtype), v_ref[0],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
-        # tiles entirely above the diagonal are all-masked: p would be 0,
-        # m/l/acc unchanged — skip their matmuls (same guard as the bwd)
-        pl.when(_causal_block_live(i, j, block_q, block_k))(_accumulate)
-    else:
-        _accumulate()
+    # tiles entirely above the diagonal are all-masked: p would be 0, m/l/acc
+    # unchanged — their matmuls are skipped (same guard as the bwd); only a
+    # tile the diagonal or the padding runs through pays for a mask
+    _by_tile(_accumulate, i, j, causal, block_q, block_k, kv_len)
 
     @pl.when(j == n_k - 1)
     def _finish():
@@ -120,6 +120,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_fin, 1e-30)).astype(out_dtype)
         m_ref[0] = m_scr[:]
         l_ref[0] = l_scr[:]
+
+
+def _by_tile(accumulate, i, j, causal, block_q, block_k, kv_len, q_len=None):
+    """Run ``accumulate(masked)`` for the (q-block ``i``, k-block ``j``) tile:
+    not at all where the tile lies above the causal diagonal, with the mask
+    where the diagonal or the padding of either side runs through it, and
+    without it (no iota, no compare, no select over ``[bq, bk]``) everywhere
+    else, which on a long sequence is nearly every tile."""
+    padded = (j + 1) * block_k > kv_len
+    if q_len is not None:
+        padded = jnp.logical_or(padded, (i + 1) * block_q > q_len)
+    if causal:
+        crossed = (j + 1) * block_k - 1 > i * block_q      # a key past the first query
+        masked = jnp.logical_or(padded, crossed)
+        live = _causal_block_live(i, j, block_q, block_k)
+        pl.when(jnp.logical_and(live, masked))(lambda: accumulate(True))
+        pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(lambda: accumulate(False))
+    else:
+        pl.when(padded)(lambda: accumulate(True))
+        pl.when(jnp.logical_not(padded))(lambda: accumulate(False))
 
 
 def _pad_to(x, mult, axis):
@@ -131,8 +151,24 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-def _fwd(q3, k3, v3, causal, block_q, block_k, interpret, out_dtype=None):
-    """[BH, S, D] inputs → (out [BH, S, D], m [BH, S], l [BH, S]).
+def _last_live_k(i, block_q, block_k):
+    """Last k-block a causal q-block ``i`` reads. Index maps clamp to it, so
+    a tile above the diagonal names the block already in VMEM and costs no
+    copy (its matmuls are skipped in the kernel)."""
+    return ((i + 1) * block_q - 1) // block_k
+
+
+def _first_live_q(j, block_q, block_k):
+    """First q-block a causal k-block ``j`` is read by (dK/dV pass)."""
+    return (j * block_k) // block_q
+
+
+# jitted, so that every call site of one shape traces and lowers a kernel once
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd(q3, k3, v3, causal, block_q, block_k, interpret, out_dtype=None, group=1):
+    """[BH, S, D] queries, [BH/group, S, D] keys and values → (out [BH, S, D],
+    m [BH, S], l [BH, S]). ``group`` consecutive query heads read one
+    key/value head by index: nothing is copied ``group`` times.
 
     ``out_dtype`` overrides the output dtype (default: ``q3.dtype``) — the
     ring composition asks for f32 so per-rotation partials merge without a
@@ -155,13 +191,19 @@ def _fwd(q3, k3, v3, causal, block_q, block_k, interpret, out_dtype=None):
         kv_len=s_kv, out_dtype=odt,
     )
     mem = {"memory_space": pltpu.VMEM}
+
+    def kv_block(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_live_k(i, bq, bk))
+        return (b // group, j, 0)
+
     out, m, l = pl.pallas_call(
         kern,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **mem),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **mem),
+            pl.BlockSpec((1, bk, d), kv_block, **mem),
+            pl.BlockSpec((1, bk, d), kv_block, **mem),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),
@@ -190,30 +232,31 @@ def _fwd(q3, k3, v3, causal, block_q, block_k, interpret, out_dtype=None):
 
 
 def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref,
-                    i, j, *, scale, causal, block_q, block_k, q_len, kv_len):
+                    i, j, *, scale, causal, block_q, block_k, q_len, kv_len, masked):
     """Shared backward block math: recompute the probability block ``p``
     and the score-gradient block ``ds`` from the saved (m, l) statistics.
     One definition, used by BOTH backward kernels — the masking and the
     renormalization clamp must never desync between the dq and dk/dv
-    passes. Returns f32 ``(q, do, p, ds)`` blocks."""
-    q = q_ref[0].astype(jnp.float32)                       # [bq, d]
-    do = do_ref[0].astype(jnp.float32)                     # [bq, d]
-    k = k_ref[0].astype(jnp.float32)                       # [bk, d]
-    v = v_ref[0].astype(jnp.float32)                       # [bk, d]
+    passes. Returns ``(q, do, p, ds)`` blocks: ``q``/``do`` as loaded,
+    ``p``/``ds`` in f32 (the callers cast them to the operands' dtype for
+    their products, which accumulate in f32)."""
+    q, do = q_ref[0], do_ref[0]                            # [bq, d]
+    k, v = k_ref[0], v_ref[0]                              # [bk, d]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                              # [bq, bk]
 
-    q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # padded q rows carry zero m/l from _pad_to — mask them out explicitly
-    mask = jnp.logical_and(q_pos < q_len, k_pos < kv_len)
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-
-    m_i = _col(m_ref[0])                                   # [bq, 1]
-    l_i = jnp.maximum(_col(l_ref[0]), 1e-30)
-    p = jnp.where(mask, jnp.exp(s - m_i), 0.0) / l_i       # [bq, bk]
+    # log-sum-exp of the row: one subtraction a score, no division
+    lse = _col(m_ref[0]) + jnp.log(jnp.maximum(_col(l_ref[0]), 1e-30))  # [bq, 1]
+    p = jnp.exp(s - lse)                                   # [bq, bk]
+    if masked:
+        q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # padded q rows carry zero m/l from _pad_to — mask them out explicitly
+        mask = jnp.logical_and(q_pos < q_len, k_pos < kv_len)
+        if causal:
+            mask = jnp.logical_and(mask, q_pos >= k_pos)
+        p = jnp.where(mask, p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                                      # [bq, bk]
@@ -232,38 +275,38 @@ def _causal_block_live(i, j, block_q, block_k):
 
 def _bwd_dkdv_kernel(q_ref, do_ref, m_ref, l_ref, delta_ref, k_ref, v_ref,
                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                     block_q, block_k, q_len, kv_len, k_dtype, v_dtype):
-    """dK/dV pass (FlashAttention-2): one (batch·head, k-block) per grid
-    point, accumulating over q-blocks in VMEM scratch — the innermost grid
-    dim is the q loop, declared ``arbitrary`` so only it is sequential."""
+                     block_q, block_k, q_len, kv_len, k_dtype, v_dtype, n_q):
+    """dK/dV pass (FlashAttention-2): one (batch·kv-head, k-block) per grid
+    point, accumulating in VMEM scratch over the q-blocks of every query
+    head that reads this key/value head — the innermost grid dim is that
+    (head, q-block) loop, declared ``arbitrary`` so only it is sequential."""
     j = pl.program_id(1)
-    i = pl.program_id(2)
-    n_q = pl.num_programs(2)
+    t = pl.program_id(2)
+    i = t % n_q
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _accumulate():
+    def _accumulate(masked):
         q, do, p, ds = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref, i, j,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            q_len=q_len, kv_len=kv_len,
+            q_len=q_len, kv_len=kv_len, masked=masked,
         )
         dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )                                                  # p^T do: [bk, d]
         dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )                                                  # ds^T q: [bk, d]
 
-    if causal:
-        pl.when(_causal_block_live(i, j, block_q, block_k))(_accumulate)
-    else:
-        _accumulate()
+    _by_tile(_accumulate, i, j, causal, block_q, block_k, kv_len, q_len)
 
-    @pl.when(i == n_q - 1)
+    @pl.when(t == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(k_dtype)
         dv_ref[0] = dv_scr[:].astype(v_dtype)
@@ -282,29 +325,28 @@ def _bwd_dq_kernel(k_ref, v_ref, q_ref, do_ref, m_ref, l_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _accumulate():
+    def _accumulate(masked):
         _, _, _, ds = _recompute_p_ds(
             q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, delta_ref, i, j,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            q_len=q_len, kv_len=kv_len,
+            q_len=q_len, kv_len=kv_len, masked=masked,
         )
-        k = k_ref[0].astype(jnp.float32)
         dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(_causal_block_live(i, j, block_q, block_k))(_accumulate)
-    else:
-        _accumulate()
+    _by_tile(_accumulate, i, j, causal, block_q, block_k, kv_len, q_len)
 
     @pl.when(j == n_k - 1)
     def _finish():
         dq_ref[0] = dq_scr[:].astype(out_dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10),
+                   static_argnames=("grad_dtype", "group"))
 def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
-                delta=None, grad_dtype=None):
+                delta=None, grad_dtype=None, group=1):
     """Pallas FlashAttention-2 backward: two tiled passes (dK/dV then dQ),
     O(block²) VMEM working set, never materializing [S, S] — the TPU-kernel
     sibling of the XLA-level ``_bwd_blocked`` (``bwd='xla'``, kept for A/B).
@@ -341,12 +383,24 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
     n_k = kp.shape[1] // bk
     mem = {"memory_space": pltpu.VMEM}
 
+    def q_block(b, j, t):
+        # t runs over the group's query heads, each over its q-blocks
+        i = t % n_q
+        if causal:
+            i = jnp.maximum(i, _first_live_q(j, bq, bk))
+        return (b * group + t // n_q, i, 0)
+
+    def kv_block(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_live_k(i, bq, bk))
+        return (b // group, j, 0)
+
     q_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0), **mem),  # q
-        pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0), **mem),  # do
-        pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0), **mem),  # m
-        pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0), **mem),  # l
-        pl.BlockSpec((1, bq, _LANES), lambda b, j, i: (b, i, 0), **mem),  # delta
+        pl.BlockSpec((1, bq, d), q_block, **mem),        # q
+        pl.BlockSpec((1, bq, d), q_block, **mem),        # do
+        pl.BlockSpec((1, bq, _LANES), q_block, **mem),   # m
+        pl.BlockSpec((1, bq, _LANES), q_block, **mem),   # l
+        pl.BlockSpec((1, bq, _LANES), q_block, **mem),   # delta
     ]
     kv_specs = [
         pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), **mem),  # k
@@ -356,9 +410,9 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
         functools.partial(
             _bwd_dkdv_kernel, scale=scale, causal=causal, block_q=bq,
             block_k=bk, q_len=s_q, kv_len=s_kv,
-            k_dtype=dk_dtype, v_dtype=dv_dtype,
+            k_dtype=dk_dtype, v_dtype=dv_dtype, n_q=n_q,
         ),
-        grid=(bh, n_k, n_q),
+        grid=(bh // group, n_k, group * n_q),
         in_specs=q_specs + kv_specs,
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), **mem),
@@ -385,8 +439,8 @@ def _bwd_pallas(q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
         ),
         grid=(bh, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **mem),  # k
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0), **mem),  # v
+            pl.BlockSpec((1, bk, d), kv_block, **mem),                   # k
+            pl.BlockSpec((1, bk, d), kv_block, **mem),                   # v
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),  # q
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), **mem),  # do
             pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0), **mem),  # m
@@ -452,24 +506,35 @@ def _bwd_blocked(q3, k3, v3, o3, m, l, do3, causal, block_k):
     return dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, causal, block_q, block_k, interpret, bwd):
-    out, _, _ = _fwd(q3, k3, v3, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q3, k3, v3, causal, block_q, block_k, interpret, bwd, group):
+    out, _, _ = _fwd(q3, k3, v3, causal, block_q, block_k, interpret, None, group)
     return out
 
 
-def _flash_fwd(q3, k3, v3, causal, block_q, block_k, interpret, bwd):
-    out, m, l = _fwd(q3, k3, v3, causal, block_q, block_k, interpret)
+def _flash_fwd(q3, k3, v3, causal, block_q, block_k, interpret, bwd, group):
+    out, m, l = _fwd(q3, k3, v3, causal, block_q, block_k, interpret, None, group)
     return out, (q3, k3, v3, out, m, l)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, bwd, res, do3):
+def _flash_bwd(causal, block_q, block_k, interpret, bwd, group, res, do3):
     q3, k3, v3, o3, m, l = res
     if bwd == "pallas":
         return _bwd_pallas(
-            q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret
+            q3, k3, v3, o3, m, l, do3, causal, block_q, block_k, interpret,
+            group=group,
         )
-    return _bwd_blocked(q3, k3, v3, o3, m, l, do3, causal, block_k)
+    if group == 1:
+        return _bwd_blocked(q3, k3, v3, o3, m, l, do3, causal, block_k)
+    # the XLA formulation has no grouped form: give every query head its
+    # key/value head's copy and sum the group's gradients
+    dq, dk, dv = _bwd_blocked(
+        q3, jnp.repeat(k3, group, axis=0), jnp.repeat(v3, group, axis=0),
+        o3, m, l, do3, causal, block_k,
+    )
+    fold = lambda g: g.astype(jnp.float32).reshape(  # noqa: E731
+        (-1, group) + g.shape[1:]).sum(axis=1)
+    return dq, fold(dk).astype(k3.dtype), fold(dv).astype(v3.dtype)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -654,12 +719,27 @@ def ring_flash_attention(q, k, v, axis_name: str, *, causal: bool = False,
     return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None,
+LONG_SEQ = 2048    # from here on the tiles are LONG_BLOCK wide where that divides
+# 128-wide tiles spend a long sequence on grid steps and on rescaling the
+# accumulator once a k-block: forward + backward at S=8192, 32 heads on 2,
+# takes 66 ms at 256x512, 54 at 512x512, 43 at 512x1024, 40 at 1024x1024
+# (v5e, my chip run, PR 33)
+LONG_BLOCK = 1024
+
+
+def flash_attention(q, k, v, *, causal: bool = False, block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool | None = None,
                     bwd: str = "pallas"):
     """Tiled attention on [B, S, H, D] — drop-in for
     :func:`tpu_dist.nn.attention.full_attention` (same contract: f32
-    softmax accumulation, output in ``q.dtype``).
+    softmax accumulation, output in ``q.dtype``). The matmuls take their
+    operands in the inputs' dtype (bf16 under the bf16 policy) and
+    accumulate in f32; the softmax statistics are f32.
+
+    Grouped heads: ``k``/``v`` may have ``H / g`` heads; query head ``h``
+    reads key/value head ``h // g`` by index, and dK/dV accumulate over the
+    group inside the kernel. Tiles are 128 wide, and ``LONG_BLOCK`` wide from
+    ``LONG_SEQ`` tokens on where that divides the sequence, unless given.
 
     ``interpret=None`` auto-selects Pallas interpret mode off-TPU. Head
     dim ``D`` should be a multiple of 128 lanes for peak MXU utilization
@@ -677,6 +757,10 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, d = q.shape
-    to3 = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, t.shape[1], d)
-    out3 = _flash(to3(q), to3(k), to3(v), causal, block_q, block_k, interpret, bwd)
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{h} query heads do not group over {k.shape[2]}/{v.shape[2]} key/value heads")
+    block = LONG_BLOCK if s >= LONG_SEQ and s % LONG_BLOCK == 0 else 128
+    to3 = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, t.shape[1], d)
+    out3 = _flash(to3(q), to3(k), to3(v), causal, block_q or block, block_k or block,
+                  interpret, bwd, h // k.shape[2])
     return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -22,6 +22,7 @@ the token.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -148,3 +149,144 @@ class MoE:
         P_e = probs.mean(0)                                   # mean router prob
         aux = E * jnp.sum(f_e * P_e)
         return pack, combine, aux.astype(x.dtype)
+
+
+# -- dropless routing over a held share of the experts ------------------------
+#
+# The capacity layer above drops what overflows a slot. The functions below
+# drop nothing, and serve a layer that is told which of the routed experts it
+# holds (``held = (first, count)`` of a wider deployment's experts): the
+# router scores every expert, and the chosen (token, expert) pairs whose
+# expert is held here are sorted by expert into one row buffer of static
+# size and multiplied tile by tile, every tile one expert's. What the absent
+# experts would add is left out, and no code stands in for them.
+
+
+def choose_experts(scores, bias, top_k: int, scaling: float):
+    """The ``top_k`` experts of each token by ``scores + bias`` (the bias
+    moves the selection only), weighted by their scores renormalised over the
+    chosen ones times ``scaling``. ``scores [T, E]`` float32 in (0, 1).
+    Returns ``(chosen [T, k] int32, weights [T, k] float32)``."""
+    _, chosen = lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, scaling * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+
+
+GROUP_TILE = 512  # rows of one grouped-product tile: every tile belongs to one expert
+
+
+def _tile_dot(x, w, transpose: bool):
+    """``x [rows, a]`` times one expert's ``w`` (``[a, b]``, or ``[b, a]`` read
+    transposed), accumulated in float32, back in ``x``'s dtype."""
+    dims = (((1,), (1 if transpose else 0,)), ((), ()))
+    return lax.dot_general(x, w, dims, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(x, w, tile_expert, n_live, transpose=False):
+    """``x [tiles, rows, a]`` times, tile by tile, the expert matrix
+    ``w[tile_expert[t]]`` of ``w [experts, a, b]`` (``[experts, b, a]`` with
+    ``transpose``): one loop over the tiles, an XLA dot a tile, the expert's
+    matrix read in place. Tiles from ``n_live`` on are skipped and give
+    zeros. The gradients are the same loop: ``dx`` against the transposed
+    matrices, ``dw`` summed into its expert's slab tile by tile."""
+    def tile(args):
+        t, x_t, e = args
+        return lax.cond(
+            t < n_live,
+            lambda: _tile_dot(x_t, lax.dynamic_index_in_dim(w, e, keepdims=False), transpose),
+            lambda: jnp.zeros((x.shape[1], w.shape[1 if transpose else 2]), x.dtype),
+        )
+
+    return lax.map(tile, (jnp.arange(x.shape[0]), x, tile_expert))
+
+
+def _grouped_matmul_fwd(x, w, tile_expert, n_live, transpose):
+    return grouped_matmul(x, w, tile_expert, n_live, transpose), (x, w, tile_expert, n_live)
+
+
+def _grouped_matmul_bwd(transpose, res, dy):
+    x, w, tile_expert, n_live = res
+    dx = grouped_matmul(dy, w, tile_expert, n_live, not transpose)
+
+    def tile(dw, args):
+        t, x_t, dy_t, e = args
+        a, b = (dy_t, x_t) if transpose else (x_t, dy_t)
+        part = lax.dot_general(a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return lax.cond(t < n_live, lambda: dw.at[e].add(part), lambda: dw), None
+
+    dw, _ = lax.scan(tile, jnp.zeros(w.shape, jnp.float32),
+                     (jnp.arange(x.shape[0]), x, dy, tile_expert))
+    return dx, dw.astype(w.dtype), None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def dropless_experts(x, chosen, weights, w_up, w_down, *, held, capacity: int, activation):
+    """``sum_k weights[t, k] * expert_{chosen[t, k]}(x[t])`` over the chosen
+    experts that are held, ``expert_e(v) = activation(v w_up[e]) w_down[e]``.
+
+    ``x [T, d]``; ``w_up [count, d, f]``, ``w_down [count, f, d]`` for the
+    experts ``first .. first + count - 1``. The pairs whose expert is held
+    are sorted by expert into a row buffer, each expert's rows padded to
+    whole tiles of ``GROUP_TILE`` rows, and multiplied tile by tile
+    (:func:`grouped_matmul`); the buffer takes ``capacity`` live rows
+    (``capacity / GROUP_TILE + count`` tiles), however they fall on the experts.
+    A step that would need more is never cut short in silence: its output is
+    NaN (the trainer's guard stops on the loss) and ``rows_over_cap`` counts
+    the rows. Returns ``(out [T, d], {"rows_live", "rows_over_cap"})``."""
+    t, k = chosen.shape
+    first, count = held
+    tile = min(GROUP_TILE, -(-capacity // 8) * 8)       # a small buffer is one short tile an expert
+    n_tiles = -(-capacity // tile) + count
+    local = chosen.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)  # absent experts sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    live = sizes.sum()
+    over = jnp.maximum(live - capacity, 0)
+    ends = jnp.minimum(jnp.cumsum(sizes), capacity)
+    sizes = jnp.diff(ends, prepend=0)                       # what the buffer takes
+    starts = ends - sizes
+    tiles = -(-sizes // tile)                               # whole tiles an expert
+    tile_ends = jnp.cumsum(tiles)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right"), count - 1)
+    # buffer row -> its place in the sorted pairs, or past the expert's rows
+    row = jnp.arange(n_tiles * tile)
+    e = jnp.repeat(tile_expert, tile)
+    within = row - (tile_ends - tiles)[e] * tile
+    valid = ((within < sizes[e]) & (row < tile_ends[-1] * tile))[:, None]
+    pair = order[jnp.clip(starts[e] + within, 0, t * k - 1)]
+    token = pair // k
+    shape = (n_tiles, tile, -1)
+    rows = jnp.where(valid, x[token], 0).reshape(shape)
+    h = activation(grouped_matmul(rows, w_up, tile_expert, tile_ends[-1]))
+    y = grouped_matmul(h, w_down, tile_expert, tile_ends[-1]).reshape(n_tiles * tile, -1)
+    y = jnp.where(valid, y.astype(jnp.float32) * weights.reshape(-1)[pair][:, None], 0)
+    out = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(y)
+    out = jnp.where(over > 0, jnp.nan, out)
+    return out.astype(x.dtype), {"rows_live": live, "rows_over_cap": over}
+
+
+def load_stats(loads, rows, held):
+    """What a step's expert layers did, as float32 scalars: ``loads [L, E]``
+    (tokens that chose each expert, a layer) and the layers' ``rows`` dicts.
+    ``moe_rows_balanced`` is what a perfectly balanced router would have sent
+    to the held experts. The counts are sums (over replicas too); what is a
+    worst case instead sits under ``"maxima"``, which is how a step knows to
+    take its maximum over replicas: ``moe_load_max_over_mean``, the worst
+    layer's busiest expert over the mean."""
+    if not rows:
+        return {}
+    total = loads.sum(axis=-1)
+    return {
+        "moe_rows_live": sum(r["rows_live"] for r in rows).astype(jnp.float32),
+        "moe_rows_over_cap": sum(r["rows_over_cap"] for r in rows).astype(jnp.float32),
+        "moe_rows_balanced": (total * held[1] / loads.shape[-1]).sum(),
+        "maxima": {
+            "moe_load_max_over_mean":
+                (loads.max(axis=-1) / jnp.maximum(loads.mean(axis=-1), 1.0)).max(),
+        },
+    }

@@ -305,8 +305,20 @@ def make_train_step(
     grad_compression: str = "none",
     device_metrics: bool = False,
     model_kwargs: dict | None = None,
+    model_loss: Callable | None = None,
 ):
     """Build ``step(state, images, labels, lr) -> (state, metrics)``.
+
+    ``model_loss``: a model that computes its own loss (a token model:
+    ``nn/nemotron_h.py``) gives ``model_loss(params, state, inputs, targets,
+    train=, compute_dtype=) -> (loss, new_state, stats)`` in place of
+    ``model_apply`` + cross-entropy. Inputs are integer ids and stay so;
+    targets are one a position; the parameters reach the model in float32
+    and it casts what it multiplies (its router and recurrences stay
+    float32); recomputation is the model's own, a layer at a time. The
+    state it returns is carried as ``bn_state`` is. ``stats`` holds the
+    sums the metrics are made of (``top1``, ``top5``, ``weight_sum``) and
+    any counts of the model's own, which ride the metrics dict (one fetch).
 
     ``model_apply(params, bn_state, x, train=, axis_name=)`` is the
     functional model (e.g. ``ResNetDef.apply``). ``metrics`` is a dict of
@@ -466,6 +478,13 @@ def make_train_step(
     batch_axes = (axis, ep_axis) if ep_axis is not None else axis
     bn_axis = batch_axes if sync_bn else None
 
+    def token_loss_fn(params, bn_state, tokens, targets):
+        loss, new_bn, stats = model_loss(
+            params, bn_state, tokens, targets, train=True,
+            compute_dtype=compute_dtype, axis_name=batch_axes, **(model_kwargs or {}),
+        )
+        return loss, (new_bn, stats)
+
     def loss_fn(params, bn_state, images, labels):
         x = images.astype(compute_dtype)
         p = jax.tree_util.tree_map(lambda t: t.astype(compute_dtype), params)
@@ -533,7 +552,17 @@ def make_train_step(
         scale = jnp.minimum(1.0, grad_clip_norm / jnp.maximum(norm, 1e-12))
         return jax.tree_util.tree_map(lambda g: g * scale, grads)
 
-    if remat:
+    if model_loss is not None:
+        if K > 1 or shard_weight_update or quantized or any(
+            a is not None for a in (tp_axis, ep_axis, pp_axis, seq_axis)
+        ):
+            raise ValueError(
+                "a model with its own loss (token models) runs the plain "
+                "data-parallel step: no grad accumulation, ZeRO-1, int8 wire "
+                "or tp/ep/pp/sp yet"
+            )
+        loss_fn = token_loss_fn
+    elif remat:
         # rematerialize the forward during the backward: activations are
         # recomputed instead of stored, trading ~33% extra FLOPs for O(depth)
         # less activation memory — the standard TPU lever for bigger batches.
@@ -609,13 +638,26 @@ def make_train_step(
         new_state = TrainState(new_params, new_bn, new_opt, state.step + 1, new_ef)
 
         # Replica-averaged metrics, fused into the same program
-        labels_all = labels
-        c1, c5 = F.topk_correct(logits.astype(jnp.float32), labels_all, (1, 5))
-        b = labels_all.shape[0]
+        if model_loss is not None:
+            # `logits` is the model's stats: hits and positions as sums, and
+            # its own counts: summed over replicas, but for what the model
+            # put under "maxima", of which the worst replica's is taken
+            stats = dict(logits)
+            c1, c5, b = stats.pop("top1"), stats.pop("top5"), stats.pop("weight_sum")
+            stats.pop("nll_sum")
+            maxima = stats.pop("maxima", {})
+            extra = {
+                **{k: lax.psum(v, batch_axes) for k, v in stats.items()},
+                **{k: lax.pmax(v, batch_axes) for k, v in maxima.items()},
+            }
+        else:
+            c1, c5 = F.topk_correct(logits.astype(jnp.float32), labels, (1, 5))
+            b, extra = labels.shape[0], {}
         metrics = {
             "loss": lax.pmean(loss, batch_axes),
             "acc1": lax.psum(c1, batch_axes) / (b * lax.psum(1, batch_axes)) * 100.0,
             "acc5": lax.psum(c5, batch_axes) / (b * lax.psum(1, batch_axes)) * 100.0,
+            **extra,
         }
         if device_metrics:
             # grads is the post-reduce (post-clip) tree here — the ZeRO-1
@@ -790,8 +832,12 @@ def make_eval_step(
     opt_specs=None,
     ef_specs=(),
     model_kwargs: dict | None = None,
+    model_loss: Callable | None = None,
 ):
     """Build ``eval_step(state, images, labels, mask) -> sums``.
+
+    ``model_loss``: as in :func:`make_train_step`; a sequence counts as one
+    example, its loss and hits as the means over its positions.
 
     ``opt_specs``: partition specs for the optimizer state when its TREE
     differs from the param tree (AdamW under TP/EP/PP) — eval never reads
@@ -811,6 +857,19 @@ def make_eval_step(
     needs no sequence parallelism — different devices just hold different
     examples).
     """
+
+    def eval_tokens(state: TrainState, tokens, targets, mask):
+        _, _, stats = model_loss(
+            state.params, state.bn_state, tokens, targets, train=False,
+            compute_dtype=compute_dtype, sample_weight=mask, **(model_kwargs or {}),
+        )
+        per = 1.0 / targets.shape[1]
+        return {
+            "loss": lax.psum(stats["nll_sum"] * per, axis),
+            "top1": lax.psum(stats["top1"] * per, axis),
+            "top5": lax.psum(stats["top5"] * per, axis),
+            "count": lax.psum(jnp.sum(mask), axis),
+        }
 
     def eval_local(state: TrainState, images, labels, mask):
         x = images.astype(compute_dtype)
@@ -850,7 +909,7 @@ def make_eval_step(
         ef=ef_specs,
     )
     sharded = shard_map(
-        eval_local,
+        eval_local if model_loss is None else eval_tokens,
         mesh=mesh,
         in_specs=(state_spec, P(axis), P(axis), P(axis)),
         out_specs=P(),

@@ -101,6 +101,14 @@ def build_model(cfg: TrainConfig):
         from tpu_dist.nn.resnet import resnet50_imagenet  # noqa: PLC0415
 
         _MODELS.setdefault("resnet50_imagenet", resnet50_imagenet)
+
+        from tpu_dist.nn.nemotron_h import (  # noqa: PLC0415
+            nemotron3_nano_share,
+            nemotron_h_tiny,
+        )
+
+        _MODELS.setdefault("nemotron3_nano_share", nemotron3_nano_share)
+        _MODELS.setdefault("nemotron_h_tiny", nemotron_h_tiny)
     except ImportError:
         pass
     if cfg.model not in _MODELS:
@@ -291,6 +299,16 @@ class Trainer:
         # Trainer in the same process must not inherit a stale 'flash'
         set_default_attention_impl(self._attn_impl(cfg))
         self.model = build_model(cfg)
+        # a model with its own loss takes token ids and per-position targets
+        # (nn/nemotron_h.py); the steps call its `loss` where it has one
+        self._token_model = hasattr(self.model, "loss")
+        if self._token_model and (
+            cfg.fsdp or cfg.fused_epoch or cfg.dataset != "synthetic_tokens"
+        ):
+            raise ValueError(
+                f"model {cfg.model!r} takes token ids: --dataset synthetic_tokens, "
+                "the plain data-parallel step (no --fsdp, no --fused_epoch)"
+            )
         if cfg.sp_mode not in ("ring", "ulysses"):
             raise ValueError(
                 f"sp_mode must be 'ring' or 'ulysses', got {cfg.sp_mode!r}"
@@ -549,6 +567,20 @@ class Trainer:
             self.test_data = synthetic_multifactor(
                 max(cfg.synthetic_n // 5, self.n_devices), seed=2, label_noise=0.0
             )
+        elif cfg.dataset == "synthetic_tokens":
+            from tpu_dist.data.synthetic import synthetic_tokens  # noqa: PLC0415
+
+            if not self._token_model:
+                raise ValueError(
+                    f"dataset 'synthetic_tokens' needs a token model; {cfg.model!r} "
+                    "takes images"
+                )
+            # sequence length and vocabulary are the model's
+            m = self.model
+            self.train_data = synthetic_tokens(cfg.synthetic_n, m.seq_len, m.vocab_size, seed=1)
+            self.test_data = synthetic_tokens(
+                max(cfg.synthetic_n // 5, self.n_devices), m.seq_len, m.vocab_size, seed=2
+            )
         elif cfg.dataset == "cifar100":
             self.train_data = load_cifar100(cfg.data_dir, train=True)
             self.test_data = load_cifar100(cfg.data_dir, train=False)
@@ -620,15 +652,20 @@ class Trainer:
             eval_axes = mesh_lib.DATA_AXIS
         eval_ways = self.n_data * (cfg.sp if cfg.sp > 1 else cfg.ep if cfg.ep > 1 else 1)
         eval_divisor = max(1, eval_ways // nproc)
+        # integer ids are gathered by index and placed as they are: no crop,
+        # no normalisation, no C++ image path
+        images = self.train_data[0].dtype == np.uint8
         self.train_loader = DataLoader(
             *self.train_data, self.local_batch, self.train_sampler, self.mesh,
-            gather_transform=functools.partial(native.gather_augment, train=True, **stats),
+            gather_transform=functools.partial(
+                native.gather_augment, train=True, **stats) if images else None,
             seed=seed, prefetch=cfg.num_workers, batch_divisor=divisor,
             shard_axes=train_axes,
         )
         self.test_loader = DataLoader(
             *self.test_data, self.local_batch, self.test_sampler, self.mesh,
-            gather_transform=functools.partial(native.gather_augment, train=False, **stats),
+            gather_transform=functools.partial(
+                native.gather_augment, train=False, **stats) if images else None,
             seed=seed, with_mask=True, prefetch=cfg.num_workers,
             batch_divisor=eval_divisor, shard_axes=eval_axes,
         )
@@ -832,6 +869,7 @@ class Trainer:
             self.eval_step = make_eval_step(
                 self.model.apply, self.mesh, compute_dtype=compute_dtype,
                 model_kwargs=self._attn_model_kwargs() or None,
+                model_loss=getattr(self.model, "loss", None),
                 axis=eval_axes,
                 tp_axis=mesh_lib.MODEL_AXIS if cfg.tp > 1 else None,
                 ep_axis=mesh_lib.EXPERT_AXIS if cfg.ep > 1 else None,
@@ -919,7 +957,9 @@ class Trainer:
                 "images": jax.ShapeDtypeStruct(
                     (per_dev,) + tuple(img.shape[1:]), img.dtype
                 ),
-                "labels": jax.ShapeDtypeStruct((per_dev,), lbl.dtype),
+                "labels": jax.ShapeDtypeStruct(
+                    (per_dev,) + tuple(lbl.shape[1:]), lbl.dtype
+                ),
             }
         except Exception:  # tpu-dist: ignore[TD006] — an exotic dataset
             pass  # shape only costs the batch row, never the pre-flight
@@ -1114,6 +1154,7 @@ class Trainer:
             grad_compression=cfg.grad_compression,
             device_metrics=cfg.device_metrics,
             model_kwargs=mk or None,
+            model_loss=getattr(self.model, "loss", None),
         )
 
     def _attn_model_kwargs(self) -> dict:
@@ -1552,6 +1593,9 @@ class Trainer:
                 # logged BEFORE the NaN guard below raises), per-step
                 # TensorBoard scalars — no additional device traffic
                 self._observe_health(epoch, step, nb, m)
+                # a model with its own loss counts what it returned beside it
+                # (nn/nemotron_h.py), from the copy fetched above
+                model_note = self.model.count_stats(m) if hasattr(self.model, "count_stats") else ""
             if want_save:
                 # periodic EXACT snapshot (kill-9 safety for long epochs):
                 # same stamp as the interrupt path — ckpt_{epoch} carries
@@ -1591,6 +1635,7 @@ class Trainer:
                         f"upd={m['update_ratio']:.2e}"
                         if "grad_norm" in m else ""
                     )
+                    + model_note
                 )
             if preemption.requested():
                 # cooperative SIGTERM: the in-flight step is finished and
@@ -1631,8 +1676,10 @@ class Trainer:
         dt = time.time() - t0
         ips = images_seen / dt if dt > 0 else 0.0
         # reference epoch wall-time print (distributed.py:113-115)
+        unit = "samples/s" if self._token_model else "img/s"
         rank0_print(
-            f"Epoch {epoch} done in {dt:.2f}s ({ips:.0f} img/s, avg loss {losses.avg:.4f})"
+            f"Epoch {epoch} done in {dt:.2f}s ({ips:.{2 if ips < 100 else 0}f} {unit}, "
+            f"avg loss {losses.avg:.4f})"
         )
         out.update(epoch_time=dt, images_per_sec=ips)
         # step-phase summary: tail latency + where the wall time went
