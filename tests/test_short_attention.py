@@ -5,7 +5,9 @@ one compile-only look at what it takes out of the v5e program.
 The kernel runs in Pallas interpret mode here; the compile-only test builds
 the ViT-B/16 block for a described (not attached) ``v5e:2x2`` and is skipped
 where that topology cannot be described. It is the only test file that loads
-the TPU compiler: keep it that way (one process holds the library's lock).
+the TPU compiler: keep it that way (one process holds the library's lock),
+which is why the scan kernel's compile-only test (``ops/ssm_scan.py``; its
+other tests are ``tests/test_ssm_scan_kernel.py``) stands at the end of it.
 """
 
 import os
@@ -336,3 +338,51 @@ def test_v5e_block_program_holds_no_score_array_and_few_copies(monkeypatch, one_
     mosaic = 'custom_call_target="tpu_custom_call"'
     assert (texts["auto"].count(mosaic), texts["xla"].count(mosaic)) == (2, 0)
     assert copy_bytes(programs["auto"]) < 0.4 * copy_bytes(programs["xla"])
+
+
+# -- compile only: the mixers' scan kernel pair (ops/ssm_scan.py) ----------------
+
+
+def _scan_program(one_chip, scan):
+    """Forward + backward of one mixer's scan at the Nemotron share's shapes
+    (2 x 8,192 tokens, 64 heads of 64 in 8 groups, state 128, chunk 128,
+    bfloat16 operands), x, B and C flat as the mixer's convolution leaves
+    them, compiled for one v5e chip."""
+    b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (place((b, t, h * p), bf16), place((b, t, h), f32), place((h,), f32),
+            place((b, t, g * n), bf16), place((b, t, g * n), bf16), place((b, t, h * p), bf16))
+
+    def loss(x, dt, a, bb, cc, w):
+        y = scan(x.reshape(b, t, h, p), dt, a, bb.reshape(b, t, g, n), cc.reshape(b, t, g, n), 128)
+        return (y.reshape(b, t, h * p).astype(f32) * w.astype(f32)).sum()
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+
+
+def _state_sized_float32(compiled):
+    """(opcode, dims) of the entry computation's float32 arrays as large as
+    every chunk's state, [2, 64, 8, 8, 64, 128] elements."""
+    return [(op, dims) for op, arrays in _entry_ops(compiled.as_text()) for dt, dims in arrays
+            if dt == "f32" and int(np.prod(dims)) >= 2 * 64 * 8 * 8 * 64 * 128]
+
+
+def test_scan_kernel_pair_compiles_for_v5e_and_keeps_one_state_array(monkeypatch, one_chip):
+    """What interpret mode cannot show: Mosaic takes the three kernels at
+    the cell's shapes, and of the einsum form's per-chunk states (float32 and
+    their cotangents, through HBM) one array is left, the states the chunks
+    start from, which the reverse sweep reads."""
+    from tpu_dist.nn import nemotron_h as decoder
+    from tpu_dist.ops import ssm_scan as S
+
+    assert S.fits(128, 8, 64, 128, jnp.bfloat16)
+    kernel = _scan_program(one_chip, lambda *a: S.ssm_scan(*a, interpret=False))
+    assert kernel.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    assert _state_sized_float32(kernel) == [("custom-call", [2, 64, 8, 512, 128])]
+    assert kernel.memory_analysis().temp_size_in_bytes < 0.6e9
+
+    monkeypatch.setattr(decoder, "_on_tpu", lambda: False)  # the einsum form, for the v5e
+    einsums = _scan_program(one_chip, decoder.ssm_scan)
+    assert len(_state_sized_float32(einsums)) >= 2
+    assert einsums.memory_analysis().temp_size_in_bytes > 0.9e9

@@ -54,6 +54,21 @@ def rms_norm(scale, x, eps: float):
     return (y * scale).astype(x.dtype)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def takes_scan_kernel(chunk: int, heads_per_group: int, head_dim: int, state: int,
+                      dtype, state_dtype) -> bool:
+    """Where :func:`ssm_scan` takes the Pallas kernel pair: a TPU, float32
+    decays and state (the kernel has no other), and a shape its blocks fit."""
+    if not _on_tpu() or jnp.dtype(state_dtype) != jnp.float32:
+        return False
+    from tpu_dist.ops.ssm_scan import fits  # noqa: PLC0415
+
+    return fits(chunk, heads_per_group, head_dim, state, dtype)
+
+
 def ssm_scan(x, dt, a, b, c, chunk: int, state_dtype=jnp.float32):
     """Mamba-2's recurrence ``H_t = exp(dt_t a) H_{t-1} + dt_t x_t (x) B_t``,
     ``y_t = H_t C_t`` in chunks of ``chunk`` tokens: the quadratic form inside
@@ -65,12 +80,24 @@ def ssm_scan(x, dt, a, b, c, chunk: int, state_dtype=jnp.float32):
     the model (the benchmark's lower-precision control passes bfloat16 to show
     what that costs); the four products take their operands in ``x``'s dtype
     and accumulate in float32. Returns ``y [B,S,H,P]`` in ``x``'s dtype.
-    Differentiable by plain autodiff."""
+
+    One algorithm, two realisations, chosen by what is seen here: on a TPU,
+    with float32 state and shapes the kernel pair of ``ops/ssm_scan.py``
+    takes (:func:`takes_scan_kernel`), the chunks are walked in order with
+    the carried state in VMEM (counted in ``ssm.sites_kernel``); anything
+    else is the einsum form below, differentiable by plain autodiff
+    (``ssm.sites_xla``), which puts every chunk's state through HBM."""
     bsz, s, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
     e = h // g
     if s % chunk:
         raise ValueError(f"sequence {s} is not whole chunks of {chunk} tokens")
+    if takes_scan_kernel(chunk, e, p, n, x.dtype, state_dtype):
+        from tpu_dist.ops import ssm_scan as scan_kernel  # noqa: PLC0415
+
+        counters_lib.inc("ssm.sites_kernel")
+        return scan_kernel.ssm_scan(x, dt, a, b, c, chunk, interpret=False)  # only on a TPU
+    counters_lib.inc("ssm.sites_xla")
     nc, dtype, f32, sd = s // chunk, x.dtype, jnp.float32, state_dtype
     xr = x.reshape(bsz, nc, chunk, g, e, p)
     br = b.reshape(bsz, nc, chunk, g, n)
@@ -224,15 +251,19 @@ class HybridDecoderDef:
         conv = sum(padded[:, i:i + s] * p["conv_w"][i] for i in range(k)) + p["conv_b"]
         xbc = jax.nn.silu(conv).astype(dtype)
         x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
-        x = x.reshape(bsz, s, heads, hp)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        # Around the scan everything stays [B, S, inner], the layout the
+        # projections and the scan kernel share: on the TPU a [.., heads, 64]
+        # or [.., groups, inner/G] view of it is another tiling, and XLA
+        # copies the whole array to get there and back (PERF.md, PR 34).
         with jax.named_scope("ssm/scan"):
-            y = ssm_scan(x, dt, -jnp.exp(p["A_log"].astype(jnp.float32)),
+            y = ssm_scan(x.reshape(bsz, s, heads, hp), dt, -jnp.exp(p["A_log"].astype(jnp.float32)),
                          b.reshape(bsz, s, g, n), c.reshape(bsz, s, g, n), self.chunk_size)
-            y = y + (p["D"].astype(jnp.float32)[:, None] * x).astype(dtype)
+            skip = jnp.repeat(p["D"].astype(jnp.float32), hp)    # a head's D over its channels
+            y = y.reshape(bsz, s, inner) + (skip * x).astype(dtype)
         # gate before the norm; the norm is over groups of inner/G channels
-        y = y.reshape(bsz, s, inner).astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
-        y = rms_norm(1.0, y.reshape(bsz, s, g, inner // g), self.eps).reshape(bsz, s, inner)
+        y = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+        y = jnp.concatenate([rms_norm(1.0, part, self.eps) for part in jnp.split(y, g, axis=-1)], -1)
         return (y * p["gnorm"]).astype(dtype) @ p["out_proj"].astype(dtype)
 
     def _attention(self, p, h, dtype, attn_impl):
