@@ -76,3 +76,24 @@ def hybrid_arch(m, bias=None) -> dict:
 
         arch["router_bias"] = np.asarray(bias)
     return arch
+
+
+def lfm2_arch(m, bias=None) -> dict:
+    """The ``arch`` block the plain reference (``benchmarks/models/lfm2_moe.py``)
+    reads, for a ``HybridDecoderDef`` of LFM2 blocks (a layer is an operator
+    block, ``C`` or ``*``, then a feed-forward block, ``F`` or ``E``)."""
+    ops, ffns = m.pattern[0::2], m.pattern[1::2]
+    arch = dict(
+        hidden_size=m.hidden, conv_L_cache=m.conv_kernel, intermediate_size=m.dense_width,
+        moe_intermediate_size=m.expert_width, num_attention_heads=m.attn_heads,
+        num_key_value_heads=m.kv_heads, rope_parameters={"rope_theta": m.rope_theta},
+        norm_eps=m.eps, num_experts_per_tok=m.top_k, routed_scaling_factor=m.routed_scaling,
+        layer_types=["conv" if c == "C" else "full_attention" for c in ops],
+        num_dense_layers=ffns.count("F"), published={"num_experts": m.n_experts},
+        experts_held=list(m.experts_held), vocab_size=m.vocab_size, seq_len=m.seq_len,
+    )
+    if bias is not None:
+        import numpy as np  # noqa: PLC0415
+
+        arch["router_bias"] = np.asarray(bias)
+    return arch

@@ -277,6 +277,22 @@ def test_kernel_pair_compiles_for_v5e(one_chip, seq, heads, d, dtype):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("heads,kv,d", [(32, 8, 64), (32, 2, 128)], ids=["lfm2_64", "nemotron_128"])
+def test_tiled_kernels_compile_for_v5e_at_the_token_cells_shapes(one_chip, heads, kv, d):
+    """The tiled flash kernels (this file holds the suite's one described
+    topology) at the two token cells' heads, causal, 8,192 tokens, bf16,
+    1,024-wide tiles: forward, dK/dV and dQ, with heads of 64 channels as
+    whole blocks of a 64-wide array."""
+    from tpu_dist.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 8192, heads, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 8192, kv, d), jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, interpret=False).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 _BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
 _ENTRY_OP = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?)\s([\w\-]+)\(")
 _SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")

@@ -82,12 +82,12 @@ def takes_flash_kernel(impl: Optional[str], causal: bool, seq: int, h_dim: int) 
     """Where :func:`full_attention` takes the tiled kernel
     (``ops/flash_attention.py``) without being told to: a causal site on a
     TPU from ``FLASH_MIN_SEQ`` tokens on, whose ``[S, S]`` scores the XLA
-    chain would put in HBM for every head, with whole 128-lane heads and
-    whole tiles. ``impl`` "flash" takes it at any shape."""
+    chain would put in HBM for every head, with heads of 64 channels or whole
+    128-lane groups and whole tiles. ``impl`` "flash" takes it at any shape."""
     impl = _resolve_impl(impl)
     if impl != "auto":
         return impl == "flash"
-    return causal and _on_tpu() and seq >= FLASH_MIN_SEQ and seq % 128 == 0 and h_dim % 128 == 0
+    return causal and _on_tpu() and seq >= FLASH_MIN_SEQ and seq % 128 == 0 and h_dim % 64 == 0
 
 
 def full_attention(q, k, v, *, causal: bool = False, impl: Optional[str] = None):

@@ -1,18 +1,30 @@
-"""Hybrid decoder of the Nemotron-H family: Mamba-2 mixers, sparse expert
-layers and grouped-head causal attention in one stack, driven by a pattern
-string (``M`` mixer, ``*`` attention, ``E`` expert layer), one pre-norm
-residual block a character: ``x <- x + f_i(RMSNorm(x))``, a final RMSNorm and
-an untied head. ``docs/hybrid_decoder.md`` has the equations.
+"""Hybrid decoders as a pattern of blocks: one definition, ``HybridDecoderDef``,
+runs every token model of the framework. A pattern string names the blocks in
+depth order, one pre-norm residual block a character, ``x <- x +
+f_i(RMSNorm(x))``:
 
-The first token model of the framework, so its contract is wider than
-``ViTDef``'s. ``init(key) -> (params, state)`` and ``apply(params, state,
-tokens, train=) -> (logits, state)`` as everywhere; beside them
-``loss(params, state, tokens, targets, train=, compute_dtype=)``, which the
-train and eval steps call where a model has it: the head and its
+* ``M`` Mamba-2 mixer, ``*`` grouped-head causal attention, ``E`` dropless
+  expert layer (the Nemotron-H family, ``model_type: nemotron_h``: non-gated
+  ``relu^2`` experts beside a shared expert, no positions, an untied head);
+* ``C`` gated short convolution, ``F`` dense gated feed-forward (the LFM2
+  family, ``model_type: lfm2_moe``, whose layer is two blocks: operator, then
+  feed-forward; there ``*`` has per-head q/k RMSNorm and rotary positions,
+  ``E`` has gated experts and no shared expert, and the head is the embedding
+  read transposed).
+
+What differs between families at one kind of block is a field of the
+definition (``gated_experts``, ``shared_width`` 0, ``qk_norm``, ``rope_theta``,
+``tied_head``, ``topk_eps``), each defaulting to the Nemotron form. Then a
+final RMSNorm and the head. ``docs/hybrid_decoder.md`` has the equations.
+
+The contract is wider than ``ViTDef``'s. ``init(key) -> (params, state)`` and
+``apply(params, state, tokens, train=) -> (logits, state)`` as everywhere;
+beside them ``loss(params, state, tokens, targets, train=, compute_dtype=)``,
+which the train and eval steps call where a model has it: the head and its
 cross-entropy run over the tokens in blocks, so no ``[tokens, vocabulary]``
-array outlives a block, and layers are recomputed in the backward pass one at
-a time (``jax.checkpoint`` a layer, not one around the whole loss; which
-layers, ``recompute`` says).
+array outlives a block, and blocks are recomputed in the backward pass one at
+a time (``jax.checkpoint`` a block, not one around the whole loss; which
+blocks, ``recompute`` says).
 
 ``state`` holds what is not a parameter: ``router_bias [expert layers,
 experts]``, the selection bias of the auxiliary-loss-free balancing rule,
@@ -21,14 +33,14 @@ step and receives no gradient.
 
 Mixed precision is the model's own: parameters arrive in float32 and each
 matrix is cast to ``compute_dtype`` where it is used; the router, the
-mixer's decay and state, every norm and the loss stay in float32; the
-residual stream is in ``compute_dtype``.
+mixer's decay and state, every norm, the rotation, the gates' products and
+the loss stay in float32; the residual stream is in ``compute_dtype``.
 
 A deployment's share: ``experts_held = (first, count)`` says which routed
-experts this chip holds (``w_up``/``w_down`` have ``count`` leading rows);
+experts this chip holds (the experts' matrices have ``count`` leading rows);
 the router scores all ``n_experts``, and the layer adds only what its own
-experts give to the shared expert's output. ``vocab_size`` is the slice of
-the vocabulary held here.
+experts give (to the shared expert's output, where there is one).
+``vocab_size`` is the slice of the vocabulary held here.
 """
 
 from __future__ import annotations
@@ -39,6 +51,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from tpu_dist.nn import attention as attn_lib
@@ -142,7 +155,7 @@ class HybridDecoderDef:
     mamba_head_dim: int
     ssm_groups: int
     ssm_state: int
-    conv_kernel: int
+    conv_kernel: int                     # taps of the depthwise causal convolution (`M`, `C`)
     chunk_size: int
     attn_heads: int
     kv_heads: int
@@ -151,7 +164,7 @@ class HybridDecoderDef:
     experts_held: Tuple[int, int]        # (first, count) of the routed experts held here
     top_k: int
     expert_width: int
-    shared_width: int
+    shared_width: int                    # 0: no shared expert
     routed_scaling: float = 2.5
     eps: float = 1e-5
     bias_rate: float = 1e-3
@@ -163,6 +176,13 @@ class HybridDecoderDef:
     # layer left out keeps its activations instead (memory for time). None: all
     recompute: Optional[Tuple[int, ...]] = None
     rescale_layers: Optional[int] = None  # depth the residual outputs' init is scaled for
+    # what a family says of its blocks; the defaults are Nemotron-H's
+    dense_width: int = 0                 # an `F` block's width
+    gated_experts: bool = False          # (silu(x W_gate) * (x W_up)) W_down, not relu2(x W_up) W_down
+    qk_norm: bool = False                # per-head RMSNorm of q and k, learned weights
+    rope_theta: Optional[float] = None   # rotary base over the whole head; None: no positions
+    tied_head: bool = False              # the head is the embedding read transposed
+    topk_eps: float = 1e-20              # added to the chosen scores' sum (LFM2 publishes 1e-6)
 
     # -- sizes -----------------------------------------------------------------
 
@@ -220,13 +240,31 @@ class HybridDecoderDef:
                 q, kv = self.attn_heads * self.attn_head_dim, self.kv_heads * self.attn_head_dim
                 p.update(wq=normal(ks[0], (d, q)), wk=normal(ks[1], (d, kv)),
                          wv=normal(ks[2], (d, kv)), wo=normal(ks[3], (q, d), out_std))
+                if self.qk_norm:
+                    p.update(q_norm=jnp.ones((self.attn_head_dim,), dtype),
+                             k_norm=jnp.ones((self.attn_head_dim,), dtype))
             elif kind == "E":
                 held, f, fs = self.experts_held[1], self.expert_width, self.shared_width
                 p.update(
                     router=normal(ks[0], (d, self.n_experts)),
                     w_up=normal(ks[1], (held, d, f)), w_down=normal(ks[2], (held, f, d), out_std),
-                    shared_up=normal(ks[3], (d, fs)), shared_down=normal(ks[4], (fs, d), out_std),
                 )
+                if fs:
+                    p.update(shared_up=normal(ks[3], (d, fs)),
+                             shared_down=normal(ks[4], (fs, d), out_std))
+                if self.gated_experts:
+                    p.update(w_gate=normal(ks[5], (held, d, f)))
+            elif kind == "C":
+                bound = self.conv_kernel ** -0.5
+                p.update(
+                    in_proj=normal(ks[0], (d, 3 * d)),          # [B | C | u]
+                    conv_w=jax.random.uniform(ks[1], (self.conv_kernel, d), dtype, -bound, bound),
+                    out_proj=normal(ks[2], (d, d), out_std),
+                )
+            elif kind == "F":
+                f = self.dense_width
+                p.update(w1=normal(ks[0], (d, f)), w3=normal(ks[1], (d, f)),
+                         w2=normal(ks[2], (f, d), out_std))
             else:
                 raise ValueError(f"pattern {self.pattern!r}: unknown layer kind {kind!r}")
             layers.append(p)
@@ -234,8 +272,9 @@ class HybridDecoderDef:
             "embed": normal(keys[-2], (self.vocab_size, d)),
             "layers": layers,
             "norm_f": jnp.ones((d,), dtype),
-            "head": normal(keys[-1], (d, self.vocab_size)),
         }
+        if not self.tied_head:
+            params["head"] = normal(keys[-1], (d, self.vocab_size))
         state = {"router_bias": jnp.zeros((self.n_expert_layers, self.n_experts), jnp.float32)}
         return params, state
 
@@ -266,11 +305,56 @@ class HybridDecoderDef:
         y = jnp.concatenate([rms_norm(1.0, part, self.eps) for part in jnp.split(y, g, axis=-1)], -1)
         return (y * p["gnorm"]).astype(dtype) @ p["out_proj"].astype(dtype)
 
+    def _short_conv(self, p, h, dtype):
+        """LFM2's gated short convolution: ``[B | C | u] = h W_in``, ``y = C *
+        conv_k(B * u)`` (depthwise, causal, no bias, no activation), ``y W_out``;
+        the chain between the two products in float32."""
+        s, k, f32 = h.shape[1], self.conv_kernel, jnp.float32
+        proj = h @ p["in_proj"].astype(dtype)
+        counters_lib.inc("conv.sites")
+        with jax.named_scope("conv/short"):
+            b, c, u = jnp.split(proj, 3, axis=-1)
+            v = jnp.pad(b.astype(f32) * u.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
+            w = sum(v[:, j:j + s] * p["conv_w"][j].astype(f32) for j in range(k))
+            y = (c.astype(f32) * w).astype(dtype)
+        return y @ p["out_proj"].astype(dtype)
+
+    def _dense_ffn(self, p, h, dtype):
+        """``(silu(h W_1) * (h W_3)) W_2``, the gate's product in float32."""
+        with jax.named_scope("ffn/dense"):
+            gate = (h @ p["w1"].astype(dtype)).astype(jnp.float32)
+            up = (h @ p["w3"].astype(dtype)).astype(jnp.float32)
+            return (jax.nn.silu(gate) * up).astype(dtype) @ p["w2"].astype(dtype)
+
+    def _norm_rotate(self, scale, x):
+        """A projection's heads ``x [B, S, heads, D]`` as attention takes
+        them: RMSNorm over each head's ``D`` channels (``qk_norm``), then the
+        rotation by position (``rope_theta``; channel ``i`` pairs with ``i +
+        D/2``, positions 0..S-1), in float32, back in ``x``'s dtype."""
+        xf = x.astype(jnp.float32)
+        if self.qk_norm:
+            xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + self.eps)
+            xf = xf * scale.astype(jnp.float32)
+        if self.rope_theta is not None:
+            half = x.shape[-1] // 2
+            inv = jnp.asarray(np.float32(self.rope_theta ** (-np.arange(half) / half)))
+            angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv   # [S, D/2]
+            cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[:, None, :]
+            sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[:, None, :]
+            x1, x2 = xf[..., :half], xf[..., half:]
+            xf = xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+        return xf.astype(x.dtype)
+
     def _attention(self, p, h, dtype, attn_impl):
         bsz, s, _ = h.shape
         q = (h @ p["wq"].astype(dtype)).reshape(bsz, s, self.attn_heads, self.attn_head_dim)
         k = (h @ p["wk"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
         v = (h @ p["wv"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
+        if self.qk_norm or self.rope_theta is not None:
+            counters_lib.inc("rope.sites")
+            with jax.named_scope("attn/rope"):
+                q = self._norm_rotate(p.get("q_norm"), q)
+                k = self._norm_rotate(p.get("k_norm"), k)
         with jax.named_scope("attn/causal"):
             o = attn_lib.attention(q, k, v, causal=True, impl=attn_impl)
         return o.reshape(bsz, s, -1) @ p["wo"].astype(dtype)
@@ -287,17 +371,24 @@ class HybridDecoderDef:
         with jax.named_scope("moe/route"):
             scores = self.router_scores(p, x)
             chosen, weights = expert_lib.choose_experts(
-                scores, bias, self.top_k, self.routed_scaling)
+                scores, bias, self.top_k, self.routed_scaling, self.topk_eps)
             load = jnp.zeros((self.n_experts,), jnp.float32).at[chosen.reshape(-1)].add(1.0)
+        gated = {}
+        if self.gated_experts:
+            counters_lib.inc("moe.sites_gated")
+            gated = {"w_gate": p["w_gate"].astype(dtype)}
         with jax.named_scope("moe/experts"):
             routed, rows = expert_lib.dropless_experts(
                 x, chosen, weights.astype(dtype), p["w_up"].astype(dtype),
                 p["w_down"].astype(dtype), held=self.experts_held,
-                capacity=self.buffer_rows(bsz * s), activation=_relu2,
+                capacity=self.buffer_rows(bsz * s),
+                activation=jax.nn.silu if self.gated_experts else _relu2, **gated,
             )
-        with jax.named_scope("moe/shared"):
-            shared = _relu2(x @ p["shared_up"].astype(dtype)) @ p["shared_down"].astype(dtype)
-        return (routed + shared).reshape(bsz, s, d), load, rows
+        if self.shared_width:
+            with jax.named_scope("moe/shared"):
+                shared = _relu2(x @ p["shared_up"].astype(dtype)) @ p["shared_down"].astype(dtype)
+            routed = routed + shared
+        return routed.reshape(bsz, s, d), load, rows
 
     def hidden_states(self, params, state, tokens, *, train: bool, compute_dtype,
                       attn_impl: Optional[str] = None, router_inputs: bool = False,
@@ -327,8 +418,10 @@ class HybridDecoderDef:
             else:
                 def f(p, x, kind=kind):
                     y = rms_norm(p["norm"], x, self.eps)
-                    return self._mixer(p, y, dtype) if kind == "M" else self._attention(
-                        p, y, dtype, attn_impl)
+                    if kind == "*":
+                        return self._attention(p, y, dtype, attn_impl)
+                    return {"M": self._mixer, "C": self._short_conv, "F": self._dense_ffn}[kind](
+                        p, y, dtype)
 
                 out = (jax.checkpoint(f) if remat else f)(p, x)
             x = x + out
@@ -347,13 +440,18 @@ class HybridDecoderDef:
 
     # -- the model's two entries ------------------------------------------------
 
+    def head_matrix(self, params, dtype):
+        """``[d, vocab]`` in ``dtype``: the head, or (``tied_head``) the
+        embedding read transposed, whose gradient then sums both uses."""
+        return (params["embed"].T if self.tied_head else params["head"]).astype(dtype)
+
     def apply(self, params, state, tokens, train: bool = False, axis_name=None,
               compute_dtype=jnp.float32, attn_impl: Optional[str] = None):
         """``[B, S]`` token ids to ``[B, S, vocab]`` float32 logits, all kept:
         for small shapes and tests; training goes through :meth:`loss`."""
         h, new_state, _ = self.hidden_states(
             params, state, tokens, train=train, compute_dtype=compute_dtype, attn_impl=attn_impl)
-        logits = jnp.einsum("bsd,dv->bsv", h, params["head"].astype(compute_dtype),
+        logits = jnp.einsum("bsd,dv->bsv", h, self.head_matrix(params, compute_dtype),
                             preferred_element_type=jnp.float32)
         return logits, new_state
 
@@ -371,7 +469,7 @@ class HybridDecoderDef:
         w = jnp.ones((b,), jnp.float32) if sample_weight is None else sample_weight
         with jax.named_scope("lm/head_loss"):
             nll, top1, top5 = F.blocked_cross_entropy(
-                h.reshape(b * s, d), params["head"].astype(compute_dtype),
+                h.reshape(b * s, d), self.head_matrix(params, compute_dtype),
                 targets.reshape(b * s), jnp.repeat(w.astype(jnp.float32), s),
                 block=self.head_block,
             )
@@ -434,4 +532,38 @@ def nemotron_h_tiny(num_classes: int = 0) -> HybridDecoderDef:
         chunk_size=8, attn_heads=4, kv_heads=2, attn_head_dim=8,
         n_experts=16, experts_held=(0, 4), top_k=2, expert_width=16, shared_width=32,
         head_block=16,
+    )
+
+
+def lfm2_24b_a2b_share(num_classes: int = 0) -> HybridDecoderDef:
+    """One chip's share of LiquidAI's LFM2-24B-A2B at its published widths:
+    layers 1-5 of 40 (the second dense layer, then one whole period of four
+    expert layers: ``conv | attention, conv, conv, conv``), a layer two blocks
+    (operator, feed-forward): ``CF *E CE CE CE``; experts 0-7 of 64 and 8,192
+    of 65,536 vocabulary rows, as one of 8 expert-parallel chips would hold
+    them; 469.3M parameters. The four `C` blocks and the first `E` block are
+    recomputed in the backward pass (``benchmarks/configs/lfm2_24b_a2b.json``,
+    ``remat``)."""
+    return HybridDecoderDef(
+        pattern="CF*ECECECE", vocab_size=8192, seq_len=8192, hidden=2048,
+        mamba_heads=0, mamba_head_dim=0, ssm_groups=0, ssm_state=0, conv_kernel=3, chunk_size=0,
+        attn_heads=32, kv_heads=8, attn_head_dim=64,
+        n_experts=64, experts_held=(0, 8), top_k=4, expert_width=1536, shared_width=0,
+        routed_scaling=1.0, dense_width=11776, gated_experts=True, qk_norm=True,
+        rope_theta=1e6, tied_head=True, topk_eps=1e-6, rescale_layers=40,
+        recompute=(0, 3, 4, 6, 8),
+    )
+
+
+def lfm2_moe_tiny(num_classes: int = 0) -> HybridDecoderDef:
+    """Every kind of LFM2 block at toy widths, for the CPU: 4 experts of 16
+    held, and a buffer for every pair (a toy router at a toy learning rate may
+    send them all here)."""
+    return HybridDecoderDef(
+        pattern="CF*ECE", vocab_size=64, seq_len=32, hidden=32,
+        mamba_heads=0, mamba_head_dim=0, ssm_groups=0, ssm_state=0, conv_kernel=3, chunk_size=0,
+        attn_heads=4, kv_heads=2, attn_head_dim=8,
+        n_experts=16, experts_held=(0, 4), top_k=2, expert_width=16, shared_width=0,
+        routed_scaling=1.0, dense_width=48, gated_experts=True, qk_norm=True,
+        rope_theta=1e6, tied_head=True, topk_eps=1e-6, head_block=16, capacity_factor=4.0,
     )

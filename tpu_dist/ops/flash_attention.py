@@ -723,7 +723,8 @@ LONG_SEQ = 2048    # from here on the tiles are LONG_BLOCK wide where that divid
 # 128-wide tiles spend a long sequence on grid steps and on rescaling the
 # accumulator once a k-block: forward + backward at S=8192, 32 heads on 2,
 # takes 66 ms at 256x512, 54 at 512x512, 43 at 512x1024, 40 at 1024x1024
-# (v5e, my chip run, PR 33)
+# (v5e, my chip run, PR 33); 64-wide heads choose the same (the docstring of
+# flash_attention has their readings, PR 35)
 LONG_BLOCK = 1024
 
 
@@ -741,10 +742,17 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int | None = None
     group inside the kernel. Tiles are 128 wide, and ``LONG_BLOCK`` wide from
     ``LONG_SEQ`` tokens on where that divides the sequence, unless given.
 
-    ``interpret=None`` auto-selects Pallas interpret mode off-TPU. Head
-    dim ``D`` should be a multiple of 128 lanes for peak MXU utilization
-    (64 works, at some padding cost). Sequence lengths are padded to the
-    block size internally and masked exactly.
+    ``interpret=None`` auto-selects Pallas interpret mode off-TPU. Heads of
+    64 channels run as whole 64-wide blocks at half the rate of 128-wide
+    ones: on the v5e, causal, 4 x 8,192 tokens, 32 query heads on 8, forward
+    22.2 ms and forward + backward 81.3 ms at 1,024 x 1,024 tiles (20% of
+    attention's roofline; 128-wide heads, 32 on 2, the same operations:
+    43%), because a 64-deep contraction (``q k^T``, ``dO v^T``) and a 64-wide
+    result (``p v``, ``ds^T q``, ``ds k``) each fill half of the 128 x 128
+    matrix unit. Smaller tiles are slower there too (512 x 1,024: 26.5 /
+    88.8 ms; 1,024 x 512: 41.4 / 103.9; 512 x 512: 43.2 / 110.8) and 2,048-wide
+    ones exceed VMEM (my chip run, PR 35). Sequence lengths are padded to
+    the block size internally and masked exactly.
 
     ``bwd``: ``'pallas'`` (default) runs the FlashAttention-2 backward as
     two tiled Pallas kernels (dK/dV pass + dQ pass); ``'xla'`` keeps the

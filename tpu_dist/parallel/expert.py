@@ -159,17 +159,21 @@ class MoE:
 # router scores every expert, and the chosen (token, expert) pairs whose
 # expert is held here are sorted by expert into one row buffer of static
 # size and multiplied tile by tile, every tile one expert's. What the absent
-# experts would add is left out, and no code stands in for them.
+# experts would add is left out, and no code stands in for them. An expert is
+# ``activation(v W_up) W_down`` or, with a gate matrix, the gated form
+# ``(activation(v W_gate) * (v W_up)) W_down`` (SwiGLU with ``silu``): two
+# grouped products into the same sorted rows, a third out.
 
 
-def choose_experts(scores, bias, top_k: int, scaling: float):
+def choose_experts(scores, bias, top_k: int, scaling: float, eps: float = 1e-20):
     """The ``top_k`` experts of each token by ``scores + bias`` (the bias
     moves the selection only), weighted by their scores renormalised over the
-    chosen ones times ``scaling``. ``scores [T, E]`` float32 in (0, 1).
+    chosen ones (``eps`` added to their sum: a model publishes its own) times
+    ``scaling``. ``scores [T, E]`` float32 in (0, 1).
     Returns ``(chosen [T, k] int32, weights [T, k] float32)``."""
     _, chosen = lax.top_k(scores + bias, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    return chosen, scaling * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen, scaling * picked / (picked.sum(-1, keepdims=True) + eps)
 
 
 GROUP_TILE = 512  # rows of one grouped-product tile: every tile belongs to one expert
@@ -223,11 +227,14 @@ def _grouped_matmul_bwd(transpose, res, dy):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def dropless_experts(x, chosen, weights, w_up, w_down, *, held, capacity: int, activation):
+def dropless_experts(x, chosen, weights, w_up, w_down, *, held, capacity: int, activation,
+                     w_gate=None):
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(x[t])`` over the chosen
-    experts that are held, ``expert_e(v) = activation(v w_up[e]) w_down[e]``.
+    experts that are held, ``expert_e(v) = activation(v w_up[e]) w_down[e]``
+    or, given ``w_gate``, ``(activation(v w_gate[e]) * (v w_up[e])) w_down[e]``.
 
-    ``x [T, d]``; ``w_up [count, d, f]``, ``w_down [count, f, d]`` for the
+    ``x [T, d]``; ``w_up`` (and ``w_gate``) ``[count, d, f]``, ``w_down
+    [count, f, d]`` for the
     experts ``first .. first + count - 1``. The pairs whose expert is held
     are sorted by expert into a row buffer, each expert's rows padded to
     whole tiles of ``GROUP_TILE`` rows, and multiplied tile by tile
@@ -262,7 +269,12 @@ def dropless_experts(x, chosen, weights, w_up, w_down, *, held, capacity: int, a
     token = pair // k
     shape = (n_tiles, tile, -1)
     rows = jnp.where(valid, x[token], 0).reshape(shape)
-    h = activation(grouped_matmul(rows, w_up, tile_expert, tile_ends[-1]))
+    h = grouped_matmul(rows, w_up, tile_expert, tile_ends[-1])
+    if w_gate is None:
+        h = activation(h)
+    else:
+        gate = grouped_matmul(rows, w_gate, tile_expert, tile_ends[-1])
+        h = (activation(gate.astype(jnp.float32)) * h.astype(jnp.float32)).astype(x.dtype)
     y = grouped_matmul(h, w_down, tile_expert, tile_ends[-1]).reshape(n_tiles * tile, -1)
     y = jnp.where(valid, y.astype(jnp.float32) * weights.reshape(-1)[pair][:, None], 0)
     out = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(y)

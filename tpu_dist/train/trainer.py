@@ -103,12 +103,16 @@ def build_model(cfg: TrainConfig):
         _MODELS.setdefault("resnet50_imagenet", resnet50_imagenet)
 
         from tpu_dist.nn.nemotron_h import (  # noqa: PLC0415
+            lfm2_24b_a2b_share,
+            lfm2_moe_tiny,
             nemotron3_nano_share,
             nemotron_h_tiny,
         )
 
         _MODELS.setdefault("nemotron3_nano_share", nemotron3_nano_share)
         _MODELS.setdefault("nemotron_h_tiny", nemotron_h_tiny)
+        _MODELS.setdefault("lfm2_24b_a2b_share", lfm2_24b_a2b_share)
+        _MODELS.setdefault("lfm2_moe_tiny", lfm2_moe_tiny)
     except ImportError:
         pass
     if cfg.model not in _MODELS:
