@@ -15,9 +15,11 @@ made from a seed:
   shards on every chip; one ``make_train_step`` step on a 1-device mesh
   against all devices (fp32, same global batch); one ``--sp N
   --flash_attention`` step (ppermute + Pallas inside ``shard_map``).
-* ``fused_sgd`` / ``flash_kernels`` / ``vit_b16_flash_step`` — every Pallas
-  kernel compiled (``interpret=False``) and compared with its jnp/XLA
-  reference; ViT-B/16 at 224 px takes its steps through ``bench.run``.
+* ``fused_sgd`` / ``flash_kernels`` / ``gmm_kernel`` / ``vit_b16_flash_step``
+  — every Pallas kernel compiled (``interpret=False``) and compared with its
+  jnp/XLA reference (the experts' grouped product inside ``dropless_experts``
+  at the two token cells' shapes); ViT-B/16 at 224 px takes its steps
+  through ``bench.run``.
 
 It prints one ``PASS``/``FAIL <phase>: <reason>`` line per phase and, as the
 last line of stdout, ``{"ok": true, "device": {...}}`` — only when every
@@ -58,6 +60,12 @@ FLASH_SHAPES = [
 REHEARSAL_FLASH_SHAPES = [(1, 197, 2, 64, False), (1, 256, 2, 128, True),
                           (2, 65, 2, 64, False)]
 FLASH_TOL = 2e-2  # bf16 inputs/outputs: max error over the reference's max
+# (name, tokens, hidden, expert width, experts, top-k, buffer rows, gated): the
+# expert layers of lfm2_24b_a2b_share and nemotron3_nano_share, 8 experts held
+GMM_SHAPES = [("lfm2", 32768, 2048, 1536, 64, 4, 32768, True),
+              ("nemotron", 16384, 2688, 1856, 128, 6, 12288, False)]
+REHEARSAL_GMM_SHAPES = [("toy_gated", 1024, 256, 384, 16, 2, 2048, True),
+                        ("toy_whole_width", 1024, 256, 464, 16, 2, 2048, False)]
 
 
 class SmokeFailure(Exception):
@@ -459,6 +467,63 @@ def phase_vit_b16_flash_step(ctx: dict) -> str:
             + spy.check_compiled(ctx["on_tpu"]))
 
 
+def phase_gmm_kernel(ctx: dict) -> str:
+    """The experts' grouped product (``ops/grouped_matmul.py``) inside
+    ``dropless_experts`` at the two token cells' shapes, bf16: the layer's
+    value and its gradients to the rows and to every expert matrix, the
+    kernel pair compiled against the XLA loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_dist.obs import counters
+    from tpu_dist.parallel import expert
+
+    rule = expert.takes_gmm_kernel
+    notes = []
+    with PallasSpy() as spy:
+        for name, tokens, d, f, n_experts, top_k, capacity, gated in ctx["gmm_shapes"]:
+            ks = jax.random.split(jax.random.PRNGKey(d), 6)
+            x = jax.random.normal(ks[0], (tokens, d), jnp.bfloat16)
+            chosen = jax.vmap(lambda k: jax.random.permutation(k, n_experts)[:top_k])(
+                jax.random.split(ks[1], tokens))
+            weights = jax.random.uniform(ks[2], (tokens, top_k)).astype(jnp.bfloat16)
+            ws = [(jax.random.normal(k, shape) * shape[1] ** -0.5).astype(jnp.bfloat16)
+                  for k, shape in zip(ks[3:], [(8, d, f), (8, f, d)] + [(8, d, f)] * gated)]
+
+            def value_and_grads(x, *ws):
+                def loss(x, *ws):
+                    out, rows = expert.dropless_experts(
+                        x, chosen, weights, *ws[:2], held=(0, 8), capacity=capacity,
+                        activation=jax.nn.silu, **({"w_gate": ws[2]} if gated else {}))
+                    return jnp.sum(out.astype(jnp.float32) ** 2), (out, rows)
+                (_, (out, rows)), grads = jax.value_and_grad(
+                    loss, argnums=tuple(range(1 + len(ws))), has_aux=True)(x, *ws)
+                return (out, *grads), rows
+
+            try:
+                if not ctx["on_tpu"]:  # the rehearsal: forced, and interpreted
+                    expert.takes_gmm_kernel = lambda *a: True
+                before = counters.get("moe.sites_gmm_xla")
+                kernel, rows = jax.jit(value_and_grads)(x, *ws)
+                check(counters.get("moe.sites_gmm_xla") == before,
+                      f"{name}: a product of the layer took the XLA loop")
+                expert.takes_gmm_kernel = lambda *a: False
+                loop, _ = jax.jit(value_and_grads)(x, *ws)
+            finally:
+                expert.takes_gmm_kernel = rule
+            check(int(rows["rows_over_cap"]) == 0, f"{name}: rows over the buffer")
+            errs = []
+            for what, k, l in zip(("out", "dx", "dw_up", "dw_down", "dw_gate"), kernel, loop):
+                check(bool(jnp.all(jnp.isfinite(k.astype(jnp.float32)))), f"{name}: {what} not finite")
+                check(k.shape == l.shape and k.dtype == l.dtype, f"{name}: {what} is {k.dtype}{k.shape}")
+                errs.append(_nerr(k, l))
+                check(errs[-1] <= FLASH_TOL / 2, f"{name}: {what} off the loop's by {errs[-1]:.3e}")
+            notes.append(f"{name} {int(rows['rows_live'])} live rows, {len(errs)} arrays, "
+                         f"worst {max(errs):.1e}")
+    return ("dropless_experts, kernel pair vs XLA loop, max normalized difference: "
+            + "; ".join(notes) + "; " + spy.check_compiled(ctx["on_tpu"]))
+
+
 def phase_token_step(ctx: dict) -> str:
     """The token path end to end at toy widths: one epoch of the tiny hybrid
     decoder (mixer, expert layer, causal grouped attention) through
@@ -520,24 +585,24 @@ def main(argv=None) -> int:
         print("chip_smoke: REHEARSAL at cut sizes — this run proves nothing "
               "about the chip", flush=True)
         size = {"batch": 32, "steps": 2, "synthetic_n": 256, "vit_batch": 1}
-        shapes = REHEARSAL_FLASH_SHAPES
+        shapes, gmm_shapes = REHEARSAL_FLASH_SHAPES, REHEARSAL_GMM_SHAPES
     elif not on_tpu:
         print(f"FAIL device: platform is {dev.platform!r}, not 'tpu' "
               f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})", flush=True)
         return 2
     else:
         size = {"batch": 256, "steps": 4, "synthetic_n": 2048, "vit_batch": None}
-        shapes = FLASH_SHAPES
+        shapes, gmm_shapes = FLASH_SHAPES, GMM_SHAPES
 
     phases = [phase_train, phase_resume, phase_placement]
     if device["count"] > 1:
         phases += [phase_dp_equivalence, phase_ring_flash]
-    phases += [phase_fused_sgd, phase_flash_kernels, phase_vit_b16_flash_step,
-               phase_token_step]
+    phases += [phase_fused_sgd, phase_flash_kernels, phase_gmm_kernel,
+               phase_vit_b16_flash_step, phase_token_step]
 
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
-    ctx = {"size": size, "flash_shapes": shapes, "workdir": workdir,
-           "on_tpu": on_tpu}
+    ctx = {"size": size, "flash_shapes": shapes, "gmm_shapes": gmm_shapes,
+           "workdir": workdir, "on_tpu": on_tpu}
     failed = []
     try:
         for phase in phases:

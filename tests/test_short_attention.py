@@ -7,7 +7,9 @@ the ViT-B/16 block for a described (not attached) ``v5e:2x2`` and is skipped
 where that topology cannot be described. It is the only test file that loads
 the TPU compiler: keep it that way (one process holds the library's lock),
 which is why the scan kernel's compile-only test (``ops/ssm_scan.py``; its
-other tests are ``tests/test_ssm_scan_kernel.py``) stands at the end of it.
+other tests are ``tests/test_ssm_scan_kernel.py``) stands at the end of it, and
+after it the experts' grouped product's (``ops/grouped_matmul.py``; its other
+tests are ``tests/test_grouped_matmul_kernel.py``).
 """
 
 import os
@@ -402,3 +404,96 @@ def test_scan_kernel_pair_compiles_for_v5e_and_keeps_one_state_array(monkeypatch
     einsums = _scan_program(one_chip, decoder.ssm_scan)
     assert len(_state_sized_float32(einsums)) >= 2
     assert einsums.memory_analysis().temp_size_in_bytes > 0.9e9
+
+
+# -- compile only: the experts' grouped product (ops/grouped_matmul.py) -----------
+
+# (tiles, hidden, expert width): the expert layers of lfm2_24b_a2b_share and
+# nemotron3_nano_share, 512-row tiles, 8 experts held
+_GMM_CELLS = {"lfm2": (72, 2048, 1536), "nemotron_1856_whole": (32, 2688, 1856)}
+
+
+@pytest.mark.parametrize("tiles,d,f", list(_GMM_CELLS.values()), ids=list(_GMM_CELLS))
+def test_grouped_matmul_kernel_pair_compiles_for_v5e_at_the_token_cells_shapes(one_chip, tiles, d, f):
+    """What interpret mode cannot show: Mosaic takes the product, its
+    transposed form and the weight gradient at both cells' shapes in bf16,
+    up and down (a width of 1856 = 14.5 x 128 as one whole block, the 20 to
+    70 MB a step holds under ``vmem_limit_bytes``)."""
+    from tpu_dist.ops import grouped_matmul as G
+
+    assert G.fits(512, d, f, jnp.bfloat16)
+    place = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    te, n_live = place((tiles,), jnp.int32), place((), jnp.int32)
+
+    def loss(x, w_up, w_down, te, n_live):
+        # up and down through the kernels' own custom_vjp-free calls: the
+        # product, the transposed product and the weight gradient of each
+        h = G.gmm(x, w_up, te, n_live, interpret=False)
+        y = G.gmm(h, w_down, te, n_live, interpret=False)
+        dh = G.gmm(y, w_down, te, n_live, True, interpret=False)
+        dx = G.gmm(dh, w_up, te, n_live, True, interpret=False)
+        dw_down = G.tgmm(h, y, te, n_live, 8, jnp.bfloat16, interpret=False)
+        dw_up = G.tgmm(x, dh, te, n_live, 8, jnp.bfloat16, interpret=False)
+        return dx, dw_up, dw_down
+
+    text = jax.jit(loss).lower(
+        place((tiles, 512, d)), place((8, d, f)), place((8, f, d)), te, n_live).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+
+
+_SLAB_UPDATE_IN_A_LOOP = re.compile(
+    r"= f32\[8,(?:2048,1536|1536,2048)\]\S* dynamic-update-slice\(.*op_name=\"[^\"]*while/body")
+
+
+def test_v5e_expert_layer_gradient_keeps_no_slab_update_in_a_loop(monkeypatch, one_chip):
+    """``dropless_experts`` at the LFM2 share's shapes, loss and gradient,
+    compiled for one v5e chip: with the kernel pair the program's loops hold
+    no ``f32[8,2048,1536]`` ``dynamic-update-slice`` (the XLA loop's weight
+    gradient, a slab read and written a tile), with the loop they do."""
+    from tpu_dist.parallel import expert as E
+
+    t, d, f, k = 4 * 8192, 2048, 1536, 4
+    place = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (place((t, d)), place((t, k), jnp.int32), place((t, k)),
+            place((8, d, f)), place((8, f, d)), place((8, d, f)))
+
+    def loss(x, chosen, weights, w_up, w_down, w_gate):
+        out, _ = E.dropless_experts(x, chosen, weights, w_up, w_down, held=(0, 8),
+                                    capacity=t, activation=jax.nn.silu, w_gate=w_gate)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def slab_updates_in_loops(on_tpu):
+        monkeypatch.setattr(E, "_on_tpu", lambda: on_tpu)
+        text = jax.jit(jax.grad(loss, argnums=(0, 3, 4, 5))).lower(*args).compile().as_text()
+        return (text.count('custom_call_target="tpu_custom_call"'),
+                len(_SLAB_UPDATE_IN_A_LOOP.findall(text)))
+
+    assert slab_updates_in_loops(True) == (9, 0)  # three products: gmm, its dx, tgmm
+    calls, in_loops = slab_updates_in_loops(False)
+    assert calls == 0 and in_loops >= 3
+
+
+def test_v5e_expert_layer_reads_an_1856_wide_matrix_as_the_chip_keeps_it(monkeypatch, one_chip):
+    """The Nemotron share's ``w_up`` is ``f32[8,2688,1856]``, which the v5e
+    keeps with 2688 minor (1856 is no multiple of 128). The kernels take its
+    transpose read transposed, the same bytes, so that the layer's gradient
+    program copies no array of that shape (row-major operands cost one copy
+    of the weights here, and of gradient and moments in the step: 9.2 ms)."""
+    from tpu_dist.parallel import expert as E
+
+    t, d, f, k = 2 * 8192, 2688, 1856, 6
+    place = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (place((t, d)), place((t, k), jnp.int32), place((t, k)),
+            place((8, d, f), jnp.float32), place((8, f, d), jnp.float32))
+
+    def loss(x, chosen, weights, w_up, w_down):
+        out, _ = E.dropless_experts(
+            x, chosen, weights, w_up.astype(jnp.bfloat16), w_down.astype(jnp.bfloat16),
+            held=(0, 8), capacity=12288, activation=lambda v: jnp.square(jax.nn.relu(v)))
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    monkeypatch.setattr(E, "_on_tpu", lambda: True)
+    text = jax.jit(jax.grad(loss, argnums=(0, 3, 4))).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert "f32[8,2688,1856]{1,2,0" in text  # w_up as the chip keeps it
+    assert not re.findall(r"copy[.\d]* = \w+\[8,(?:2688,1856|1856,2688)\]", text)

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpu_dist.obs import counters as counters_lib
+
 
 @dataclass(frozen=True)
 class MoE:
@@ -158,7 +160,9 @@ class MoE:
 # holds (``held = (first, count)`` of a wider deployment's experts): the
 # router scores every expert, and the chosen (token, expert) pairs whose
 # expert is held here are sorted by expert into one row buffer of static
-# size and multiplied tile by tile, every tile one expert's. What the absent
+# size and multiplied tile by tile, every tile one expert's: on a TPU, at
+# shapes its blocks fit, by the Pallas kernel pair of ops/grouped_matmul.py,
+# elsewhere by a loop of XLA dots (``grouped_matmul``). What the absent
 # experts would add is left out, and no code stands in for them. An expert is
 # ``activation(v W_up) W_down`` or, with a gate matrix, the gated form
 # ``(activation(v W_gate) * (v W_up)) W_down`` (SwiGLU with ``silu``): two
@@ -179,6 +183,22 @@ def choose_experts(scores, bias, top_k: int, scaling: float, eps: float = 1e-20)
 GROUP_TILE = 512  # rows of one grouped-product tile: every tile belongs to one expert
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def takes_gmm_kernel(rows: int, a: int, b: int, dtype) -> bool:
+    """Where :func:`grouped_matmul` takes the Pallas kernel pair of
+    ``ops/grouped_matmul.py``: a TPU, and tiles of ``rows`` rows against
+    ``[a, b]`` matrices that its blocks fit (whole 128-row tiles; widths in
+    128-lane blocks, or whole)."""
+    if not _on_tpu():
+        return False
+    from tpu_dist.ops.grouped_matmul import fits  # noqa: PLC0415
+
+    return fits(rows, a, b, dtype)
+
+
 def _tile_dot(x, w, transpose: bool):
     """``x [rows, a]`` times one expert's ``w`` (``[a, b]``, or ``[b, a]`` read
     transposed), accumulated in float32, back in ``x``'s dtype."""
@@ -190,10 +210,27 @@ def _tile_dot(x, w, transpose: bool):
 def grouped_matmul(x, w, tile_expert, n_live, transpose=False):
     """``x [tiles, rows, a]`` times, tile by tile, the expert matrix
     ``w[tile_expert[t]]`` of ``w [experts, a, b]`` (``[experts, b, a]`` with
-    ``transpose``): one loop over the tiles, an XLA dot a tile, the expert's
-    matrix read in place. Tiles from ``n_live`` on are skipped and give
-    zeros. The gradients are the same loop: ``dx`` against the transposed
-    matrices, ``dw`` summed into its expert's slab tile by tile."""
+    ``transpose``), the expert's matrix read in place. Tiles from ``n_live``
+    on are skipped and give zeros, whatever their rows hold. The gradients:
+    ``dx`` is the same product against the transposed matrices, ``dw`` is
+    summed in float32 over all of an expert's tiles and cast once.
+
+    One contract, two realisations, chosen by what is seen here
+    (:func:`takes_gmm_kernel`): on a TPU, at shapes its blocks fit, the
+    kernel pair of ``ops/grouped_matmul.py``, told each tile's expert by
+    scalar prefetch: a tile's output written once, an expert's weight
+    gradient summed in VMEM and written once (``moe.sites_gmm_kernel``
+    counts the products traced, the backward's ``dx`` one of them).
+    Anything else (``moe.sites_gmm_xla``) is one loop over the tiles, an XLA
+    dot a tile, ``dw`` summed into its expert's slab tile by tile."""
+    a, b = (w.shape[2], w.shape[1]) if transpose else w.shape[1:]
+    if takes_gmm_kernel(x.shape[1], a, b, x.dtype):
+        from tpu_dist.ops.grouped_matmul import gmm  # noqa: PLC0415
+
+        counters_lib.inc("moe.sites_gmm_kernel")
+        return gmm(x, w, tile_expert, n_live, transpose, interpret=not _on_tpu())
+    counters_lib.inc("moe.sites_gmm_xla")
+
     def tile(args):
         t, x_t, e = args
         return lax.cond(
@@ -212,6 +249,11 @@ def _grouped_matmul_fwd(x, w, tile_expert, n_live, transpose):
 def _grouped_matmul_bwd(transpose, res, dy):
     x, w, tile_expert, n_live = res
     dx = grouped_matmul(dy, w, tile_expert, n_live, not transpose)
+    if takes_gmm_kernel(x.shape[1], x.shape[2], dy.shape[2], x.dtype):
+        from tpu_dist.ops.grouped_matmul import tgmm  # noqa: PLC0415
+
+        dw = tgmm(x, dy, tile_expert, n_live, w.shape[0], w.dtype, transpose, interpret=not _on_tpu())
+        return dx, dw, None, None
 
     def tile(dw, args):
         t, x_t, dy_t, e = args
