@@ -57,6 +57,7 @@ from jax import lax
 from tpu_dist.nn import attention as attn_lib
 from tpu_dist.nn import functional as F
 from tpu_dist.obs import counters as counters_lib
+from tpu_dist.obs import hlo_scopes
 from tpu_dist.parallel import expert as expert_lib
 
 
@@ -284,44 +285,51 @@ class HybridDecoderDef:
         bsz, s, _ = h.shape
         heads, hp, g, n = self.mamba_heads, self.mamba_head_dim, self.ssm_groups, self.ssm_state
         inner, k = self.mamba_inner, self.conv_kernel
-        proj = h @ p["in_proj"].astype(dtype)
-        gate, xbc, dt = jnp.split(proj, [inner, inner + self.conv_dim], axis=-1)
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-        conv = sum(padded[:, i:i + s] * p["conv_w"][i] for i in range(k)) + p["conv_b"]
-        xbc = jax.nn.silu(conv).astype(dtype)
-        x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        with hlo_scopes.scope("ssm/in_proj"):
+            proj = h @ p["in_proj"].astype(dtype)
+            gate, xbc, dt = jnp.split(proj, [inner, inner + self.conv_dim], axis=-1)
+        with hlo_scopes.scope("ssm/conv1d"):
+            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+            conv = sum(padded[:, i:i + s] * p["conv_w"][i] for i in range(k)) + p["conv_b"]
+            xbc = jax.nn.silu(conv).astype(dtype)
+            x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
         # Around the scan everything stays [B, S, inner], the layout the
         # projections and the scan kernel share: on the TPU a [.., heads, 64]
         # or [.., groups, inner/G] view of it is another tiling, and XLA
         # copies the whole array to get there and back (PERF.md, PR 34).
-        with jax.named_scope("ssm/scan"):
+        with hlo_scopes.scope("ssm/scan"):
             y = ssm_scan(x.reshape(bsz, s, heads, hp), dt, -jnp.exp(p["A_log"].astype(jnp.float32)),
                          b.reshape(bsz, s, g, n), c.reshape(bsz, s, g, n), self.chunk_size)
             skip = jnp.repeat(p["D"].astype(jnp.float32), hp)    # a head's D over its channels
             y = y.reshape(bsz, s, inner) + (skip * x).astype(dtype)
         # gate before the norm; the norm is over groups of inner/G channels
-        y = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
-        y = jnp.concatenate([rms_norm(1.0, part, self.eps) for part in jnp.split(y, g, axis=-1)], -1)
-        return (y * p["gnorm"]).astype(dtype) @ p["out_proj"].astype(dtype)
+        with hlo_scopes.scope("ssm/gate_norm"):
+            y = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+            y = jnp.concatenate([rms_norm(1.0, part, self.eps) for part in jnp.split(y, g, axis=-1)], -1)
+            y = (y * p["gnorm"]).astype(dtype)
+        with hlo_scopes.scope("ssm/out_proj"):
+            return y @ p["out_proj"].astype(dtype)
 
     def _short_conv(self, p, h, dtype):
         """LFM2's gated short convolution: ``[B | C | u] = h W_in``, ``y = C *
         conv_k(B * u)`` (depthwise, causal, no bias, no activation), ``y W_out``;
         the chain between the two products in float32."""
         s, k, f32 = h.shape[1], self.conv_kernel, jnp.float32
-        proj = h @ p["in_proj"].astype(dtype)
+        with hlo_scopes.scope("conv/in_proj"):
+            proj = h @ p["in_proj"].astype(dtype)
         counters_lib.inc("conv.sites")
-        with jax.named_scope("conv/short"):
+        with hlo_scopes.scope("conv/short"):
             b, c, u = jnp.split(proj, 3, axis=-1)
             v = jnp.pad(b.astype(f32) * u.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
             w = sum(v[:, j:j + s] * p["conv_w"][j].astype(f32) for j in range(k))
             y = (c.astype(f32) * w).astype(dtype)
-        return y @ p["out_proj"].astype(dtype)
+        with hlo_scopes.scope("conv/out_proj"):
+            return y @ p["out_proj"].astype(dtype)
 
     def _dense_ffn(self, p, h, dtype):
         """``(silu(h W_1) * (h W_3)) W_2``, the gate's product in float32."""
-        with jax.named_scope("ffn/dense"):
+        with hlo_scopes.scope("ffn/dense"):
             gate = (h @ p["w1"].astype(dtype)).astype(jnp.float32)
             up = (h @ p["w3"].astype(dtype)).astype(jnp.float32)
             return (jax.nn.silu(gate) * up).astype(dtype) @ p["w2"].astype(dtype)
@@ -347,17 +355,19 @@ class HybridDecoderDef:
 
     def _attention(self, p, h, dtype, attn_impl):
         bsz, s, _ = h.shape
-        q = (h @ p["wq"].astype(dtype)).reshape(bsz, s, self.attn_heads, self.attn_head_dim)
-        k = (h @ p["wk"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
-        v = (h @ p["wv"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
+        with hlo_scopes.scope("attn/qkv"):
+            q = (h @ p["wq"].astype(dtype)).reshape(bsz, s, self.attn_heads, self.attn_head_dim)
+            k = (h @ p["wk"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
+            v = (h @ p["wv"].astype(dtype)).reshape(bsz, s, self.kv_heads, self.attn_head_dim)
         if self.qk_norm or self.rope_theta is not None:
             counters_lib.inc("rope.sites")
-            with jax.named_scope("attn/rope"):
+            with hlo_scopes.scope("attn/rope"):
                 q = self._norm_rotate(p.get("q_norm"), q)
                 k = self._norm_rotate(p.get("k_norm"), k)
-        with jax.named_scope("attn/causal"):
+        with hlo_scopes.scope("attn/causal"):
             o = attn_lib.attention(q, k, v, causal=True, impl=attn_impl)
-        return o.reshape(bsz, s, -1) @ p["wo"].astype(dtype)
+        with hlo_scopes.scope("attn/out"):
+            return o.reshape(bsz, s, -1) @ p["wo"].astype(dtype)
 
     def router_scores(self, p, h):
         """``sigmoid(h W_r)`` over all experts in float32, ``h [T, d]``."""
@@ -368,7 +378,7 @@ class HybridDecoderDef:
     def _experts(self, p, bias, h, dtype):
         bsz, s, d = h.shape
         x = h.reshape(bsz * s, d)
-        with jax.named_scope("moe/route"):
+        with hlo_scopes.scope("moe/route"):
             scores = self.router_scores(p, x)
             chosen, weights = expert_lib.choose_experts(
                 scores, bias, self.top_k, self.routed_scaling, self.topk_eps)
@@ -377,7 +387,7 @@ class HybridDecoderDef:
         if self.gated_experts:
             counters_lib.inc("moe.sites_gated")
             gated = {"w_gate": p["w_gate"].astype(dtype)}
-        with jax.named_scope("moe/experts"):
+        with hlo_scopes.scope("moe/experts"):
             routed, rows = expert_lib.dropless_experts(
                 x, chosen, weights.astype(dtype), p["w_up"].astype(dtype),
                 p["w_down"].astype(dtype), held=self.experts_held,
@@ -385,7 +395,7 @@ class HybridDecoderDef:
                 activation=jax.nn.silu if self.gated_experts else _relu2, **gated,
             )
         if self.shared_width:
-            with jax.named_scope("moe/shared"):
+            with hlo_scopes.scope("moe/shared"):
                 shared = _relu2(x @ p["shared_up"].astype(dtype)) @ p["shared_down"].astype(dtype)
             routed = routed + shared
         return routed.reshape(bsz, s, d), load, rows
@@ -400,14 +410,17 @@ class HybridDecoderDef:
         loop outside the model). ``axis_name``: the mesh axes the batch is
         split over; the balancing rule then moves by the whole batch's load."""
         dtype = compute_dtype
-        x = params["embed"].astype(dtype)[tokens]
+        with hlo_scopes.scope("lm/embed"):
+            x = params["embed"].astype(dtype)[tokens]
         bias = state["router_bias"]
         loads, rows, seen = [], [], []
         for depth, (kind, p) in enumerate(zip(self.pattern, params["layers"])):
             remat = train and (self.recompute is None or depth in self.recompute)
             if kind == "E":
                 def f(p, x, b):
-                    return self._experts(p, b, rms_norm(p["norm"], x, self.eps), dtype)
+                    with hlo_scopes.scope("block/norm"):
+                        y = rms_norm(p["norm"], x, self.eps)
+                    return self._experts(p, b, y, dtype)
 
                 if router_inputs:
                     seen.append(rms_norm(p["norm"], x, self.eps).reshape(-1, x.shape[-1]))
@@ -417,14 +430,16 @@ class HybridDecoderDef:
                 rows.append(n_rows)
             else:
                 def f(p, x, kind=kind):
-                    y = rms_norm(p["norm"], x, self.eps)
+                    with hlo_scopes.scope("block/norm"):
+                        y = rms_norm(p["norm"], x, self.eps)
                     if kind == "*":
                         return self._attention(p, y, dtype, attn_impl)
                     return {"M": self._mixer, "C": self._short_conv, "F": self._dense_ffn}[kind](
                         p, y, dtype)
 
                 out = (jax.checkpoint(f) if remat else f)(p, x)
-            x = x + out
+            with hlo_scopes.scope("block/residual"):
+                x = x + out
         loads = jnp.stack(loads) if loads else jnp.zeros((0, self.n_experts), jnp.float32)
         stats = expert_lib.load_stats(loads, rows, self.experts_held)
         new_state = state
@@ -435,7 +450,9 @@ class HybridDecoderDef:
             # the mean load, lower it above; selection only, no gradient
             mean = loads.mean(axis=-1, keepdims=True)
             new_state = {"router_bias": bias + self.bias_rate * jnp.sign(mean - loads)}
-        out = (rms_norm(params["norm_f"], x, self.eps), new_state, stats)
+        with hlo_scopes.scope("lm/final_norm"):
+            h = rms_norm(params["norm_f"], x, self.eps)
+        out = (h, new_state, stats)
         return out + (seen,) if router_inputs else out
 
     # -- the model's two entries ------------------------------------------------
@@ -467,7 +484,7 @@ class HybridDecoderDef:
             attn_impl=attn_impl, axis_name=axis_name)
         b, s, d = h.shape
         w = jnp.ones((b,), jnp.float32) if sample_weight is None else sample_weight
-        with jax.named_scope("lm/head_loss"):
+        with hlo_scopes.scope("lm/head_loss"):
             nll, top1, top5 = F.blocked_cross_entropy(
                 h.reshape(b * s, d), self.head_matrix(params, compute_dtype),
                 targets.reshape(b * s), jnp.repeat(w.astype(jnp.float32), s),

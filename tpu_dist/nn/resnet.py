@@ -21,6 +21,17 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist.nn import layers as L
+from tpu_dist.obs import hlo_scopes
+
+
+# one literal a stage, so that the table in obs/hlo_scopes.py and the test that
+# holds the sites to it can read them
+_STAGE_SCOPES = (
+    lambda: hlo_scopes.scope("resnet/stage1"),
+    lambda: hlo_scopes.scope("resnet/stage2"),
+    lambda: hlo_scopes.scope("resnet/stage3"),
+    lambda: hlo_scopes.scope("resnet/stage4"),
+)
 
 
 @dataclass(frozen=True)
@@ -126,32 +137,35 @@ class ResNetDef:
         bn = dict(train=train, axis_name=axis_name)
         new_state = {}
 
-        if self.imagenet_stem:
-            if self.s2d_stem:
-                y = self._stem_s2d(params["stem_conv"]["w"], x)
+        with hlo_scopes.scope("resnet/stem"):
+            if self.imagenet_stem:
+                if self.s2d_stem:
+                    y = self._stem_s2d(params["stem_conv"]["w"], x)
+                else:
+                    y = L.conv_apply(params["stem_conv"], x, stride=2, padding=3)
             else:
-                y = L.conv_apply(params["stem_conv"], x, stride=2, padding=3)
-        else:
-            y = L.conv_apply(params["stem_conv"], x, stride=1, padding=1)
-        y, new_state["stem_bn"] = L.bn_apply(params["stem_bn"], state["stem_bn"], y, **bn)
-        y = L.relu(y)
-        if self.imagenet_stem:
-            y = jax.lax.reduce_window(
-                y, -jnp.inf, jax.lax.max,
-                (1, 3, 3, 1), (1, 2, 2, 1), [(0, 0), (1, 1), (1, 1), (0, 0)],
-            )
+                y = L.conv_apply(params["stem_conv"], x, stride=1, padding=1)
+            y, new_state["stem_bn"] = L.bn_apply(params["stem_bn"], state["stem_bn"], y, **bn)
+            y = L.relu(y)
+            if self.imagenet_stem:
+                y = jax.lax.reduce_window(
+                    y, -jnp.inf, jax.lax.max,
+                    (1, 3, 3, 1), (1, 2, 2, 1), [(0, 0), (1, 1), (1, 1), (0, 0)],
+                )
 
-        for si in range(4):
+        for si, stage in enumerate(_STAGE_SCOPES):
             name = f"stage{si + 1}"
             stage_state = []
-            for bp, bs in zip(params[name], state[name]):
-                stride = (1, 2, 2, 2)[si] if not stage_state else 1
-                y, ns = self._block_apply(bp, bs, y, stride, bn)
-                stage_state.append(ns)
+            with stage():
+                for bp, bs in zip(params[name], state[name]):
+                    stride = (1, 2, 2, 2)[si] if not stage_state else 1
+                    y, ns = self._block_apply(bp, bs, y, stride, bn)
+                    stage_state.append(ns)
             new_state[name] = stage_state
 
-        y = L.global_avg_pool(y)
-        logits = L.linear_apply(params["fc"], y)
+        with hlo_scopes.scope("resnet/head"):
+            y = L.global_avg_pool(y)
+            logits = L.linear_apply(params["fc"], y)
         return logits, new_state
 
     @staticmethod

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from tpu_dist.nn import attention as attn_lib
+from tpu_dist.obs import hlo_scopes
 
 
 def _ln_init(dim):
@@ -67,12 +68,17 @@ def block_forward(blk, t, heads: int, attn_impl: Optional[str] = None):
     Shared by ViTDef's sequential path and the pipeline-parallel wrapper.
     ``attn_impl`` pins the attention implementation at build time (None =
     process default at trace time)."""
-    y = _ln_apply(blk["ln1"], t)
-    o = attn_lib.projected_attention(y, blk["qkv"], t.shape[-1] // heads, impl=attn_impl)
-    t = t + _dense(blk["proj"], o)
-    y = _ln_apply(blk["ln2"], t)
-    y = jax.nn.gelu(_dense(blk["mlp1"], y))
-    return t + _dense(blk["mlp2"], y)
+    with hlo_scopes.scope("vit/norm"):
+        y = _ln_apply(blk["ln1"], t)
+    with hlo_scopes.scope("vit/qkv"):
+        o = attn_lib.projected_attention(y, blk["qkv"], t.shape[-1] // heads, impl=attn_impl)
+    with hlo_scopes.scope("vit/attn_out"):
+        t = t + _dense(blk["proj"], o)
+    with hlo_scopes.scope("vit/norm"):
+        y = _ln_apply(blk["ln2"], t)
+    with hlo_scopes.scope("vit/mlp"):
+        y = jax.nn.gelu(_dense(blk["mlp1"], y))
+        return t + _dense(blk["mlp2"], y)
 
 
 def tp_block_forward(
@@ -92,17 +98,22 @@ def tp_block_forward(
     from :func:`tpu_dist.parallel.tensor.tp_ops`.  Shared by ViTDef's
     sequential TP path and the pipeline-parallel stage scan (PP×TP —
     Megatron's layout: TP inside each pipeline stage)."""
-    y = copy_to_tp(_ln_apply(blk["ln1"], t))
+    with hlo_scopes.scope("vit/norm"):
+        y = copy_to_tp(_ln_apply(blk["ln1"], t))
     # qkv is col-sharded under TP, layout [heads, 3, h_dim]: a contiguous
     # column shard is whole (local) heads
-    o = attn_lib.projected_attention(
-        y, blk["qkv"], h_dim, seq_axis=seq_axis, sp_mode=sp_mode, impl=attn_impl
-    )
-    proj = reduce_from_tp(_dense_local(blk["proj"], o))
-    t = t + proj + blk["proj"]["b"].astype(t.dtype)
-    y = copy_to_tp(_ln_apply(blk["ln2"], t))
-    y = jax.nn.gelu(_dense(blk["mlp1"], y))  # col-sharded hidden
-    return t + reduce_from_tp(_dense_local(blk["mlp2"], y)) + blk["mlp2"]["b"].astype(t.dtype)
+    with hlo_scopes.scope("vit/qkv"):
+        o = attn_lib.projected_attention(
+            y, blk["qkv"], h_dim, seq_axis=seq_axis, sp_mode=sp_mode, impl=attn_impl
+        )
+    with hlo_scopes.scope("vit/attn_out"):
+        proj = reduce_from_tp(_dense_local(blk["proj"], o))
+        t = t + proj + blk["proj"]["b"].astype(t.dtype)
+    with hlo_scopes.scope("vit/norm"):
+        y = copy_to_tp(_ln_apply(blk["ln2"], t))
+    with hlo_scopes.scope("vit/mlp"):
+        y = jax.nn.gelu(_dense(blk["mlp1"], y))  # col-sharded hidden
+        return t + reduce_from_tp(_dense_local(blk["mlp2"], y)) + blk["mlp2"]["b"].astype(t.dtype)
 
 
 def check_pos_capacity(n_tokens: int, pos_table, image_size: int, patch_size: int):
@@ -213,33 +224,34 @@ class ViTDef:
         SyncBN (there is no BN).
         """
         del axis_name
-        if tokens is None:
-            tokens = self.patchify(x)
-            if seq_axis is not None:
-                # x arrived replicated over the seq axis: each device keeps
-                # only its contiguous token chunk (ring attention owns the
-                # cross-chunk interaction)
-                n_sp = jax.lax.axis_size(seq_axis)
-                if tokens.shape[1] % n_sp:
-                    raise ValueError(
-                        f"sequence of {tokens.shape[1]} patch tokens does not "
-                        f"divide over {n_sp} sequence-parallel devices — "
-                        f"tokens would be silently dropped"
+        with hlo_scopes.scope("vit/patch_embed"):
+            if tokens is None:
+                tokens = self.patchify(x)
+                if seq_axis is not None:
+                    # x arrived replicated over the seq axis: each device keeps
+                    # only its contiguous token chunk (ring attention owns the
+                    # cross-chunk interaction)
+                    n_sp = jax.lax.axis_size(seq_axis)
+                    if tokens.shape[1] % n_sp:
+                        raise ValueError(
+                            f"sequence of {tokens.shape[1]} patch tokens does not "
+                            f"divide over {n_sp} sequence-parallel devices — "
+                            f"tokens would be silently dropped"
+                        )
+                    s_loc = tokens.shape[1] // n_sp
+                    tokens = jax.lax.dynamic_slice_in_dim(
+                        tokens, jax.lax.axis_index(seq_axis) * s_loc, s_loc, axis=1
                     )
-                s_loc = tokens.shape[1] // n_sp
-                tokens = jax.lax.dynamic_slice_in_dim(
-                    tokens, jax.lax.axis_index(seq_axis) * s_loc, s_loc, axis=1
-                )
-        t = _dense(params["patch"], tokens)
-        pos = params["pos"].astype(t.dtype)
-        if seq_axis is not None:
-            idx = jax.lax.axis_index(seq_axis)
-            s_loc = t.shape[1]
-            pos = jax.lax.dynamic_slice_in_dim(pos, idx * s_loc + pos_offset, s_loc)
-        else:
-            check_pos_capacity(t.shape[1], pos, self.image_size, self.patch_size)
-            pos = pos[: t.shape[1]]  # smaller inputs use the leading positions
-        t = t + pos[None]
+            t = _dense(params["patch"], tokens)
+            pos = params["pos"].astype(t.dtype)
+            if seq_axis is not None:
+                idx = jax.lax.axis_index(seq_axis)
+                s_loc = t.shape[1]
+                pos = jax.lax.dynamic_slice_in_dim(pos, idx * s_loc + pos_offset, s_loc)
+            else:
+                check_pos_capacity(t.shape[1], pos, self.image_size, self.patch_size)
+                pos = pos[: t.shape[1]]  # smaller inputs use the leading positions
+            t = t + pos[None]
 
         if tp_axis is not None:
             from tpu_dist.parallel.tensor import tp_ops  # noqa: PLC0415
@@ -255,12 +267,14 @@ class ViTDef:
                 seq_axis=seq_axis, sp_mode=sp_mode, attn_impl=attn_impl,
             )
 
-        t = _ln_apply(params["ln_f"], t)
-        pooled = t.mean(axis=1)
-        if seq_axis is not None:
-            # token mean over the full (sharded) sequence
-            pooled = jax.lax.pmean(pooled, seq_axis)
-        return _dense(params["head"], pooled), state
+        with hlo_scopes.scope("vit/norm"):
+            t = _ln_apply(params["ln_f"], t)
+        with hlo_scopes.scope("vit/head"):
+            pooled = t.mean(axis=1)
+            if seq_axis is not None:
+                # token mean over the full (sharded) sequence
+                pooled = jax.lax.pmean(pooled, seq_axis)
+            return _dense(params["head"], pooled), state
 
 
 def vit_b16(num_classes: int = 1000, image_size: int = 224) -> ViTDef:

@@ -25,6 +25,7 @@ than a made-up figure.
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 from tpu_dist.obs import counters as counters_lib
@@ -331,11 +332,13 @@ def analyze_jitted(jitted, *args, loop_trips: int = 1) -> Optional[dict]:
             return cost
         cost = step_cost(compiled, loop_trips)
         # the executable is in hand: keep which of its ops lie in which of
-        # the model's named scopes, for whoever reads a device trace
+        # the program's named scopes, for whoever reads a device trace
+        t0 = time.perf_counter()
         try:
-            hlo_scopes.record(compiled.as_text())
+            hlo_scopes.record(compiled.as_text(), hlo_scopes.opened())
         except Exception:
             hlo_scopes.record("")  # no table rather than a stale or half one
+        counters_lib.inc("compile.scope_table_s", round(time.perf_counter() - t0, 3))
     return cost
 
 

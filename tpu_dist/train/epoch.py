@@ -38,6 +38,7 @@ from tpu_dist.comm import mesh as mesh_lib
 from tpu_dist.comm.compat import shard_map
 from tpu_dist.data.transforms import CIFAR100_MEAN, CIFAR100_STD
 from tpu_dist.nn import functional as F
+from tpu_dist.obs import hlo_scopes
 from tpu_dist.train.state import TrainState
 
 
@@ -182,33 +183,38 @@ def make_fused_epoch(
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
         def body(state, i):
-            idx = lax.dynamic_slice_in_dim(perm, i * batch_per_device, batch_per_device)
-            imgs = jnp.take(images_u8, idx, axis=0)
-            ys = jnp.take(labels, idx, axis=0)
-            x = augment(imgs, jax.random.fold_in(base, i + 1))
+            with hlo_scopes.scope("data/take_crop"):
+                idx = lax.dynamic_slice_in_dim(perm, i * batch_per_device, batch_per_device)
+                imgs = jnp.take(images_u8, idx, axis=0)
+                ys = jnp.take(labels, idx, axis=0)
+                x = augment(imgs, jax.random.fold_in(base, i + 1))
 
-            (loss, (new_bn, logits)), grads = grad_fn(state.params, state.bn_state, x, ys)
-            # same per-step/per-replica stochastic-rounding stream as the
-            # streaming path (step.py::quant_key); no-op for none/bf16
-            qkey = jax.random.fold_in(
-                jax.random.fold_in(
-                    jax.random.PRNGKey(_QUANT_KEY_SEED), state.step
-                ),
-                dev,
-            )
-            grads, new_ef = compressed_pmean(
-                grads, axis, grad_compression,
-                key=qkey, ef=state.ef,
-            )
+            with hlo_scopes.scope("step/loss_grad"):
+                (loss, (new_bn, logits)), grads = grad_fn(state.params, state.bn_state, x, ys)
+            with hlo_scopes.scope("step/grad_reduce"):
+                # same per-step/per-replica stochastic-rounding stream as the
+                # streaming path (step.py::quant_key); no-op for none/bf16
+                qkey = jax.random.fold_in(
+                    jax.random.fold_in(
+                        jax.random.PRNGKey(_QUANT_KEY_SEED), state.step
+                    ),
+                    dev,
+                )
+                grads, new_ef = compressed_pmean(
+                    grads, axis, grad_compression,
+                    key=qkey, ef=state.ef,
+                )
             if not sync_bn:
                 new_bn = lax.pmean(new_bn, axis)
-            new_params, new_opt = optimizer.update(grads, state.opt_state, state.params, lr)
-            c1, c5 = F.topk_correct(logits.astype(jnp.float32), ys, (1, 5))
-            metrics = {
-                "loss": lax.pmean(loss, axis),
-                "acc1": lax.psum(c1, axis) / (batch_per_device * lax.psum(1, axis)) * 100.0,
-                "acc5": lax.psum(c5, axis) / (batch_per_device * lax.psum(1, axis)) * 100.0,
-            }
+            with hlo_scopes.scope("step/optimizer"):
+                new_params, new_opt = optimizer.update(grads, state.opt_state, state.params, lr)
+            with hlo_scopes.scope("step/metrics"):
+                c1, c5 = F.topk_correct(logits.astype(jnp.float32), ys, (1, 5))
+                metrics = {
+                    "loss": lax.pmean(loss, axis),
+                    "acc1": lax.psum(c1, axis) / (batch_per_device * lax.psum(1, axis)) * 100.0,
+                    "acc5": lax.psum(c5, axis) / (batch_per_device * lax.psum(1, axis)) * 100.0,
+                }
             return TrainState(
                 new_params, new_bn, new_opt, state.step + 1, new_ef
             ), metrics

@@ -41,6 +41,7 @@ from tpu_dist.comm import mesh as mesh_lib
 from tpu_dist.comm.compat import shard_map
 from tpu_dist.comm.quantize import DEFAULT_CHUNK
 from tpu_dist.nn import functional as F
+from tpu_dist.obs import hlo_scopes
 from tpu_dist.train.state import TrainState
 
 
@@ -595,7 +596,9 @@ def make_train_step(
         return losses.mean(), grads, bn, logits
 
     def step_local(state: TrainState, images, labels, lr):
-        loss, grads, new_bn, logits = local_grads(state.params, state.bn_state, images, labels)
+        with hlo_scopes.scope("step/loss_grad"):
+            loss, grads, new_bn, logits = local_grads(
+                state.params, state.bn_state, images, labels)
 
         if not sync_bn:
             # Local-BN replicas hold diverged running stats; average them so
@@ -607,37 +610,48 @@ def make_train_step(
         if shard_weight_update:
             new_params, new_opt, new_ef = _sharded_update(state, grads, lr)
         else:
-            if ep_axis is not None:
-                grads = _ep_grad_reduce(grads)
-            elif quantized:
-                # THE data-parallel reduce on the int8 wire: two-stage
-                # quantized reduce-scatter + all-gather, residuals carried
-                # in the state under int8_ef
-                grads, new_ef = quantized_pmean_flat(
-                    grads, axis, key=quant_key(state.step),
-                    ef=state.ef if grad_compression == "int8_ef" else (),
-                    chunk=DEFAULT_CHUNK,
+            with hlo_scopes.scope("step/grad_reduce"):
+                grads, new_ef = _replicated_grad_reduce(state, grads)
+            with hlo_scopes.scope("step/optimizer"):
+                grads = clip_grads(grads)
+                new_params, new_opt = optimizer.update(
+                    grads, state.opt_state, state.params, lr
                 )
-            else:
-                # THE data-parallel step: average grads over the mesh (DDP),
-                # on the (optionally bf16-compressed) wire format; one cast
-                # round-trip covers both axes.
-                local = grads
-                grads = lax.pmean(jax.tree_util.tree_map(wire, grads), axis)
-                if seq_axis is not None:
-                    # every seq shard differentiates a full replica of the
-                    # loss, so local grads sum to n× the true gradient —
-                    # MEAN over the axis recovers it (verified empirically,
-                    # tests/test_seq_parallel_training.py)
-                    grads = lax.pmean(grads, seq_axis)
-                grads = jax.tree_util.tree_map(unwire, grads, local)
-            grads = clip_grads(grads)
-            new_params, new_opt = optimizer.update(
-                grads, state.opt_state, state.params, lr
-            )
         new_state = TrainState(new_params, new_bn, new_opt, state.step + 1, new_ef)
 
-        # Replica-averaged metrics, fused into the same program
+        with hlo_scopes.scope("step/metrics"):
+            metrics = _metrics(state, loss, logits, labels, grads, new_params)
+        return new_state, metrics
+
+    def _replicated_grad_reduce(state: TrainState, grads):
+        """The cross-replica mean of a replicated parameter tree's gradients;
+        ``(grads, new_ef)``."""
+        if ep_axis is not None:
+            return _ep_grad_reduce(grads), state.ef
+        if quantized:
+            # THE data-parallel reduce on the int8 wire: two-stage
+            # quantized reduce-scatter + all-gather, residuals carried
+            # in the state under int8_ef
+            return quantized_pmean_flat(
+                grads, axis, key=quant_key(state.step),
+                ef=state.ef if grad_compression == "int8_ef" else (),
+                chunk=DEFAULT_CHUNK,
+            )
+        # THE data-parallel step: average grads over the mesh (DDP),
+        # on the (optionally bf16-compressed) wire format; one cast
+        # round-trip covers both axes.
+        local = grads
+        grads = lax.pmean(jax.tree_util.tree_map(wire, grads), axis)
+        if seq_axis is not None:
+            # every seq shard differentiates a full replica of the
+            # loss, so local grads sum to n× the true gradient —
+            # MEAN over the axis recovers it (verified empirically,
+            # tests/test_seq_parallel_training.py)
+            grads = lax.pmean(grads, seq_axis)
+        return jax.tree_util.tree_map(unwire, grads, local), state.ef
+
+    def _metrics(state: TrainState, loss, logits, labels, grads, new_params):
+        """Replica-averaged metrics, fused into the same program."""
         if model_loss is not None:
             # `logits` is the model's stats: hits and positions as sums, and
             # its own counts: summed over replicas, but for what the model
@@ -666,7 +680,7 @@ def make_train_step(
             metrics.update(
                 compute_device_stats(grads, state.params, new_params)
             )
-        return new_state, metrics
+        return metrics
 
     def _ep_grad_reduce(grads):
         """Per-leaf reduction under expert parallelism (rule verified
@@ -707,56 +721,58 @@ def make_train_step(
         Returns ``(params, opt_state, ef)``."""
         from jax.flatten_util import ravel_pytree  # noqa: PLC0415
 
-        if seq_axis is not None:
-            # same correction as the plain path: each seq shard holds a
-            # full-loss-replica gradient, mean over the axis recovers truth
-            grads = jax.tree_util.tree_map(
-                lambda g: unwire(lax.pmean(wire(g), seq_axis), g), grads
+        with hlo_scopes.scope("step/grad_reduce"):
+            if seq_axis is not None:
+                # same correction as the plain path: each seq shard holds a
+                # full-loss-replica gradient, mean over the axis recovers truth
+                grads = jax.tree_util.tree_map(
+                    lambda g: unwire(lax.pmean(wire(g), seq_axis), g), grads
+                )
+            flat_g, _ = ravel_pytree(grads)
+            flat_p, unravel = ravel_pytree(state.params)
+            L = flat_g.shape[0]
+            chunk = -(-L // n_axis)
+            pad = chunk * n_axis - L
+            new_ef = state.ef
+            if quantized:
+                x = jnp.pad(flat_g / n_axis, (0, pad))
+                if grad_compression == "int8_ef":
+                    x = x + state.ef["r1"]
+                g_shard, sent = _quantized_reduce_scatter_rows(
+                    x.reshape(n_axis, chunk), axis,
+                    quant_key(state.step), DEFAULT_CHUNK,
+                )
+                if grad_compression == "int8_ef":
+                    new_ef = {"r1": x - sent.reshape(chunk * n_axis)}
+            else:
+                g_shard = lax.psum_scatter(
+                    wire(jnp.pad(flat_g / n_axis, (0, pad))), axis,
+                    scatter_dimension=0, tiled=True,
+                ).astype(flat_g.dtype)
+        with hlo_scopes.scope("step/optimizer"):
+            if grad_clip_norm > 0.0:  # global norm from shard norms (one psum)
+                sq = lax.psum(jnp.sum(jnp.square(g_shard)), axis)
+                scale = jnp.minimum(1.0, grad_clip_norm / jnp.maximum(jnp.sqrt(sq), 1e-12))
+                g_shard = g_shard * scale
+            idx = lax.axis_index(axis)
+            p_shard = lax.dynamic_slice_in_dim(jnp.pad(flat_p, (0, pad)), idx * chunk, chunk)
+            kw = {}
+            if hasattr(optimizer, "leaf_wd_intervals"):
+                # AdamW: the rank-based decay mask in flat coordinates — this
+                # shard's per-element decay built from static leaf intervals
+                # (iota comparisons; never a model-length constant in HBM)
+                pos = idx * chunk + jnp.arange(chunk)
+                wd_shard = jnp.zeros((chunk,), jnp.float32)
+                for start, end, w in optimizer.leaf_wd_intervals(state.params):
+                    wd_shard = wd_shard + w * (
+                        (pos >= start) & (pos < end)
+                    ).astype(jnp.float32)
+                kw["wd_tree"] = wd_shard
+            new_p_shard, new_b_shard = optimizer.update(
+                g_shard, state.opt_state, p_shard, lr, **kw
             )
-        flat_g, _ = ravel_pytree(grads)
-        flat_p, unravel = ravel_pytree(state.params)
-        L = flat_g.shape[0]
-        chunk = -(-L // n_axis)
-        pad = chunk * n_axis - L
-        new_ef = state.ef
-        if quantized:
-            x = jnp.pad(flat_g / n_axis, (0, pad))
-            if grad_compression == "int8_ef":
-                x = x + state.ef["r1"]
-            g_shard, sent = _quantized_reduce_scatter_rows(
-                x.reshape(n_axis, chunk), axis,
-                quant_key(state.step), DEFAULT_CHUNK,
-            )
-            if grad_compression == "int8_ef":
-                new_ef = {"r1": x - sent.reshape(chunk * n_axis)}
-        else:
-            g_shard = lax.psum_scatter(
-                wire(jnp.pad(flat_g / n_axis, (0, pad))), axis,
-                scatter_dimension=0, tiled=True,
-            ).astype(flat_g.dtype)
-        if grad_clip_norm > 0.0:  # global norm from shard norms (one psum)
-            sq = lax.psum(jnp.sum(jnp.square(g_shard)), axis)
-            scale = jnp.minimum(1.0, grad_clip_norm / jnp.maximum(jnp.sqrt(sq), 1e-12))
-            g_shard = g_shard * scale
-        idx = lax.axis_index(axis)
-        p_shard = lax.dynamic_slice_in_dim(jnp.pad(flat_p, (0, pad)), idx * chunk, chunk)
-        kw = {}
-        if hasattr(optimizer, "leaf_wd_intervals"):
-            # AdamW: the rank-based decay mask in flat coordinates — this
-            # shard's per-element decay built from static leaf intervals
-            # (iota comparisons; never a model-length constant in HBM)
-            pos = idx * chunk + jnp.arange(chunk)
-            wd_shard = jnp.zeros((chunk,), jnp.float32)
-            for start, end, w in optimizer.leaf_wd_intervals(state.params):
-                wd_shard = wd_shard + w * (
-                    (pos >= start) & (pos < end)
-                ).astype(jnp.float32)
-            kw["wd_tree"] = wd_shard
-        new_p_shard, new_b_shard = optimizer.update(
-            g_shard, state.opt_state, p_shard, lr, **kw
-        )
-        flat_new = lax.all_gather(new_p_shard, axis, tiled=True)[:L]
-        return unravel(flat_new), new_b_shard, new_ef
+            flat_new = lax.all_gather(new_p_shard, axis, tiled=True)[:L]
+            return unravel(flat_new), new_b_shard, new_ef
 
     p_spec = param_specs if param_specs is not None else P()
     if shard_weight_update:
