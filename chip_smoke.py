@@ -15,10 +15,12 @@ made from a seed:
   shards on every chip; one ``make_train_step`` step on a 1-device mesh
   against all devices (fp32, same global batch); one ``--sp N
   --flash_attention`` step (ppermute + Pallas inside ``shard_map``).
-* ``fused_sgd`` / ``flash_kernels`` / ``gmm_kernel`` / ``vit_b16_flash_step``
+* ``fused_sgd`` / ``flash_kernels`` / ``gmm_kernel`` / ``conv_kernel`` /
+  ``vit_b16_flash_step``
   — every Pallas kernel compiled (``interpret=False``) and compared with its
   jnp/XLA reference (the experts' grouped product inside ``dropless_experts``
-  at the two token cells' shapes); ViT-B/16 at 224 px takes its steps
+  at the two token cells' shapes, the mixers' convolution reading the
+  Nemotron cell's projection in place); ViT-B/16 at 224 px takes its steps
   through ``bench.run``.
 
 It prints one ``PASS``/``FAIL <phase>: <reason>`` line per phase and, as the
@@ -66,6 +68,10 @@ GMM_SHAPES = [("lfm2", 32768, 2048, 1536, 64, 4, 32768, True),
               ("nemotron", 16384, 2688, 1856, 128, 6, 12288, False)]
 REHEARSAL_GMM_SHAPES = [("toy_gated", 1024, 256, 384, 16, 2, 2048, True),
                         ("toy_whole_width", 1024, 256, 464, 16, 2, 2048, False)]
+# (sequences, tokens, the column borders of x | B | C in proj, taps): the mixer
+# of nemotron3_nano_share, whose proj is gate 4096 | x 4096 | B 1024 | C 1024 | dt 64
+CONV_SHAPE = (2, 8192, (4096, 8192, 9216, 10240), 4)
+REHEARSAL_CONV_SHAPE = (2, 64, (128, 384, 512, 640), 4)
 
 
 class SmokeFailure(Exception):
@@ -524,6 +530,54 @@ def phase_gmm_kernel(ctx: dict) -> str:
             + "; ".join(notes) + "; " + spy.check_compiled(ctx["on_tpu"]))
 
 
+def phase_conv_kernel(ctx: dict) -> str:
+    """The mixers' depthwise convolution (``ops/causal_conv1d.py``) at the
+    Nemotron cell's shape, bf16: x, B and C read in ``proj`` where they lie,
+    and the gradients to ``proj``, the taps and the bias, the kernel pair
+    compiled against the XLA chain of ``nn/nemotron_h.py::_mixer``."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_dist.ops import causal_conv1d as K
+
+    bsz, seq, borders, taps = ctx["conv_shape"]
+    lo, hi, width = borders[0], borders[-1], borders[-1] + 64
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    proj = jax.random.normal(ks[0], (bsz, seq, width), jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (taps, hi - lo), jnp.float32, -0.5, 0.5)
+    bias = jax.random.uniform(ks[2], (hi - lo,), jnp.float32, -0.5, 0.5)
+    ct = jax.random.normal(ks[3], (bsz, seq, hi - lo), jnp.bfloat16)
+
+    def chain(proj, w, bias):
+        padded = jnp.pad(proj[..., lo:hi], ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
+        conv = sum(padded[:, i:i + seq] * w[i] for i in range(taps)) + bias
+        return jax.nn.silu(conv).astype(proj.dtype)
+
+    def kernel(proj, w, bias):
+        ys = K.causal_conv1d(proj, w, bias, borders=borders, activation="silu",
+                             interpret=not ctx["on_tpu"])
+        return jnp.concatenate(ys[1:-1], axis=-1)
+
+    def value_and_grads(f):
+        loss = lambda *a: jnp.sum(f(*a).astype(jnp.float32) * ct.astype(jnp.float32))  # noqa: E731
+        return jax.jit(lambda *a: (f(*a), *jax.grad(loss, argnums=(0, 1, 2))(*a)))(proj, w, bias)
+
+    check(K.fits(seq, borders, taps, proj.dtype), f"fits refuses {seq} tokens, borders {borders}")
+    with PallasSpy() as spy:
+        got = value_and_grads(kernel)
+    want = value_and_grads(chain)
+    errs = []
+    for what, k, x in zip(("y", "dproj", "dw", "dbias"), got, want):
+        check(bool(jnp.all(jnp.isfinite(k.astype(jnp.float32)))), f"{what} not finite")
+        check(k.shape == x.shape and k.dtype == x.dtype, f"{what} is {k.dtype}{k.shape}")
+        errs.append(_nerr(k, x))
+        check(errs[-1] <= FLASH_TOL / 2, f"{what} off the chain's by {errs[-1]:.3e}")
+    return (f"causal_conv1d {bsz} x {seq} tokens, sections {borders}, {taps} taps, kernel pair vs "
+            f"XLA chain, max normalized difference: " + ", ".join(
+                f"{n} {e:.1e}" for n, e in zip(("y", "dproj", "dw", "dbias"), errs))
+            + "; " + spy.check_compiled(ctx["on_tpu"]))
+
+
 def phase_token_step(ctx: dict) -> str:
     """The token path end to end at toy widths: one epoch of the tiny hybrid
     decoder (mixer, expert layer, causal grouped attention) through
@@ -597,11 +651,12 @@ def main(argv=None) -> int:
     phases = [phase_train, phase_resume, phase_placement]
     if device["count"] > 1:
         phases += [phase_dp_equivalence, phase_ring_flash]
-    phases += [phase_fused_sgd, phase_flash_kernels, phase_gmm_kernel,
+    phases += [phase_fused_sgd, phase_flash_kernels, phase_gmm_kernel, phase_conv_kernel,
                phase_vit_b16_flash_step, phase_token_step]
 
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     ctx = {"size": size, "flash_shapes": shapes, "gmm_shapes": gmm_shapes,
+           "conv_shape": CONV_SHAPE if on_tpu and not args.rehearse_on_cpu else REHEARSAL_CONV_SHAPE,
            "workdir": workdir, "on_tpu": on_tpu}
     failed = []
     try:

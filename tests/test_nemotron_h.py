@@ -2,6 +2,7 @@
 (benchmarks/models/nemotron_h.py): CPU, tiny preset, seeded weights."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -18,6 +19,7 @@ from benchmarks.harness.manifest import load_module  # noqa: E402
 from tpu_dist.nn import attention as attn_lib  # noqa: E402
 from tpu_dist.nn import functional as F  # noqa: E402
 from tpu_dist.nn.nemotron_h import HybridDecoderDef, nemotron_h_tiny, ssm_scan  # noqa: E402
+from tpu_dist.obs import counters as counters_lib  # noqa: E402
 from tests.helpers import hybrid_arch as arch_of  # noqa: E402
 from tpu_dist.parallel import expert as expert_lib  # noqa: E402
 
@@ -162,6 +164,69 @@ def test_a_bfloat16_state_strays_a_hundred_times_further_from_the_recurrence():
 def test_scan_refuses_a_ragged_sequence():
     with pytest.raises(ValueError, match="whole chunks"):
         ssm_scan(*_scan_inputs(s=30), 8)
+
+
+# -- the mixer's convolution: one chain, two realisations ---------------------------------
+
+def _conv_sites(fn):
+    """(ssm.conv_sites_kernel, ssm.conv_sites_xla) that running ``fn`` adds."""
+    names = ("ssm.conv_sites_kernel", "ssm.conv_sites_xla")
+    before = [counters_lib.get(n) for n in names]
+    out = fn()
+    return out, tuple(counters_lib.get(n) - b for n, b in zip(names, before))
+
+
+def _mixer_value_and_grads(m, p, h, dtype):
+    w = jax.random.normal(jax.random.PRNGKey(9), h.shape)
+    loss = lambda p, h: (m._mixer(p, h, dtype).astype(jnp.float32) * w).sum()  # noqa: E731
+    return m._mixer(p, h, dtype), jax.grad(loss, argnums=(0, 1))(p, h)
+
+
+def _interpreted(conv, *args, interpret, **kw):
+    """The mixer asks for the compiled kernels (it takes them on a TPU only);
+    here they are interpreted, two tiles of tokens a sequence."""
+    assert interpret is False
+    return conv(*args, interpret=True, tile=32, **kw)
+
+
+def test_the_mixer_on_the_cpu_takes_the_xla_chain(tiny):
+    m, params, _ = tiny
+    h = jax.random.normal(jax.random.PRNGKey(2), (2, m.seq_len, m.hidden))
+    _, sites = _conv_sites(lambda: m._mixer(params["layers"][0], h, jnp.float32))
+    assert sites == (0, 1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_the_mixer_with_the_conv_kernel_equals_the_mixer_with_the_chain(monkeypatch, dtype):
+    """A toy mixer whose sections are whole lane blocks (2 heads of 64, one
+    group of state 128: proj is gate 128 | x 128 | B 128 | C 128 | dt 2),
+    with ``takes_conv_kernel`` patched true and the kernel pair interpreted:
+    the output, ``dh`` and every parameter's gradient against the chain's,
+    float32 to rounding, bfloat16 to a step of its own (the scan's test)."""
+    from tpu_dist.nn import nemotron_h as decoder
+    from tpu_dist.ops import causal_conv1d as K
+
+    m = dataclasses.replace(
+        nemotron_h_tiny(), pattern="M", hidden=32, mamba_heads=2, mamba_head_dim=64,
+        ssm_groups=1, ssm_state=128, chunk_size=16, seq_len=64)
+    p = m.init(jax.random.PRNGKey(3))[0]["layers"][0]
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, m.seq_len, m.hidden)).astype(dtype)
+    (y_xla, g_xla), sites = _conv_sites(lambda: _mixer_value_and_grads(m, p, h, dtype))
+    assert sites[0] == 0 and sites[1] >= 1
+
+    monkeypatch.setattr(K, "causal_conv1d", functools.partial(_interpreted, K.causal_conv1d))
+    monkeypatch.setattr(decoder, "takes_conv_kernel", K.fits)
+    (y_ker, g_ker), sites = _conv_sites(lambda: _mixer_value_and_grads(m, p, h, dtype))
+    assert sites[0] >= 1 and sites[1] == 0
+
+    rms = lambda t: float(jnp.sqrt(jnp.mean(jnp.square(t.astype(jnp.float32)))))  # noqa: E731
+    tol = 2e-5 if dtype == jnp.float32 else 2.0 ** -7
+    assert y_ker.dtype == y_xla.dtype and rms(y_ker - y_xla) <= tol * rms(y_xla)
+    flat = lambda g: jax.tree_util.tree_leaves_with_path(g)  # noqa: E731
+    for (path, ker), (_, xla) in zip(flat(g_ker), flat(g_xla)):
+        name = jax.tree_util.keystr(path)
+        assert ker.shape == xla.shape and ker.dtype == xla.dtype, name
+        assert rms(ker - xla) <= tol * rms(xla) + 1e-12, name
 
 
 # -- causal grouped-head attention -------------------------------------------------------

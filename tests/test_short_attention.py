@@ -497,3 +497,56 @@ def test_v5e_expert_layer_reads_an_1856_wide_matrix_as_the_chip_keeps_it(monkeyp
     assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert "f32[8,2688,1856]{1,2,0" in text  # w_up as the chip keeps it
     assert not re.findall(r"copy[.\d]* = \w+\[8,(?:2688,1856|1856,2688)\]", text)
+
+
+# -- compile only: the mixers' depthwise convolution (ops/causal_conv1d.py) --------
+
+
+def _mixer_program(monkeypatch, one_chip, conv_kernel):
+    """One mixer layer of the Nemotron share (norm, mixer, residual: 2 x
+    8,192 tokens, ``proj`` 10,304 columns = gate 4,096 | x 4,096 | B 1,024 |
+    C 1,024 | dt 64, 4 taps, bfloat16) with its gradients, the scan through
+    its kernel pair, compiled for one v5e chip."""
+    from tpu_dist.nn import nemotron_h as decoder
+
+    monkeypatch.setattr(decoder, "_on_tpu", lambda: True)
+    if not conv_kernel:
+        monkeypatch.setattr(decoder, "takes_conv_kernel", lambda *a: False)
+    m = decoder.nemotron3_nano_share()
+    place = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)  # noqa: E731
+    p = jax.tree_util.tree_map(place, jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))[0]["layers"][0]))
+    x = jax.ShapeDtypeStruct((2, 8192, m.hidden), jnp.bfloat16, sharding=one_chip)
+
+    def layer(p, x):
+        return x + m._mixer(p, decoder.rms_norm(p["norm"], x, m.eps), jnp.bfloat16)
+
+    def step(p, x, ct):
+        y, vjp = jax.vjp(layer, p, x)
+        return y, vjp(ct)
+
+    return jax.jit(step).lower(p, x, x).compile().as_text()
+
+
+_CHAIN_FLOAT32 = re.compile(r"f32\[2,819[25],6144\]")  # the padded input, the pre-activation
+
+
+def test_conv_kernel_pair_compiles_for_v5e_and_reads_the_projection_in_place(monkeypatch, one_chip):
+    """What interpret mode cannot show: Mosaic takes both kernels at the
+    cell's shape (blocks of ``proj [2, 8192, 10304]`` read where x, B and C
+    lie, 4 taps over 6,144 channels); the layer's program holds three calls
+    forward and three backward beside the scan's three, none of the chain's
+    float32 ``[2, 8195, 6144]`` / ``[2, 8192, 6144]`` arrays, and no copy of
+    ``proj``, of a slice of it or of the scan's operands between the input
+    product, these kernels and the scan's."""
+    from tpu_dist.ops import causal_conv1d as C
+
+    assert C.fits(8192, (4096, 8192, 9216, 10240), 4, jnp.bfloat16)
+    text = _mixer_program(monkeypatch, one_chip, conv_kernel=True)
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert text.count("causal_conv1d_fwd/pallas_call") >= 3 and text.count("causal_conv1d_bwd/pallas_call") >= 3
+    assert not _CHAIN_FLOAT32.search(text)
+    assert not re.findall(r"copy[.\d]* = bf16\[2,8192,(?:10304|6144|4096|1024)\]", text)
+
+    chain = _mixer_program(monkeypatch, one_chip, conv_kernel=False)
+    assert chain.count('custom_call_target="tpu_custom_call"') == 3
+    assert _CHAIN_FLOAT32.search(chain)

@@ -83,6 +83,17 @@ def takes_scan_kernel(chunk: int, heads_per_group: int, head_dim: int, state: in
     return fits(chunk, heads_per_group, head_dim, state, dtype)
 
 
+def takes_conv_kernel(seq: int, borders, taps: int, dtype) -> bool:
+    """Where the mixer's convolution takes the Pallas kernel pair of
+    ``ops/causal_conv1d.py``: a TPU, and sequences, column borders of the
+    convolved sections in the projection, taps and a dtype its blocks fit."""
+    if not _on_tpu():
+        return False
+    from tpu_dist.ops.causal_conv1d import fits  # noqa: PLC0415
+
+    return fits(seq, borders, taps, dtype)
+
+
 def ssm_scan(x, dt, a, b, c, chunk: int, state_dtype=jnp.float32):
     """Mamba-2's recurrence ``H_t = exp(dt_t a) H_{t-1} + dt_t x_t (x) B_t``,
     ``y_t = H_t C_t`` in chunks of ``chunk`` tokens: the quadratic form inside
@@ -285,14 +296,35 @@ class HybridDecoderDef:
         bsz, s, _ = h.shape
         heads, hp, g, n = self.mamba_heads, self.mamba_head_dim, self.ssm_groups, self.ssm_state
         inner, k = self.mamba_inner, self.conv_kernel
+        # columns of proj: gate | x | B | C | dt
+        borders = (inner, 2 * inner, 2 * inner + g * n, inner + self.conv_dim)
         with hlo_scopes.scope("ssm/in_proj"):
             proj = h @ p["in_proj"].astype(dtype)
-            gate, xbc, dt = jnp.split(proj, [inner, inner + self.conv_dim], axis=-1)
+        # One chain, two realisations, chosen by what is seen here: on a TPU,
+        # with borders on 128-lane blocks and whole tiles of tokens
+        # (takes_conv_kernel), a kernel pair reads x, B and C in proj where
+        # they lie and keeps the float32 values in VMEM
+        # (``ssm.conv_sites_kernel``); anything else is the chain below,
+        # differentiable by plain autodiff (``ssm.conv_sites_xla``), which
+        # puts them through HBM nine times over (PERF.md, PR 38).
+        if takes_conv_kernel(s, borders, k, proj.dtype):
+            from tpu_dist.ops.causal_conv1d import causal_conv1d  # noqa: PLC0415
+
+            counters_lib.inc("ssm.conv_sites_kernel")
+            with hlo_scopes.scope("ssm/conv1d"):
+                gate, x, b, c, dt = causal_conv1d(
+                    proj, p["conv_w"], p["conv_b"], borders=borders, activation="silu",
+                    interpret=False)  # only on a TPU
+        else:
+            counters_lib.inc("ssm.conv_sites_xla")
+            with hlo_scopes.scope("ssm/in_proj"):
+                gate, xbc, dt = jnp.split(proj, [inner, inner + self.conv_dim], axis=-1)
+            with hlo_scopes.scope("ssm/conv1d"):
+                padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+                conv = sum(padded[:, i:i + s] * p["conv_w"][i] for i in range(k)) + p["conv_b"]
+                xbc = jax.nn.silu(conv).astype(dtype)
+                x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
         with hlo_scopes.scope("ssm/conv1d"):
-            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-            conv = sum(padded[:, i:i + s] * p["conv_w"][i] for i in range(k)) + p["conv_b"]
-            xbc = jax.nn.silu(conv).astype(dtype)
-            x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
         # Around the scan everything stays [B, S, inner], the layout the
         # projections and the scan kernel share: on the TPU a [.., heads, 64]

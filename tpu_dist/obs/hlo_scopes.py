@@ -76,7 +76,8 @@ SCOPES: Dict[str, Scope] = {
     "block/residual": Scope(_NH, "train step", "block", _BLOCK, "x + block(x)"),
     "ssm/in_proj": Scope(_NH, "train step", "block", _BLOCK, "the mixer's input product and its splits"),
     "ssm/conv1d": Scope(_NH, "train step", "block", _BLOCK,
-                        "pad, depthwise taps, silu, the softplus of dt"),
+                        "depthwise taps, bias and silu over x, B, C (ops/causal_conv1d.py where it "
+                        "fits), the softplus of dt"),
     "ssm/scan": Scope(_NH, "kernels", "block", "ssm_scan_roofline_share", "the chunked scan and the skip"),
     "ssm/gate_norm": Scope(_NH, "train step", "block", _BLOCK, "gate, grouped norm, gnorm"),
     "ssm/out_proj": Scope(_NH, "train step", "block", _BLOCK, "the mixer's output product"),
