@@ -16,11 +16,13 @@ made from a seed:
   against all devices (fp32, same global batch); one ``--sp N
   --flash_attention`` step (ppermute + Pallas inside ``shard_map``).
 * ``fused_sgd`` / ``flash_kernels`` / ``gmm_kernel`` / ``conv_kernel`` /
-  ``vit_b16_flash_step``
+  ``combine_kernel`` / ``vit_b16_flash_step``
   — every Pallas kernel compiled (``interpret=False``) and compared with its
   jnp/XLA reference (the experts' grouped product inside ``dropless_experts``
   at the two token cells' shapes, the mixers' convolution reading the
-  Nemotron cell's projection in place); ViT-B/16 at 224 px takes its steps
+  Nemotron cell's projection in place, the experts' combine and the
+  dispatch's backward at the LFM2 cell's shapes against the scatter-adds);
+  ViT-B/16 at 224 px takes its steps
   through ``bench.run``.
 
 It prints one ``PASS``/``FAIL <phase>: <reason>`` line per phase and, as the
@@ -72,6 +74,10 @@ REHEARSAL_GMM_SHAPES = [("toy_gated", 1024, 256, 384, 16, 2, 2048, True),
 # of nemotron3_nano_share, whose proj is gate 4096 | x 4096 | B 1024 | C 1024 | dt 64
 CONV_SHAPE = (2, 8192, (4096, 8192, 9216, 10240), 4)
 REHEARSAL_CONV_SHAPE = (2, 64, (128, 384, 512, 640), 4)
+# (tokens, hidden, experts, top-k, buffer rows): the expert layer of
+# lfm2_24b_a2b_share, 8 experts held
+COMBINE_SHAPE = (32768, 2048, 64, 4, 32768)
+REHEARSAL_COMBINE_SHAPE = (1024, 256, 64, 4, 1024)
 
 
 class SmokeFailure(Exception):
@@ -578,6 +584,63 @@ def phase_conv_kernel(ctx: dict) -> str:
             + "; " + spy.check_compiled(ctx["on_tpu"]))
 
 
+def phase_combine_kernel(ctx: dict) -> str:
+    """The expert layer's combine and the dispatch's backward
+    (``ops/expert_combine.py``) at the LFM2 cell's shapes, bf16: the
+    combine's value and its gradients to the rows and the routing weights,
+    and the dispatch gather's gradient to x, the kernel through the two
+    ``custom_vjp``s of ``parallel/expert.py`` compiled against XLA's
+    scatter-add forms."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_dist.ops import expert_combine as K
+    from tpu_dist.parallel import expert
+
+    tokens, d, n_experts, top_k, capacity = ctx["combine_shape"]
+    ks = jax.random.split(jax.random.PRNGKey(41), 6)
+    chosen = jax.vmap(lambda k: jax.random.permutation(k, n_experts)[:top_k])(
+        jax.random.split(ks[0], tokens))
+    buf = expert._buffer(chosen, (0, 8), capacity)
+    runs = expert._block_runs(buf, K.TOKEN_BLOCK)
+    token, valid, over, pair = buf["token"], buf["valid"], buf["over"], buf["pair"]
+    x = jax.random.normal(ks[1], (tokens, d), jnp.bfloat16)
+    y = jnp.where(valid, jax.random.normal(ks[2], (token.shape[0], d)), 0).astype(jnp.bfloat16)
+    weights = jax.random.uniform(ks[3], (tokens, top_k)).astype(jnp.bfloat16)
+    d_out = jax.random.normal(ks[4], (tokens, d), jnp.bfloat16)
+    d_rows = jnp.where(valid, jax.random.normal(ks[5], y.shape), 0).astype(jnp.bfloat16)
+
+    def kernel(x, y, weights):
+        out, vjp = jax.vjp(lambda y, w: expert._combine(
+            tokens, y, w.reshape(-1)[pair], token, valid, runs, over), y, weights)
+        dx = jax.vjp(lambda x: expert._dispatch(tokens, x, token, valid, runs), x)[1](d_rows)[0]
+        return (out, dx, *vjp(d_out))
+
+    def scatter(x, y, weights):
+        def combine(y, w):
+            v = jnp.where(valid, y.astype(jnp.float32) * w.reshape(-1)[pair][:, None], 0)
+            out = jnp.zeros((tokens, d), jnp.float32).at[token].add(v)
+            return jnp.where(over > 0, jnp.nan, out).astype(y.dtype)
+        out, vjp = jax.vjp(combine, y, weights)
+        dx = jax.vjp(lambda x: jnp.where(valid, x[token], 0), x)[1](d_rows)[0]
+        return (out, dx, *vjp(d_out))
+
+    with PallasSpy() as spy:
+        got = jax.jit(kernel)(x, y, weights)
+    want = jax.jit(scatter)(x, y, weights)
+    check(int(over) == 0, f"{int(over)} rows over the buffer")
+    errs = []
+    for what, k, s in zip(("out", "dx", "d_y", "d_weight"), got, want):
+        check(bool(jnp.all(jnp.isfinite(k.astype(jnp.float32)))), f"{what} not finite")
+        check(k.shape == s.shape and k.dtype == s.dtype, f"{what} is {k.dtype}{k.shape}")
+        errs.append(_nerr(k, s))
+        check(errs[-1] <= FLASH_TOL / 2, f"{what} off the scatter's by {errs[-1]:.3e}")
+    return (f"combine {tokens} tokens x {d} from {int(buf['live'])} live rows of {token.shape[0]}, "
+            f"kernel vs scatter-add, max difference over the largest value: " + ", ".join(
+                f"{n} {e:.1e}" for n, e in zip(("out", "dx", "d_y", "d_weight"), errs))
+            + "; " + spy.check_compiled(ctx["on_tpu"]))
+
+
 def phase_token_step(ctx: dict) -> str:
     """The token path end to end at toy widths: one epoch of the tiny hybrid
     decoder (mixer, expert layer, causal grouped attention) through
@@ -652,11 +715,12 @@ def main(argv=None) -> int:
     if device["count"] > 1:
         phases += [phase_dp_equivalence, phase_ring_flash]
     phases += [phase_fused_sgd, phase_flash_kernels, phase_gmm_kernel, phase_conv_kernel,
-               phase_vit_b16_flash_step, phase_token_step]
+               phase_combine_kernel, phase_vit_b16_flash_step, phase_token_step]
 
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     ctx = {"size": size, "flash_shapes": shapes, "gmm_shapes": gmm_shapes,
            "conv_shape": CONV_SHAPE if on_tpu and not args.rehearse_on_cpu else REHEARSAL_CONV_SHAPE,
+           "combine_shape": COMBINE_SHAPE if on_tpu and not args.rehearse_on_cpu else REHEARSAL_COMBINE_SHAPE,
            "workdir": workdir, "on_tpu": on_tpu}
     failed = []
     try:

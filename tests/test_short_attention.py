@@ -468,7 +468,7 @@ def test_v5e_expert_layer_gradient_keeps_no_slab_update_in_a_loop(monkeypatch, o
         return (text.count('custom_call_target="tpu_custom_call"'),
                 len(_SLAB_UPDATE_IN_A_LOOP.findall(text)))
 
-    assert slab_updates_in_loops(True) == (9, 0)  # three products: gmm, its dx, tgmm
+    assert slab_updates_in_loops(True) == (11, 0)  # three products: gmm, its dx, tgmm; the combine, the dispatch's dx
     calls, in_loops = slab_updates_in_loops(False)
     assert calls == 0 and in_loops >= 3
 
@@ -494,9 +494,67 @@ def test_v5e_expert_layer_reads_an_1856_wide_matrix_as_the_chip_keeps_it(monkeyp
 
     monkeypatch.setattr(E, "_on_tpu", lambda: True)
     text = jax.jit(jax.grad(loss, argnums=(0, 3, 4))).lower(*args).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert text.count('custom_call_target="tpu_custom_call"') == 8  # and the combine's two
     assert "f32[8,2688,1856]{1,2,0" in text  # w_up as the chip keeps it
     assert not re.findall(r"copy[.\d]* = \w+\[8,(?:2688,1856|1856,2688)\]", text)
+
+
+# -- compile only: the experts' combine (ops/expert_combine.py) --------------------
+
+# (tokens, hidden, top-k, experts, buffer capacity, gated): the expert layers of
+# lfm2_24b_a2b_share and nemotron3_nano_share, 8 experts held
+_COMBINE_CELLS = {"lfm2": (32768, 2048, 4, 64, 32768, True), "nemotron": (16384, 2688, 6, 128, 12288, False)}
+
+
+@pytest.mark.parametrize("t,d,k,n_experts,capacity,gated", list(_COMBINE_CELLS.values()), ids=list(_COMBINE_CELLS))
+def test_v5e_expert_layer_keeps_no_token_sized_scatter(monkeypatch, one_chip, t, d, k, n_experts, capacity, gated):
+    """``dropless_experts``' gradient at both token cells' shapes, compiled
+    for one v5e chip: with the combine kernel (and the dispatch's backward
+    through it) the program holds no scatter into a ``[T, d]`` array; with
+    XLA's forms it holds two, the combine's float32 and the dispatch
+    gather's transpose (bf16: the kernel's float32 sum is not less)."""
+    from tpu_dist.parallel import expert as E
+
+    del n_experts
+    place = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (place((t, d)), place((t, k), jnp.int32), place((t, k)), place((8, d, 1536)),
+            place((8, 1536, d))) + ((place((8, d, 1536)),) if gated else ())
+
+    def loss(x, chosen, weights, w_up, w_down, *w_gate):
+        out, _ = E.dropless_experts(x, chosen, weights, w_up, w_down, held=(0, 8), capacity=capacity,
+                                    activation=jax.nn.silu, **({"w_gate": w_gate[0]} if gated else {}))
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    token_sized = re.compile(rf"= (\w+)\[{t},{d}\]\S* scatter\(")
+
+    def scatters(kernel):
+        monkeypatch.setattr(E, "_on_tpu", lambda: True)
+        monkeypatch.setattr(E, "takes_combine_kernel", lambda *a: kernel)
+        text = jax.jit(jax.grad(loss)).lower(*args).compile().as_text()
+        return sorted(token_sized.findall(text))
+
+    assert scatters(True) == []
+    assert scatters(False) == ["bf16", "f32"]
+
+
+@pytest.mark.parametrize("t,d,rows", [(32768, 2048, 36864), (16384, 2688, 16384)], ids=["lfm2", "nemotron"])
+@pytest.mark.parametrize("scaled", [True, False], ids=["combine", "dispatch_backward"])
+def test_combine_kernel_compiles_for_v5e_at_the_token_cells_shapes(one_chip, t, d, rows, scaled):
+    """What interpret mode cannot show: Mosaic takes the kernel (its DMAs
+    from HBM at a chunk's dynamic row, the token row read at a dynamic
+    sublane) at both cells' buffers, in bf16."""
+    from tpu_dist.ops import expert_combine as C
+
+    assert C.fits(t, d, rows, jnp.bfloat16)
+    place = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+
+    def build(src, token, scale, runs, over):
+        return C.tokens_from_runs(src, token, scale if scaled else None, runs, over, t, jnp.bfloat16,
+                                  interpret=False)
+
+    text = jax.jit(build).lower(place((rows, d)), place((rows,), jnp.int32), place((rows,), jnp.float32),
+                                place((t // C.TOKEN_BLOCK, 8, 2), jnp.int32), place((), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 # -- compile only: the mixers' depthwise convolution (ops/causal_conv1d.py) --------

@@ -269,6 +269,135 @@ def _grouped_matmul_bwd(transpose, res, dy):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
+# -- the rows back in token order ------------------------------------------------
+#
+# The dispatch gathers ``x[token]`` into the sorted buffer and the combine sums
+# the buffer's rows back into token order. A gather is the cheap direction;
+# the sum, which is also the gather's transpose in the backward, is the
+# scatter ``zeros(f32[T, d]).at[token].add(rows)``. Where the kernel of
+# ops/expert_combine.py fits, both sums are that kernel instead, told by
+# ``runs`` where each block of tokens' rows lie.
+
+
+def takes_combine_kernel(tokens: int, width: int, rows: int, dtype) -> bool:
+    """Where the combine and the dispatch's backward take the kernel of
+    ``ops/expert_combine.py``: a TPU, whole blocks of tokens, a width in
+    128-lane blocks, whole chunks of buffer rows, VMEM within budget."""
+    if not _on_tpu():
+        return False
+    from tpu_dist.ops.expert_combine import fits  # noqa: PLC0415
+
+    return fits(tokens, width, rows, dtype)
+
+
+def _buffer(chosen, held, capacity: int) -> dict:
+    """The sorted row buffer of :func:`dropless_experts`: ``tile`` rows a
+    tile, ``n_tiles`` tiles, each tile's expert, ``n_live`` live tiles, and
+    for each of the ``n_tiles * tile`` buffer rows its ``pair`` (token-major
+    pair id), ``token`` and ``valid`` (``[rows, 1]``: a live row of its
+    expert); ``live`` rows were routed to a held expert, ``over`` of them
+    past ``capacity``. ``key``, ``order`` and ``first_row`` are what
+    :func:`_block_runs` reads."""
+    t, k = chosen.shape
+    first, count = held
+    tile = min(GROUP_TILE, -(-capacity // 8) * 8)       # a small buffer is one short tile an expert
+    n_tiles = -(-capacity // tile) + count
+    local = chosen.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)  # absent experts sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    live = sizes.sum()
+    ends = jnp.minimum(jnp.cumsum(sizes), capacity)
+    sizes = jnp.diff(ends, prepend=0)                       # what the buffer takes
+    starts = ends - sizes
+    tiles = -(-sizes // tile)                               # whole tiles an expert
+    tile_ends = jnp.cumsum(tiles)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right"), count - 1)
+    # buffer row -> its place in the sorted pairs, or past the expert's rows
+    row = jnp.arange(n_tiles * tile)
+    e = jnp.repeat(tile_expert, tile)
+    within = row - (tile_ends - tiles)[e] * tile
+    pair = order[jnp.clip(starts[e] + within, 0, t * k - 1)]
+    return {
+        "tile": tile, "n_tiles": n_tiles, "tile_expert": tile_expert, "n_live": tile_ends[-1],
+        "pair": pair, "token": pair // k,
+        "valid": ((within < sizes[e]) & (row < tile_ends[-1] * tile))[:, None],
+        "live": live, "over": jnp.maximum(live - capacity, 0),
+        "key": key, "order": order, "first_row": (tile_ends - tiles) * tile - starts,
+        "k": k, "count": count, "capacity": capacity,
+    }
+
+
+def _block_runs(buf, block: int):
+    """``[t / block, count, 2]``: for each block of ``block`` tokens and each
+    held expert, the first and last-plus-one buffer row of the expert's rows
+    whose tokens lie in the block. The sorted pairs' ``key * t + token``
+    rises (a stable sort of token-major pairs by expert), so a block's rows of
+    one expert are one run: its ends are where the block's first token and
+    the next block's fall in that order, clipped to the buffer's capacity and
+    moved to the expert's first buffer row."""
+    key, order, k, count = buf["key"], buf["order"], buf["k"], buf["count"]
+    t = key.shape[0] // k
+    sorted_ids = key[order] * t + order // k
+    edges = jnp.arange(count)[:, None] * t + jnp.arange(0, t + 1, block)[None, :]
+    pos = jnp.searchsorted(sorted_ids, edges.reshape(-1)).reshape(count, -1)
+    rows = jnp.minimum(pos, buf["capacity"]) + buf["first_row"][:, None]
+    return jnp.stack([rows[:, :-1], rows[:, 1:]], -1).swapaxes(0, 1).astype(jnp.int32)
+
+
+def _sum_rows(src, token, scale, runs, over, n_tokens, out_dtype):
+    from tpu_dist.ops.expert_combine import tokens_from_runs  # noqa: PLC0415
+
+    counters_lib.inc("moe.sites_combine_kernel")
+    return tokens_from_runs(src, token, scale, runs, over, n_tokens, out_dtype,
+                            interpret=not _on_tpu())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(n_tokens, x, token, valid, runs):
+    """``where(valid, x[token], 0)``; its backward sums the rows' cotangents
+    into their tokens through the kernel, in float32, cast once."""
+    return jnp.where(valid, x[token], 0)
+
+
+def _dispatch_fwd(n_tokens, x, token, valid, runs):
+    return _dispatch(n_tokens, x, token, valid, runs), (token, runs)
+
+
+def _dispatch_bwd(n_tokens, res, d_rows):
+    token, runs = res
+    return _sum_rows(d_rows, token, None, runs, 0, n_tokens, d_rows.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(n_tokens, y, weight_row, token, valid, runs, over):
+    """``zeros(f32[T, d]).at[token].add(where(valid, f32(y) * weight_row, 0))``,
+    NaN where ``over > 0``, in ``y``'s dtype: the kernel. The backward reads
+    the output's cotangent at the rows' tokens (gathers): ``d y = weight_row *
+    d_out[token]`` and ``d weight_row = sum_c f32(y) * f32(d_out[token])``."""
+    return _sum_rows(y, token, weight_row, runs, over, n_tokens, y.dtype)
+
+
+def _combine_fwd(n_tokens, y, weight_row, token, valid, runs, over):
+    return _combine(n_tokens, y, weight_row, token, valid, runs, over), (y, weight_row, token, valid, over)
+
+
+def _combine_bwd(n_tokens, res, d_out):
+    y, weight_row, token, valid, over = res
+    live = valid & (over == 0)
+    g = d_out[token].astype(jnp.float32)
+    d_y = jnp.where(live, weight_row.astype(jnp.float32)[:, None] * g, 0).astype(y.dtype)
+    d_w = jnp.where(live[:, 0], jnp.sum(y.astype(jnp.float32) * g, axis=-1), 0)
+    return d_y, d_w.astype(weight_row.dtype), None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def dropless_experts(x, chosen, weights, w_up, w_down, *, held, capacity: int, activation,
                      w_gate=None):
     """``sum_k weights[t, k] * expert_{chosen[t, k]}(x[t])`` over the chosen
@@ -282,46 +411,43 @@ def dropless_experts(x, chosen, weights, w_up, w_down, *, held, capacity: int, a
     whole tiles of ``GROUP_TILE`` rows, and multiplied tile by tile
     (:func:`grouped_matmul`); the buffer takes ``capacity`` live rows
     (``capacity / GROUP_TILE + count`` tiles), however they fall on the experts.
+    The rows come back to token order weighted and summed in float32: where
+    :func:`takes_combine_kernel` says so, by the kernel of
+    ``ops/expert_combine.py`` (``moe.sites_combine_kernel``: the combine, and
+    the dispatch gather's backward), elsewhere by XLA's scatter-add
+    (``moe.sites_combine_xla``, two a layer traced).
     A step that would need more is never cut short in silence: its output is
     NaN (the trainer's guard stops on the loss) and ``rows_over_cap`` counts
     the rows. Returns ``(out [T, d], {"rows_live", "rows_over_cap"})``."""
-    t, k = chosen.shape
-    first, count = held
-    tile = min(GROUP_TILE, -(-capacity // 8) * 8)       # a small buffer is one short tile an expert
-    n_tiles = -(-capacity // tile) + count
-    local = chosen.reshape(-1) - first
-    key = jnp.where((local >= 0) & (local < count), local, count)  # absent experts sort last
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
-    live = sizes.sum()
-    over = jnp.maximum(live - capacity, 0)
-    ends = jnp.minimum(jnp.cumsum(sizes), capacity)
-    sizes = jnp.diff(ends, prepend=0)                       # what the buffer takes
-    starts = ends - sizes
-    tiles = -(-sizes // tile)                               # whole tiles an expert
-    tile_ends = jnp.cumsum(tiles)
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right"), count - 1)
-    # buffer row -> its place in the sorted pairs, or past the expert's rows
-    row = jnp.arange(n_tiles * tile)
-    e = jnp.repeat(tile_expert, tile)
-    within = row - (tile_ends - tiles)[e] * tile
-    valid = ((within < sizes[e]) & (row < tile_ends[-1] * tile))[:, None]
-    pair = order[jnp.clip(starts[e] + within, 0, t * k - 1)]
-    token = pair // k
-    shape = (n_tiles, tile, -1)
-    rows = jnp.where(valid, x[token], 0).reshape(shape)
-    h = grouped_matmul(rows, w_up, tile_expert, tile_ends[-1])
+    t = chosen.shape[0]
+    buf = _buffer(chosen, held, capacity)
+    shape = (buf["n_tiles"], buf["tile"], -1)
+    token, valid, tile_expert, n_live = buf["token"], buf["valid"], buf["tile_expert"], buf["n_live"]
+    kernel = takes_combine_kernel(t, x.shape[1], token.shape[0], x.dtype)
+    if kernel:
+        from tpu_dist.ops.expert_combine import TOKEN_BLOCK  # noqa: PLC0415
+
+        runs = _block_runs(buf, TOKEN_BLOCK)
+        rows = _dispatch(t, x, token, valid, runs).reshape(shape)
+    else:
+        counters_lib.inc("moe.sites_combine_xla", 2)  # the combine, and the dispatch's transpose
+        rows = jnp.where(valid, x[token], 0).reshape(shape)
+    h = grouped_matmul(rows, w_up, tile_expert, n_live)
     if w_gate is None:
         h = activation(h)
     else:
-        gate = grouped_matmul(rows, w_gate, tile_expert, tile_ends[-1])
+        gate = grouped_matmul(rows, w_gate, tile_expert, n_live)
         h = (activation(gate.astype(jnp.float32)) * h.astype(jnp.float32)).astype(x.dtype)
-    y = grouped_matmul(h, w_down, tile_expert, tile_ends[-1]).reshape(n_tiles * tile, -1)
-    y = jnp.where(valid, y.astype(jnp.float32) * weights.reshape(-1)[pair][:, None], 0)
-    out = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(y)
-    out = jnp.where(over > 0, jnp.nan, out)
-    return out.astype(x.dtype), {"rows_live": live, "rows_over_cap": over}
+    y = grouped_matmul(h, w_down, tile_expert, n_live).reshape(token.shape[0], -1)
+    weight_row = weights.reshape(-1)[buf["pair"]]
+    over = buf["over"]
+    if kernel:
+        out = _combine(t, y, weight_row, token, valid, runs, over)
+    else:
+        y = jnp.where(valid, y.astype(jnp.float32) * weight_row[:, None], 0)
+        out = jnp.zeros((t, x.shape[1]), jnp.float32).at[token].add(y)
+        out = jnp.where(over > 0, jnp.nan, out).astype(x.dtype)
+    return out, {"rows_live": buf["live"], "rows_over_cap": over}
 
 
 def load_stats(loads, rows, held):
