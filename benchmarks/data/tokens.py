@@ -30,10 +30,10 @@ The balanced bias is model state, not a parameter, and the harness hands a
 reference ``arch``, ``params``, ``inputs`` and ``targets`` and nothing else
 (``harness/reference.py``), with ``params`` read leaf by leaf as what the
 optimizer moved. So ``attach`` leaves the bias in the one object it shares
-with the reference, the cell's ``arch`` (``router_bias``): a constant of the
-reference's program, which therefore compiles anew for every seed. Handing a
-reference the program's state is the harness's to do (``PERF.md``, Open
-questions).
+with the reference, the cell's ``arch`` (``router_bias``). The reference
+takes an array-valued entry of ``arch`` as an argument of its program, not as
+a constant (``reference.reference_program``), so its program is the same for every
+seed and compiles once a configuration.
 """
 
 from __future__ import annotations

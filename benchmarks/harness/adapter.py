@@ -207,7 +207,8 @@ class Adapter:
         """The window's program as the compiler leaves it: an AOT compile of
         the same jitted step at the same shapes (a load from the persistent
         cache once the warm-up has run), for what the trace does not tell:
-        which fused computations hold a convolution or a dot. Its
+        which fused computations hold a convolution or a dot, and which
+        Pallas kernels a matrix product. Its
         ``memory_analysis`` goes on an earlier line beside the runtime's own
         count."""
         from benchmarks.harness import trace as trace_lib  # noqa: PLC0415
@@ -218,13 +219,14 @@ class Adapter:
         else:
             jitted, args = tr.train_step, (tr.state, *self.first_batch(), tr._lr(0))
         compiled = jitted.lower(*args).compile()
-        mem = compiled.memory_analysis()
+        mem, text = compiled.memory_analysis(), compiled.as_text()
         return {
             "temp_bytes": int(mem.temp_size_in_bytes),
             "argument_bytes": int(mem.argument_size_in_bytes),
             "output_bytes": int(mem.output_size_in_bytes),
             "alias_bytes": int(mem.alias_size_in_bytes),
-            "matmul_computations": trace_lib.matmul_computations(compiled.as_text()),
+            "matmul_computations": trace_lib.matmul_computations(text),
+            "mosaic_kernels": trace_lib.mosaic_kernels(text),
         }
 
     def loss_at(self, index: int) -> Optional[float]:

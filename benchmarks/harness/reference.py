@@ -29,6 +29,46 @@ def default_loss_sum(model):
     return loss_sum
 
 
+def reference_program(model, arch, n: int, chunk: int):
+    """``(jitted, state)``: the reference's program over a batch of ``n``
+    samples, ``jitted(params, state, inputs, targets) -> (mean loss,
+    gradient)``, and the ``state`` of ``arch`` to hand it. Call it under
+    ``jax.default_matmul_precision("highest")``.
+
+    The state is ``arch``'s array-valued entries: what the program's run left
+    there for the reference (a router's balanced ``router_bias``,
+    ``benchmarks/data/tokens.py``). It is an argument of the program, so that
+    the program is the same for every seed of a configuration and the
+    persistent cache serves it from the second seed on; closed over, a seed's
+    state would be a constant of the program, and every seed would compile
+    anew."""
+    import jax  # noqa: PLC0415
+    import jax.numpy as jnp  # noqa: PLC0415
+
+    if model.WHOLE_BATCH or chunk <= 0 or chunk >= n:
+        chunk = n
+    if n % chunk:
+        raise ValueError(f"reference chunk {chunk} does not divide batch {n}")
+    loss_sum = getattr(model, "loss_sum", None) or default_loss_sum(model)
+    state = {k: v for k, v in arch.items() if isinstance(v, (np.ndarray, jax.Array))}
+    sizes = {k: v for k, v in arch.items() if k not in state}
+
+    def whole(p, state, x, y):
+        a = {**sizes, **state}
+        xs = x.reshape((n // chunk, chunk) + x.shape[1:])
+        ys = y.reshape((n // chunk, chunk) + y.shape[1:])
+
+        def body(acc, xy):
+            loss, grads = jax.value_and_grad(lambda q: loss_sum(a, q, *xy))(p)
+            return (acc[0] + loss, jax.tree_util.tree_map(jnp.add, acc[1], grads)), None
+
+        zero = (jnp.zeros((), jnp.float32), jax.tree_util.tree_map(jnp.zeros_like, p))
+        (loss, grads), _ = jax.lax.scan(body, zero, (xs, ys))
+        return loss / n, jax.tree_util.tree_map(lambda g: g / n, grads)
+
+    return jax.jit(whole), state
+
+
 def reference_loss_and_grads(model, arch, params, inputs, targets, chunk: int, device):
     """The batch's mean loss and its gradient in float32 at the highest matmul
     precision (a TPU otherwise rounds float32 operands to bf16), on one
@@ -41,33 +81,16 @@ def reference_loss_and_grads(model, arch, params, inputs, targets, chunk: int, d
     axis. The batch is taken in chunks of ``chunk`` samples where the model
     allows (``WHOLE_BATCH`` false), so float32 activations of a large batch
     need not fit at once; the sum over chunks is exact arithmetic for a loss
-    that is a mean over samples."""
+    that is a mean over samples. The state in ``arch`` is an argument of the
+    program (``reference_program``)."""
     import jax  # noqa: PLC0415
-    import jax.numpy as jnp  # noqa: PLC0415
 
-    n = int(targets.shape[0])
-    if model.WHOLE_BATCH or chunk <= 0 or chunk >= n:
-        chunk = n
-    if n % chunk:
-        raise ValueError(f"reference chunk {chunk} does not divide batch {n}")
-    loss_sum = getattr(model, "loss_sum", None) or default_loss_sum(model)
-
-    def whole(p, x, y):
-        xs = x.reshape((n // chunk, chunk) + x.shape[1:])
-        ys = y.reshape((n // chunk, chunk) + y.shape[1:])
-
-        def body(acc, xy):
-            loss, grads = jax.value_and_grad(lambda q: loss_sum(arch, q, *xy))(p)
-            return (acc[0] + loss, jax.tree_util.tree_map(jnp.add, acc[1], grads)), None
-
-        zero = (jnp.zeros((), jnp.float32), jax.tree_util.tree_map(jnp.zeros_like, p))
-        (loss, grads), _ = jax.lax.scan(body, zero, (xs, ys))
-        return loss / n, jax.tree_util.tree_map(lambda g: g / n, grads)
-
+    jitted, state = reference_program(model, arch, int(targets.shape[0]), chunk)
     put = lambda t: jax.device_put(t, device)  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.jit(whole)(
+        loss, grads = jitted(
             put(jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32), params)),
+            put(state),
             put(np.asarray(inputs, getattr(model, "INPUT_DTYPE", np.float32))),
             put(np.asarray(targets, np.int32)),
         )

@@ -16,7 +16,11 @@ and with no category stat; ``Async XLA Ops`` holds the start-to-done spans of
 asynchronous copies and collectives, which overlap compute and are not busy
 time; ``XLA Modules`` holds one event per program run and ``Steps`` one per
 step. A fusion's name does not say whether it holds a convolution or a dot:
-that comes from the compiled program's text (``matmul_computations``).
+that comes from the compiled program's text (``matmul_computations``). Nor
+does a Pallas kernel's: its op is a ``custom-call`` named after the kernel
+(``moe_gmm.2``, ``_bwd_pallas.3``), and the same text carries the kernel's
+serialized body, which says whether it runs a matrix product
+(``mosaic_kernels``).
 
 The host's side is not taken from the profiler. With its host tracer on
 (level 1 or 2) the capture of 12 steps is 130-200 MB, the traced epoch starts
@@ -30,6 +34,8 @@ some 10 ms) after it, and that is the error of the alignment.
 
 from __future__ import annotations
 
+import base64
+import binascii
 import glob
 import gzip
 import json
@@ -56,6 +62,16 @@ HLO_EVENT = re.compile(r"^%?(?P<op>[^\s=]+) = (?P<shape>.*?) (?P<opcode>[a-z][a-
 HLO_CALLS = re.compile(r"calls=%?([^\s,)]+)")
 HLO_KIND = re.compile(r"kind=(k[A-Za-z]+)")
 HLO_LAYOUT = re.compile(r"\{[^{}]*\}")
+# a Pallas (Mosaic) kernel's op in the compiled text: the instruction's name,
+# its target, and its body, base64 of MLIR bytecode, in the backend config
+MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([^\s=]+) = ")
+MOSAIC_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]*)"')
+MLIR_BYTECODE = b"ML\xefR"
+# the ops a kernel's body holds where it runs a matrix product on the MXU:
+# what ``jnp.dot`` / ``lax.dot_general`` lower to inside a kernel, in the
+# bytecode's table of op names (NUL-terminated strings)
+MOSAIC_MATMUL = re.compile(rb"(?<![\w.])(?:tpu\.matmul|vector\.contract)\x00")
 
 
 # -- interval arithmetic (copied from tpu_dist/obs/xprof.py) -------------------
@@ -221,9 +237,40 @@ def short_name(name: str) -> str:
     return (text + (f" {p['shape']}" if p["shape"] else ""))[:120]
 
 
+def kernel_holds_matmul(instruction: str) -> Optional[bool]:
+    """Whether the Mosaic kernel of one ``custom-call`` line of a compiled
+    program runs a matrix product; None where its body cannot be read (no
+    body, or one that is not MLIR bytecode, as another jax may write it)."""
+    m = MOSAIC_BODY.search(instruction)
+    if m is None:
+        return None
+    try:
+        body = base64.b64decode(m.group(1), validate=True)
+    except (binascii.Error, ValueError):
+        return None
+    if not body.startswith(MLIR_BYTECODE):
+        return None
+    return MOSAIC_MATMUL.search(body) is not None
+
+
+def mosaic_kernels(hlo_text: str) -> Dict[str, Optional[bool]]:
+    """Every Pallas kernel's op of a compiled program, by its instruction
+    name: ``kernel_holds_matmul`` of each."""
+    out: Dict[str, Optional[bool]] = {}
+    for line in hlo_text.splitlines():
+        if MOSAIC_TARGET in line:
+            m = HLO_INSTRUCTION.match(line)
+            if m:
+                out[m.group(1)] = kernel_holds_matmul(line)
+    return out
+
+
 def matmul_computations(hlo_text: str) -> List[str]:
-    """Names of the computations of a compiled program whose body holds a
-    convolution or a dot: the fusions that call them are the matmul ops."""
+    """What runs the matrix products of a compiled program: the names of the
+    computations whose body holds a convolution or a dot (the fusions that
+    call them are the matmul ops), then the names of the Pallas kernels' ops
+    whose body holds a matrix product, or cannot be read: such a kernel is
+    counted, so that none that holds one is left out."""
     out, current = [], None
     for line in hlo_text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{\s*$", line)
@@ -234,19 +281,51 @@ def matmul_computations(hlo_text: str) -> List[str]:
         elif current and re.search(r" (convolution|dot)\(", line):
             if not out or out[-1] != current:
                 out.append(current)
-    return out
+    return out + [name for name, holds in mosaic_kernels(hlo_text).items() if holds is not False]
 
 
 def op_kind(name: str, matmuls: Optional[Iterable[str]]) -> str:
     """``collective``, ``matmul`` or ``other``. Collectives count in all
     their forms (``-start``, ``-done``, sync). ``matmuls`` are the names
-    ``matmul_computations`` found; None means they are not known."""
+    ``matmul_computations`` found, a fusion's called computation or a
+    kernel's op; None means they are not known."""
     p = parse_op(name)
     if p["opcode"].startswith(COLLECTIVE_STEMS):
         return "collective"
-    if p["opcode"] in MATMUL_OPCODES or (matmuls is not None and p["calls"] in matmuls):
+    if p["opcode"] in MATMUL_OPCODES:
+        return "matmul"
+    if matmuls is not None and (p["calls"] in matmuls
+                                or (p["opcode"] == "custom-call" and p["op"] in matmuls)):
         return "matmul"
     return "other"
+
+
+def kernel_stem(name: str) -> Optional[str]:
+    """A ``custom-call`` op's name before its first ``.`` (the kernel's
+    name: ``moe_gmm`` of ``moe_gmm.2``); None for any other op."""
+    p = parse_op(name)
+    return p["op"].split(".")[0] if p["opcode"] == "custom-call" else None
+
+
+def matmul_split(self_ns_by_op: Dict[str, float], matmuls: Iterable[str],
+                 scale: float = 1.0) -> Dict[str, Any]:
+    """One chip's self time in matmul ops (``scale`` x its nanoseconds), by
+    what runs them: ``xla`` (convolutions, dots and the fusions holding one)
+    and ``kernels`` (each Pallas kernel that counts, by ``kernel_stem``);
+    beside them, counted in neither, ``other_calls``: the ``custom-call`` ops
+    that are no matmul op (a kernel without a matrix product, XLA's own
+    custom calls), by stem."""
+    matmuls = set(matmuls)
+    out: Dict[str, Any] = {"xla": 0.0, "kernels": {}, "other_calls": {}}
+    for name, t in self_ns_by_op.items():
+        t *= scale
+        stem, kind = kernel_stem(name), op_kind(name, matmuls)
+        if kind == "matmul" and stem is None:
+            out["xla"] += t
+        elif stem is not None:
+            part = out["kernels" if kind == "matmul" else "other_calls"]
+            part[stem] = part.get(stem, 0.0) + t
+    return out
 
 
 def reduce_chip(plane: Dict[str, Any], window: Interval,
@@ -314,7 +393,8 @@ def reduce_trace(trace: Trace, chips: int,
                  matmuls: Optional[Iterable[str]] = None) -> Optional[Dict[str, Any]]:
     """Busy, idle, per-kind and per-op totals of the traced window, in
     seconds; None when the trace holds no device op. ``matmuls``: see
-    ``op_kind``; without them ``chip0_matmul_s`` is None (not measured)."""
+    ``op_kind``; without them ``chip0_matmul_s`` and its ``matmul_split``,
+    ``chip0_matmul_split_s``, are None (not measured)."""
     window = window_of(trace)
     planes = device_planes(trace)[:chips]
     if window is None or not planes:
@@ -335,6 +415,8 @@ def reduce_trace(trace: Trace, chips: int,
         "busy_s_max": max(busy), "busy_s_min": min(busy),
         "chip0_busy_s": busy[0],
         "chip0_matmul_s": None if matmuls is None else first["self_ns_by_kind"]["matmul"] * ns,
+        "chip0_matmul_split_s": (None if matmuls is None
+                                 else matmul_split(first["self_ns_by_op"], matmuls, ns)),
         "chip0_collective_s": first["collective_ns"] * ns,
         "chip0_collectives": first["n_collectives"],
         "chip0_modules": first["n_modules"],

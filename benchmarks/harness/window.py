@@ -10,7 +10,8 @@ measured rate, a fused cell ends at the first epoch boundary after
 ``--seconds``. With ``--trace 1`` the window's first epoch runs under the
 profiler, capped at the traffic file's ``trace_steps``. After the window come
 the float32 reference and the comparison that decides ``correct`` and, in a
-traced run, an AOT compile of the window's program for its matmul fusions.
+traced run, an AOT compile of the window's program for the ops that hold
+its matrix products.
 """
 
 from __future__ import annotations
@@ -69,6 +70,31 @@ class CompileCounter:
     def _on_event(self, event: str, duration: float, **kw) -> None:
         if self.armed and ("backend_compile" in event or "jaxpr_to_mlir" in event):
             self.events.append(event)
+
+
+# (number, its limit) of the reference check, as ``reference.compare`` names them
+COMPARED = (("loss_rel_err", "loss_rel_tol"), ("sign_agreement", "sign_agreement_min"),
+            ("grad_rel_l2_err", "grad_rel_l2_tol"), ("worst_leaf_cosine", "leaf_cosine_min"))
+
+
+def compared(verdict: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
+    """The numbers the reference check compared, each beside its limit: the
+    result line's last key, and ``run.py`` prints them as the last lines of
+    standard error."""
+    return {v: {"value": float(verdict[v]), "limit": float(verdict[lim])}
+            for v, lim in COMPARED if v in verdict}
+
+
+def _kernels_seen(kernels: Dict[str, Optional[bool]]) -> str:
+    """What the program's text said of its Pallas kernels, by stem."""
+    if not kernels:
+        return "; no Pallas kernel"
+    stems: Dict[str, set] = {}
+    for name, holds in kernels.items():
+        stems.setdefault(name.split(".")[0], set()).add(holds)
+    say = {True: "counts", False: "no matrix product", None: "body unreadable, counted"}
+    return "; Pallas kernels: " + ", ".join(
+        f"{stem} ({'/'.join(say[h] for h in sorted(hs, key=str))})" for stem, hs in sorted(stems.items()))
 
 
 def run_cell(
@@ -241,6 +267,7 @@ def run_cell(
     if not trace:
         for m in cell.end_to_end:
             result["metrics"][m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+        result["compared"] = compared(verdict)
         return result
 
     from benchmarks.harness import trace as trace_lib  # noqa: PLC0415
@@ -249,7 +276,8 @@ def run_cell(
     program = ad.program()
     _say(f"program (AOT compile of the window's step, {time.perf_counter() - t:.1f} s): "
          + ", ".join(f"{k} {v / GIB:.3f} GiB" for k, v in program.items() if k.endswith("_bytes"))
-         + f", {len(program['matmul_computations'])} computations hold a convolution or dot")
+         + f", {len(program['matmul_computations'])} computations or kernels hold a matrix product"
+         + _kernels_seen(program["mosaic_kernels"]))
     reduced = None
     xplane = trace_lib.find_xplane(trace_dir)
     if xplane is not None:
@@ -297,4 +325,5 @@ def run_cell(
     result["breakdown"] = {
         "device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"],
     }
+    result["compared"] = compared(verdict)
     return result

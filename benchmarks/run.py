@@ -3,8 +3,9 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Runs one cell of ``BENCHMARK.json`` on the machine it is started on and
-prints the contract's result as the last line of standard output. Without a
-TPU, with fewer chips than the cell asks for, or with a ``device_kind`` that
+prints its result object as the last line of standard output, and the
+numbers that decided ``correct``, each beside its limit, as the last lines of
+standard error. Without a TPU, with fewer chips than the cell asks for, or with a ``device_kind`` that
 ``benchmarks/peaks.json`` lacks, it exits non-zero and prints no result.
 """
 
@@ -41,6 +42,9 @@ def main(argv=None) -> int:
         print(f"benchmark refused: {e}", file=sys.stderr)
         return 2
     sys.stdout.flush()
+    for name, c in result["compared"].items():
+        print(f"compared: {name} {c['value']!r}, limit {c['limit']!r}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result))
     return 0
 
